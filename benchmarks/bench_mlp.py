@@ -35,9 +35,10 @@ def _plan(k1, n1, n2, scheme, gs=128):
 
 
 def _mesh(tp):
+    from repro.launch.mesh import make_mesh
+
     n = len(jax.devices())
-    return jax.make_mesh((max(n // tp, 1), tp), ("data", "model"),
-                         devices=jax.devices()[:max(n // tp, 1) * tp])
+    return make_mesh((max(n // tp, 1), tp), ("data", "model"))
 
 
 def _bench_wall(fn, *args, iters=3):

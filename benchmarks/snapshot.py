@@ -37,21 +37,19 @@ def git_sha(short: bool = True) -> str:
 
 
 def _environment() -> dict:
-    try:
-        import jax
-        env = {"jax": jax.__version__,
-               "backend": jax.default_backend(),
-               "device_count": jax.device_count(),
-               "process_count": jax.process_count()}
-        # the DP×TP grid the numbers were taken on (DESIGN.md §11) —
-        # single-host benches report the trivial dp1xtp<N> shape only
-        # when a mesh plan was exported by the runner
-        plan = os.environ.get("REPRO_MESH")
-        if plan:
-            env["mesh"] = plan
-        return env
-    except Exception:
-        return {}
+    import jax
+
+    env = {"jax": jax.__version__,
+           "backend": jax.default_backend(),
+           "device_count": jax.device_count(),
+           "process_count": jax.process_count()}
+    # the DP×TP grid the numbers were taken on (DESIGN.md §11) —
+    # single-host benches report the trivial dp1xtp<N> shape only
+    # when a mesh plan was exported by the runner
+    plan = os.environ.get("REPRO_MESH")
+    if plan:
+        env["mesh"] = plan
+    return env
 
 
 def write(name: str, *, config: dict, metrics: dict,

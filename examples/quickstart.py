@@ -24,6 +24,7 @@ import numpy as np
 from repro.core import reorder
 from repro.core.policy import ExecutionPolicy
 from repro.launch import roofline
+from repro.launch.mesh import make_mesh
 
 K1, N1, N2, M, TP = 512, 1024, 512, 8, 4
 
@@ -36,7 +37,7 @@ x = jax.random.normal(r[3], (M, K1))
 
 print(f"MLP pair: ({K1}, {N1}) -> ({N1}, {N2}), batch {M}, TP={TP}\n")
 
-mesh = jax.make_mesh((len(jax.devices()) // TP, TP), ("data", "model"))
+mesh = make_mesh((len(jax.devices()) // TP, TP), ("data", "model"))
 outs = {}
 for scheme in ("naive-actorder", "exllama", "tp-aware"):
     # offline: quantize int4 (group 128, act_order) + lay out for `scheme`

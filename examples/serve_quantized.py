@@ -32,6 +32,7 @@ import numpy as np
 
 from repro.configs import ARCH_IDS, get_smoke_config
 from repro.core.policy import ExecutionPolicy
+from repro.launch.mesh import make_mesh
 from repro.models.common import ParallelContext
 from repro.plan import DeploymentArtifact, compiler
 from repro.runtime.sampling import SamplingConfig
@@ -97,7 +98,7 @@ def main():
         # a tuned per-layer CollectivePlan the CLI flags don't know)
         policy = artifact.policy()
 
-    mesh = jax.make_mesh((2, TP), ("data", "model"))
+    mesh = make_mesh((2, TP), ("data", "model"))
     ctx = ParallelContext(mesh=mesh, batch_axes=("data",), policy=policy)
     print(f"arch={args.arch} scheme={args.scheme} backend={policy.backend} "
           f"collective={policy.collective.shorthand()} "

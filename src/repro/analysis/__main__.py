@@ -18,8 +18,12 @@ import os
 import sys
 
 # the HLO/contract sweeps need a multi-device host platform; set BEFORE
-# jax (transitively) imports, harmless when a real backend is present
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+# jax (transitively) imports, harmless when a real backend is present.
+# The sequential CPU scheduler keeps the printed instruction order the
+# program order HL003's overlap windows are read from.
+os.environ.setdefault(
+    "XLA_FLAGS", "--xla_force_host_platform_device_count=8 "
+    "--xla_cpu_enable_concurrency_optimized_scheduler=false")
 
 
 def main(argv=None) -> int:

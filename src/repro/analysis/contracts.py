@@ -50,9 +50,9 @@ def _default_specs():
 
 
 def _tp_mesh(tp: int):
-    import jax
+    from repro.launch.mesh import make_mesh
 
-    return jax.make_mesh((tp,), ("model",), devices=jax.devices()[:tp])
+    return make_mesh((tp,), ("model",))
 
 
 def _abstract_apply(spec, tp: int, dtype):
@@ -63,16 +63,16 @@ def _abstract_apply(spec, tp: int, dtype):
     from jax.sharding import PartitionSpec as P
 
     from repro.comm import dispatch as comm_dispatch
-    from repro.core import compat
     from repro.core.policy import ExecutionPolicy
 
     mesh = _tp_mesh(tp)
     policy = ExecutionPolicy(collective=spec)
     scatters = comm_dispatch.scatters_output(spec)
     out_spec = P(None, "model") if scatters else P(None, None)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         lambda y: comm_dispatch.apply(y, "model", spec, policy),
-        mesh=mesh, in_specs=P(None, None), out_specs=out_spec)
+        mesh=mesh, in_specs=P(None, None), out_specs=out_spec,
+        check_vma=False)
     y = jax.ShapeDtypeStruct(PROBE_SHAPE, jnp.dtype(dtype))
     return jax.eval_shape(fn, y)
 

@@ -239,10 +239,10 @@ def _lowered_pair_hlo(spec, tp: int) -> str:
     import jax.numpy as jnp
 
     from repro.core.policy import ExecutionPolicy
+    from repro.launch.mesh import make_mesh
 
     pp = _sweep_pair()
-    mesh = jax.make_mesh((1, tp), ("data", "model"),
-                         devices=jax.devices()[:tp])
+    mesh = make_mesh((1, tp), ("data", "model"))
     x = jax.random.normal(jax.random.PRNGKey(1),
                           (_SWEEP_M, _SWEEP_SHAPE[0]), jnp.float32)
     pol = ExecutionPolicy(scheme="tp-aware", backend="jnp",

@@ -50,7 +50,30 @@ from repro.comm.spec import (CollectivePlan, CollectiveSpec,
 
 __all__ = [
     "KernelTiling", "ExecutionPolicy", "DEFAULT_POLICY", "resolve_policy",
+    "platform_is_tpu", "interpret_mode",
 ]
+
+
+def platform_is_tpu() -> bool:
+    """True when JAX's default backend is a TPU: the one platform test
+    behind ``interpret_mode`` and ``ExecutionPolicy.auto``."""
+    return jax.default_backend() == "tpu"
+
+
+def interpret_mode(requested: Optional[bool] = None) -> bool:
+    """Whether the Pallas kernels run interpreted.
+
+    ``None`` (the default of ``KernelTiling.interpret``) decides from the
+    platform: compiled Mosaic on a TPU, the interpreter elsewhere.
+    ``False`` compiles anywhere, which is how a CPU host compiles the
+    kernels for a described TPU.  ``True`` on a TPU is refused: there the
+    kernels never run interpreted."""
+    if requested is None:
+        return not platform_is_tpu()
+    if requested and platform_is_tpu():
+        raise ValueError("interpret=True was requested on a TPU; the Pallas "
+                         "kernels run compiled there")
+    return bool(requested)
 
 
 def _canon_dtype(dt):
@@ -65,8 +88,8 @@ class KernelTiling:
     """Tile/lowering knobs for the fused Pallas kernels.
 
     ``block_k=None`` lets ``dequant_matmul.pick_block_k`` choose the
-    largest group-aligned K tile; ``interpret=None`` auto-selects
-    interpret mode off-TPU (this container) and compiled Mosaic on TPU.
+    K tile; ``interpret`` is resolved by ``interpret_mode`` (None: from
+    the platform).
     """
 
     block_m: int = 128
@@ -144,10 +167,7 @@ class ExecutionPolicy:
         the dequant into the GEMM epilogue there.
         """
         if on_tpu is None:
-            try:
-                on_tpu = jax.default_backend() == "tpu"
-            except Exception:  # pragma: no cover
-                on_tpu = False
+            on_tpu = platform_is_tpu()
         ordered = scheme != "naive-actorder"
         backend = "pallas" if (on_tpu and ordered) else "jnp"
         return cls(scheme=scheme, backend=backend, **overrides)

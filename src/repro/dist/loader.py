@@ -94,7 +94,10 @@ def load_per_rank(dirpath: str, manifest: dict,
             f"{dirpath} is missing rank files {missing} (artifact was "
             f"prepared for tp={tp})")
 
-    trees = {r: checkpoint.load(rank_file(dirpath, r)) for r in ranks}
+    # staged in host memory and put on each device straight from there: a
+    # default-device load would first gather every local rank on one chip
+    trees = {r: checkpoint.load(rank_file(dirpath, r), host=True)
+             for r in ranks}
     flats = {r: checkpoint.flatten_keys(t) for r, t in trees.items()}
     r0 = ranks[0]
     shards = manifest["leaf_shards"]

@@ -121,16 +121,15 @@ class MeshPlan:
         global device list (all processes' devices — see module doc)."""
         import jax
 
+        from repro.launch.mesh import make_mesh
+
         devs = list(jax.devices()) if devices is None else list(devices)
         if len(devs) != self.size:
             raise ValueError(
                 f"mesh plan {self.shorthand()} spans {self.size} device(s) "
                 f"but {len(devs)} are visible; launch with a matching "
                 f"device count (or pass an explicit device subset)")
-        import numpy as np
-
-        grid = np.asarray(devs, dtype=object).reshape(self.dp, self.tp)
-        return jax.sharding.Mesh(grid, ("data", "model"))
+        return make_mesh((self.dp, self.tp), ("data", "model"), devs)
 
     def local_model_ranks(self, mesh) -> tuple:
         """Model-axis coordinates owned by THIS process's addressable

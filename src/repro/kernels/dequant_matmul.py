@@ -21,8 +21,11 @@ Packing: 8 int4 nibbles per uint32 along K (``quantization.pack_int4``); a
 unpacked with VPU shifts/masks and fed to the MXU in the compute dtype with
 f32 accumulation.
 
-All kernels are validated on CPU with ``interpret=True`` against
-``ref.py``; on real TPUs the same ``pallas_call`` lowers to Mosaic.
+Every block obeys the TPU tiling rule (last two block dims divisible by
+the dtype's sublane count and 128, or equal to the array's), which
+``check_tiling`` states once for the entry points and the dispatcher.
+The same ``pallas_call`` runs compiled on a TPU and interpreted
+(``interpret=True``) on CPU, where the tests check it against ``ref.py``.
 """
 
 from __future__ import annotations
@@ -37,20 +40,96 @@ from jax.experimental.pallas import tpu as pltpu
 
 PACK = 8
 
+#: Mosaic's default scoped-VMEM budget on TPU v5e.  ``check_tiling`` keeps
+#: a kernel's double-buffered blocks plus its dequant temporaries under it.
+VMEM_LIMIT_BYTES = 16 * 1024 * 1024
+
 
 def _lcm(a: int, b: int) -> int:
     return a * b // gcd(a, b)
 
 
-def pick_block_k(k: int, group_size: int, target: int = 256) -> int:
-    """K-tile: a multiple of lcm(group_size, 8) dividing K, close to target."""
-    base = _lcm(group_size, PACK)
-    bk = base
-    while bk * 2 <= min(k, target) and k % (bk * 2) == 0:
-        bk *= 2
-    if k % bk:
-        raise ValueError(f"K={k} not tileable with group_size={group_size}")
-    return bk
+def sublanes(dtype) -> int:
+    """Rows of one (sublane, 128) VMEM tile: 8 for 32-bit, 16 for 16-bit."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+def pick_block_k(k: int, group_size: int, target: int = 2048) -> int:
+    """K-tile of the ordered kernels.
+
+    A K-tile below K must keep three blocks on the (8, 128) grid: x
+    ``(bm, bk)`` (bk % 128), packed weights ``(bk/8, bn)`` (bk/8 % 8) and
+    metadata ``(bk/gs, bn)`` (bk/gs % 8).  So bk is a multiple of
+    ``lcm(8*gs, 128)`` dividing K: the largest one not above
+    ``max(target, lcm)``.  Where none divides K the whole K is one tile
+    (each block then spans its array's full dim), e.g. K=2560 at gs=128.
+    """
+    if k % group_size or k % PACK:
+        raise ValueError(f"K={k} is not a multiple of group_size="
+                         f"{group_size} and of the packing factor {PACK}")
+    base = _lcm(PACK * group_size, 128)
+    cap = max(target, base)
+    best = None
+    for bk in range(base, min(k, cap) + 1, base):
+        if k % bk == 0:
+            best = bk
+    return best or k
+
+
+def _vmem_bytes(bm: int, bk: int, bn: int, group_size: int) -> int:
+    """VMEM the ordered GEMM holds per grid step (f32 words): the
+    double-buffered x / packed-weight / scales / zeros / output blocks,
+    the accumulator, and the five (bk, bn) dequant temporaries (nibbles,
+    codes, scales, zeros, weights)."""
+    blocks = bm * bk + bk // PACK * bn + 2 * (bk // group_size) * bn \
+        + bm * bn
+    return 4 * (2 * blocks + bm * bn + 5 * bk * bn)
+
+
+def check_tiling(m: int, k: int, n: int, group_size: int, bm: int, bn: int,
+                 bk: int, x_dtype=jnp.float32) -> None:
+    """Raise ``ValueError`` unless ``(bm, bn, bk)`` is a tiling the
+    compiled ordered kernel accepts for an ``(m, k) @ (k, n)`` problem —
+    the one statement of its constraints (grid divisibility, group
+    alignment, the (8, 128) block rule, the VMEM budget)."""
+    why = []
+    if m % bm or n % bn or k % bk:
+        why.append("blocks do not divide the problem")
+    if bk % group_size or bk % PACK:
+        why.append(f"bk is not a multiple of group_size and of {PACK}")
+    if bm != m and bm % sublanes(x_dtype):
+        why.append(f"bm is not a multiple of {sublanes(x_dtype)}")
+    if bn != n and bn % 128:
+        why.append("bn is not a multiple of 128")
+    if bk != k and (bk % 128 or (bk // PACK) % 8
+                    or (bk // group_size) % 8):
+        why.append(f"bk is not a multiple of lcm(8*{group_size}, 128)")
+    if not why and _vmem_bytes(bm, bk, bn, group_size) > VMEM_LIMIT_BYTES:
+        why.append(f"{_vmem_bytes(bm, bk, bn, group_size)} B of VMEM > "
+                   f"{VMEM_LIMIT_BYTES}")
+    if why:
+        raise ValueError(
+            f"bad tiling m={m},n={n},k={k} bm={bm},bn={bn},bk={bk} "
+            f"(group_size={group_size}): " + "; ".join(why))
+
+
+def _unpack(qw_ref, bk: int):
+    """``(bk/8, bn)`` packed uint32 block -> ``(bk, bn)`` f32 codes in
+    [0, 15].  Shifts run on int32 (Mosaic has no uint32 -> f32 convert;
+    the masked nibbles fit either way)."""
+    qw = jax.lax.bitcast_convert_type(qw_ref[...], jnp.int32)
+    shifts = jax.lax.broadcasted_iota(jnp.int32, (1, PACK, 1), 1) * 4
+    nibbles = (qw[:, None, :] >> shifts) & 0xF
+    return nibbles.reshape(bk, qw.shape[-1]).astype(jnp.float32)
+
+
+def _expand_groups(meta_ref, group_size: int):
+    """``(bk/gs, bn)`` metadata block -> ``(bk, bn)`` rows: each group's
+    row repeated ``group_size`` times by broadcast + reshape (no gather)."""
+    meta = meta_ref[...].astype(jnp.float32)
+    g, bn = meta.shape
+    return jnp.broadcast_to(meta[:, None, :],
+                            (g, group_size, bn)).reshape(g * group_size, bn)
 
 
 # ---------------------------------------------------------------------------
@@ -69,22 +148,20 @@ def _ordered_gemm_step(x_ref, qw_ref, s_ref, z_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # unpack (bk/8, bn) uint32 -> (bk, bn) int in [0, 15]
-    qw = qw_ref[...]
-    shifts = (jnp.arange(PACK, dtype=jnp.uint32) * 4)[None, :, None]
-    nibbles = (qw[:, None, :] >> shifts) & jnp.uint32(0xF)
-    q = nibbles.reshape(bk, qw.shape[-1]).astype(jnp.float32)
-
+    q = _unpack(qw_ref, bk)
     # one metadata row per quant group in this K-tile (VMEM-resident, reused
     # across the whole (bm, bn) tile — the TPU form of the locality win)
-    rows = jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0) // group_size
-    s = jnp.take_along_axis(s_ref[...].astype(jnp.float32), rows, axis=0)
-    z = jnp.take_along_axis(z_ref[...].astype(jnp.float32), rows, axis=0)
+    s = _expand_groups(s_ref, group_size)
+    z = _expand_groups(z_ref, group_size)
     w = ((q - z) * s).astype(compute_dtype)
 
+    # an f32 compute dtype means f32 products: pin the contraction
+    # precision instead of leaving it to Mosaic's default
     acc_ref[...] += jax.lax.dot_general(
         x_ref[...].astype(compute_dtype), w,
         (((1,), (0,)), ((), ())),
+        precision=(jax.lax.Precision.HIGHEST
+                   if jnp.dtype(compute_dtype) == jnp.float32 else None),
         preferred_element_type=jnp.float32)
 
 
@@ -113,15 +190,14 @@ def dequant_matmul_ordered(
     block_k: int | None = None,
     compute_dtype=jnp.float32,
     out_dtype=None,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     m, k = x.shape
     n = qweight.shape[1]
     bk = block_k or pick_block_k(k, group_size)
     bm = min(block_m, m)
     bn = min(block_n, n)
-    if m % bm or n % bn or k % bk or bk % group_size:
-        raise ValueError(f"bad tiling m={m},n={n},k={k} bm={bm},bn={bn},bk={bk}")
+    check_tiling(m, k, n, group_size, bm, bn, bk, x.dtype)
     out_dtype = out_dtype or compute_dtype
 
     grid = (m // bm, n // bn, k // bk)
@@ -240,7 +316,7 @@ def dequant_matmul_wire_ordered(
     block_k: int | None = None,
     compute_dtype=jnp.float32,
     out_dtype=None,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Fused GEMM + wire quantize.  Returns the flat wire tuple:
     int8 -> ``(payload (M, N) int8, scales (M, N/wire_block) f16)``;
@@ -308,10 +384,7 @@ def _dequant_matmul_gidx_kernel(g_ref, x_ref, qw_ref, s_ref, z_ref, o_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    qw = qw_ref[...]
-    shifts = (jnp.arange(PACK, dtype=jnp.uint32) * 4)[None, :, None]
-    nibbles = (qw[:, None, :] >> shifts) & jnp.uint32(0xF)
-    q = nibbles.reshape(bk, qw.shape[-1]).astype(jnp.float32)
+    q = _unpack(qw_ref, bk)
 
     # per-row dynamic gather from the FULL (G, bn) metadata tile — the
     # locality penalty of the unordered layout, reproduced structurally.
@@ -342,7 +415,7 @@ def dequant_matmul_gidx(
     block_k: int = 256,
     compute_dtype=jnp.float32,
     out_dtype=None,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     m, k = x.shape
     n = qweight.shape[1]
@@ -385,29 +458,25 @@ def dequant_matmul_gidx(
 # ---------------------------------------------------------------------------
 
 def _dequant_kernel(qw_ref, s_ref, z_ref, o_ref, *, group_size: int, bk: int):
-    qw = qw_ref[...]
-    shifts = (jnp.arange(PACK, dtype=jnp.uint32) * 4)[None, :, None]
-    nibbles = (qw[:, None, :] >> shifts) & jnp.uint32(0xF)
-    q = nibbles.reshape(bk, qw.shape[-1]).astype(jnp.float32)
-    rows = jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0) // group_size
-    s = jnp.take_along_axis(s_ref[...].astype(jnp.float32), rows, axis=0)
-    z = jnp.take_along_axis(z_ref[...].astype(jnp.float32), rows, axis=0)
+    q = _unpack(qw_ref, bk)
+    s = _expand_groups(s_ref, group_size)
+    z = _expand_groups(z_ref, group_size)
     o_ref[...] = ((q - z) * s).astype(o_ref.dtype)
 
 
 def dequantize_ordered(
     qweight: jax.Array, scales: jax.Array, zeros: jax.Array, *,
     group_size: int, block_n: int = 256, block_k: int | None = None,
-    out_dtype=jnp.float32, interpret: bool = True,
+    out_dtype=jnp.float32, interpret: bool,
 ) -> jax.Array:
     k = qweight.shape[0] * PACK
     n = qweight.shape[1]
     bk = block_k or pick_block_k(k, group_size)
-    bn = min(block_n, n)
-    while bn > 1 and n % bn:
-        bn //= 2
-    if n % bn or k % bk:
-        raise ValueError(f"bad tiling k={k},n={n} bk={bk},bn={bn}")
+    # the widest 128-multiple N-tile up to block_n that divides N, else N
+    bn = next((b for b in range(block_n - block_n % 128, 0, -128)
+               if n % b == 0), n)
+    # no x operand: check the weight-side blocks under a one-tile M
+    check_tiling(PACK, k, n, group_size, PACK, bn, bk)
     kernel = functools.partial(_dequant_kernel, group_size=group_size, bk=bk)
     return pl.pallas_call(
         kernel,

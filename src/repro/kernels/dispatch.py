@@ -27,16 +27,13 @@ Seed entries (see DESIGN.md §1):
   ``CollectivePlan`` opts in via a ``:fused`` quant spec and
   ``schemes._pair_local_forward`` calls ``qmatmul_wire``.
 
-The pallas entries degrade gracefully (the ``ExecutionPolicy.auto``
-contract): when a site's K cannot tile the Pallas grid (``pick_block_k``
-would raise), they fall back to the ``jnp`` kernel with a one-line
-warning instead of erroring at forward time.
+A site the Pallas kernels cannot tile raises ``ValueError`` with the
+reason (``dequant_matmul.check_tiling``) on every platform: the pallas
+backend never swaps in another kernel behind the caller's back.
 """
 
 from __future__ import annotations
 
-import warnings
-from math import gcd
 from typing import Callable, Optional
 
 import jax
@@ -140,18 +137,21 @@ def wire_support(ql: QuantizedLinear, spec, tp: int) -> tuple[bool, str]:
 
 
 # ---------------------------------------------------------------------------
-# graceful Pallas fallback (non-tileable K -> jnp with a one-line warning)
+# Pallas tileability
 # ---------------------------------------------------------------------------
 
 def _tileable(ql: QuantizedLinear) -> tuple[bool, str]:
-    """Can the Pallas grid tile this layout's K?  Mirrors the constraints
-    ``dequant_matmul.pick_block_k`` (ordered: K % lcm(group_size, 8)) and
-    the g_idx kernel's power-of-two halving enforce."""
+    """Can the Pallas grid tile this layout's K?  ``(True, "")`` or
+    ``(False, why)``.  Ordered: exactly ``dequant_matmul.pick_block_k``'s
+    precondition (it then always returns a legal K-tile, the whole K at
+    worst).  g_idx: a power-of-two K-tile that is a multiple of 8."""
+    from repro.kernels import dequant_matmul as dk
+
     if ql.kind == "ordered":
-        base = ql.group_size * PACK // gcd(ql.group_size, PACK)
-        if ql.k % base:
-            return (False, f"K={ql.k} is not a multiple of "
-                           f"lcm(group_size={ql.group_size}, {PACK})={base}")
+        try:
+            dk.pick_block_k(ql.k, ql.group_size)
+        except ValueError as e:
+            return False, str(e)
     else:
         bk = min(256, ql.k)
         while bk > 1 and ql.k % bk:
@@ -162,18 +162,11 @@ def _tileable(ql: QuantizedLinear) -> tuple[bool, str]:
     return True, ""
 
 
-_FALLBACK_WARNED: set = set()
-
-
-def _warn_fallback(ql: QuantizedLinear, reason: str) -> None:
-    key = (ql.kind, ql.k, ql.n, ql.group_size)
-    if key in _FALLBACK_WARNED:
-        return
-    _FALLBACK_WARNED.add(key)
-    warnings.warn(
-        f"pallas {ql.kind} kernel cannot tile this site ({reason}); "
-        f"falling back to the jnp backend for K={ql.k}, N={ql.n}",
-        stacklevel=3)
+def _require_tileable(ql: QuantizedLinear) -> None:
+    ok, reason = _tileable(ql)
+    if not ok:
+        raise ValueError(f"pallas {ql.kind} kernel cannot tile K={ql.k}, "
+                         f"N={ql.n}: {reason}")
 
 
 # ---------------------------------------------------------------------------
@@ -199,10 +192,7 @@ def _jnp_dequant_matmul(x, ql, policy):
 def _pallas_ordered(x, ql, policy):
     from repro.kernels import ops
 
-    ok, reason = _tileable(ql)
-    if not ok:
-        _warn_fallback(ql, reason)
-        return _jnp_dequant_matmul(x, ql, policy)
+    _require_tileable(ql)
     t = policy.tiling
     return ops.pallas_dequant_matmul_ordered(
         x, ql, compute_dtype=policy.compute_dtype,
@@ -214,10 +204,7 @@ def _pallas_ordered(x, ql, policy):
 def _pallas_gidx(x, ql, policy):
     from repro.kernels import ops
 
-    ok, reason = _tileable(ql)
-    if not ok:
-        _warn_fallback(ql, reason)
-        return _jnp_dequant_matmul(x, ql, policy)
+    _require_tileable(ql)
     t = policy.tiling
     return ops.pallas_dequant_matmul_gidx(
         x, ql, compute_dtype=policy.compute_dtype,

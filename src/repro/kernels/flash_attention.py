@@ -33,18 +33,6 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _compiler_params_cls():
-    """Mosaic compiler-params class across jax generations (renamed from
-    TPUCompilerParams on the 0.4.x line); fail loudly if neither exists."""
-    for name in ("CompilerParams", "TPUCompilerParams"):
-        cls = getattr(pltpu, name, None)
-        if cls is not None:
-            return cls
-    raise RuntimeError(
-        "unsupported jax version: pallas TPU exposes neither "
-        "CompilerParams nor TPUCompilerParams")
-
-
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
                   bq: int, bk: int, scale: float, causal: bool,
                   window: Optional[int], seq_q: int, seq_k: int):
@@ -113,7 +101,7 @@ def flash_attention(
     window: Optional[int] = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     b, h, s, d = q.shape
     t = k.shape[2]
@@ -146,8 +134,7 @@ def flash_attention(
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        # CompilerParams was TPUCompilerParams on the jax 0.4.x line
-        compiler_params=_compiler_params_cls()(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q3, k3, v3)

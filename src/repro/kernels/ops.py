@@ -4,7 +4,8 @@ Handles the impedance between model code and kernel constraints:
 * arbitrary leading batch dims (flattened to M),
 * M/N padding to tile multiples (zero-padded, sliced off),
 * dispatch on ``QuantizedLinear.kind`` (ordered vs g_idx gather),
-* interpret=True on CPU (this container), compiled Mosaic on real TPUs.
+* interpret mode, resolved by ``core.policy.interpret_mode``: compiled
+  Mosaic on a TPU, the Pallas interpreter elsewhere.
 """
 
 from __future__ import annotations
@@ -14,15 +15,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core.policy import interpret_mode
 from repro.core.quantization import PACK, QuantizedLinear
 from repro.kernels import dequant_matmul as dk
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
 
 
 def _pad_to(x: jax.Array, mult: int, axis: int) -> jax.Array:
@@ -52,8 +47,7 @@ def dequant_matmul(
 
     ``x``: (..., K).  Returns (..., N) in ``compute_dtype``.
     """
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = interpret_mode(interpret)
     *lead, k = x.shape
     if k != ql.k:
         raise ValueError(f"x K={k} != weight K={ql.k}")
@@ -61,9 +55,11 @@ def dequant_matmul(
     m = 1
     for d in lead:
         m *= d
-    x2 = x.reshape(m, k)
+    # x enters the kernel in the compute dtype, so its row block is whole
+    # (sublane-count) tiles of that dtype
+    x2 = x.reshape(m, k).astype(compute_dtype)
 
-    bm = min(block_m, max(8, m))
+    bm = min(block_m, max(dk.sublanes(compute_dtype), m))
     x2 = _pad_to(x2, bm, 0)
     bn = min(block_n, n)
     qweight, scales, zeros = ql.qweight, ql.scales, ql.zeros
@@ -116,8 +112,7 @@ def dequant_matmul_wire(
     """
     from repro.comm.wire import wire_params
 
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = interpret_mode(interpret)
     if ql.kind != "ordered":
         raise ValueError(f"wire kernel needs the ordered layout, "
                          f"got {ql.kind!r}")
@@ -186,8 +181,7 @@ def pallas_dequant_matmul_gidx(x, ql, *, compute_dtype=jnp.float32,
 def dequantize(ql: QuantizedLinear, *, out_dtype=jnp.float32,
                interpret: bool | None = None) -> jax.Array:
     """Materialize the fp weight with the standalone dequant kernel."""
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = interpret_mode(interpret)
     if ql.kind != "ordered":
         # unordered materialization has no locality to exploit; use ref path
         from repro.kernels import ref
@@ -204,8 +198,6 @@ def flash_attention(q, k, v, *, causal=True, window=None,
     """Fused flash attention (B, H, S, D); see kernels/flash_attention.py."""
     from repro.kernels import flash_attention as fa
 
-    if interpret is None:
-        interpret = not _on_tpu()
     return fa.flash_attention(q, k, v, causal=causal, window=window,
                               block_q=block_q, block_k=block_k,
-                              interpret=interpret)
+                              interpret=interpret_mode(interpret))

@@ -7,10 +7,31 @@ import; smoke tests see 1 device).
 
 from __future__ import annotations
 
+from typing import Optional, Sequence
+
 import jax
 
 SINGLE_POD = (16, 16)            # 256 chips (one v5e pod slice)
 MULTI_POD = (2, 16, 16)          # 2 pods = 512 chips
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str],
+              devices: Optional[Sequence] = None) -> jax.sharding.Mesh:
+    """The one mesh constructor: every axis is ``AxisType.Auto``.
+
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, under which
+    ``with_sharding_constraint`` refuses the ``NamedSharding`` specs the
+    models annotate with; GSPMD-style Auto axes are what every
+    ``ctx.shard`` and ``shard_map`` in this repo is written for.
+    ``devices``: the first ``prod(shape)`` of them form the grid (default:
+    all of ``jax.devices()``)."""
+    n = 1
+    for s in shape:
+        n *= s
+    devs = list(jax.devices() if devices is None else devices)[:n]
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devs)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -24,7 +45,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
         raise RuntimeError(
             f"need {n} devices for mesh {shape}, have {len(devs)} — run "
             "under launch/dryrun.py which forces 512 host devices")
-    return jax.make_mesh(shape, axes, devices=devs[:n])
+    return make_mesh(shape, axes, devs)
 
 
 def init_distributed(coordinator: str, num_processes: int,
@@ -40,10 +61,7 @@ def init_distributed(coordinator: str, num_processes: int,
     """
     if num_processes <= 1:
         return
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # pragma: no cover - flag renamed/absent on new jax
-        pass
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address=coordinator,
                                num_processes=num_processes,
                                process_id=process_id)
@@ -59,7 +77,7 @@ def make_host_mesh(model: int = 1) -> jax.sharding.Mesh:
     n = len(jax.devices())
     if n % model:
         raise ValueError(f"{n} devices not divisible by model={model}")
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 def batch_axes_for(mesh: jax.sharding.Mesh, global_batch: int) -> tuple:

@@ -173,7 +173,11 @@ def parse_overlap_windows(hlo_text: str,
     emit explicit start/done tuples, while CPU XLA keeps the
     instructions synchronous but the printed module *is* the schedule
     (``is_scheduled=true``), so instruction order between issue and
-    first use is exactly the overlap window.
+    first use is exactly the overlap window.  That holds for CPU XLA's
+    sequential scheduler only: compile with
+    ``--xla_cpu_enable_concurrency_optimized_scheduler=false`` (the
+    default concurrency-optimized one hoists independent dots ahead of
+    every collective and runs them concurrently instead).
 
     A GEMM is a ``dot`` or ``custom-call`` instruction, directly or
     transitively inside a called computation (fusions, Pallas interpret

@@ -47,6 +47,7 @@ from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.core.policy import ExecutionPolicy
 from repro.dist import MeshPlan
 from repro.launch import mesh as mesh_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.common import ParallelContext, REPLICATED
 from repro.runtime.sampling import SamplingConfig
 from repro.runtime.scheduler import Request, Scheduler
@@ -134,6 +135,10 @@ def prepare(argv=None):
                     help="target model-axis degree the shards are pre-"
                          "split for (serving must use the same)")
     ap.add_argument("--out", required=True, help="artifact directory")
+    ap.add_argument("--num-layers", type=int, default=None,
+                    help="keep only the first N layers, at full width "
+                         "(smoke runs of a large model); the manifest "
+                         "records it and serving rebuilds the same config")
     ap.add_argument("--autotune-collectives", action="store_true",
                     help="score every full-output collective per pair "
                          "site (analytic wire bytes + calibration error "
@@ -155,13 +160,16 @@ def prepare(argv=None):
         ap.error("--overlap-collectives requires --autotune-collectives")
 
     cfg = _build_cfg(args)
+    if args.num_layers is not None:
+        cfg = cfg.with_(num_layers=args.num_layers)
     # record the intended grid in the manifest (provenance: validate pins
     # only the TP degree, so serving may widen dp without re-preparing)
     policy = ExecutionPolicy.from_config(cfg).with_(
         mesh=MeshPlan(dp=1, tp=args.tp))
     t0 = time.time()
     art = compiler.prepare(cfg, tp=args.tp, seed=args.seed, policy=policy,
-                           extra_manifest={"smoke": bool(args.smoke)},
+                           extra_manifest={"smoke": bool(args.smoke),
+                                           "num_layers": cfg.num_layers},
                            autotune=args.autotune_collectives,
                            tune_budget=args.tune_budget,
                            tune_overlap=args.overlap_collectives)
@@ -179,6 +187,15 @@ def prepare(argv=None):
         print(f"  tuned {site['path']} [{site.get('kind', 'pair')}]: "
               f"{site['chosen']} ({site['status']})")
     return path
+
+
+def config_from_manifest(man: dict):
+    """The model config an artifact was prepared for, rebuilt from its
+    manifest (smoke variant and ``--num-layers`` cut included)."""
+    cfg = (get_smoke_config(man["arch_id"]) if man.get("smoke")
+           else get_config(man["arch_id"]))
+    cfg = cfg.with_(num_layers=man.get("num_layers", cfg.num_layers))
+    return cfg.with_quant(**man["quant"])
 
 
 def _load_artifact(args, *, manifest_only: bool = False):
@@ -203,9 +220,7 @@ def _load_artifact(args, *, manifest_only: bool = False):
     else:
         art = DeploymentArtifact.load(args.artifact)
     man = art.manifest
-    cfg = (get_smoke_config(man["arch_id"]) if man.get("smoke")
-           else get_config(man["arch_id"]))
-    cfg = cfg.with_quant(**man["quant"])
+    cfg = config_from_manifest(man)
     policy = art.policy()
     # cache layout is runtime-only (excluded from validate): CLI kv flags
     # override the manifest's recorded layout on the POLICY, never on cfg
@@ -289,6 +304,7 @@ def verify(argv=None):
 def main(argv=None):
     import sys
 
+    enable_compile_cache()
     argv = list(sys.argv[1:] if argv is None else argv)
     if argv and argv[0] == "prepare":
         return prepare(argv[1:])

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.core import compat, schemes
+from repro.core import schemes
 from repro.core.policy import DEFAULT_POLICY, ExecutionPolicy
 from repro.core.reorder import PlannedPair
 
@@ -302,10 +302,10 @@ def _flash_sdpa(cfg: ModelConfig, ctx: ParallelContext, q, k, v, *,
         out = local(qt, kt, vt)
     else:
         spec = P(ctx.batch_spec, ctx.model_axis, None, None)
-        out = compat.shard_map(
+        out = jax.shard_map(
             local, mesh=ctx.mesh,
             in_specs=(spec, spec, spec),
-            out_specs=spec,
+            out_specs=spec, check_vma=False,
         )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3).reshape(b, s, h * hd)
 
@@ -372,6 +372,10 @@ def attention_forward(cfg: ModelConfig, p, x, ctx: ParallelContext, *,
             positions = jnp.arange(s)
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
+    # attend over K/V in the activation dtype, exactly as decode reads them
+    # back from its cache (attention_decode): the f32 projections rounded
+    # once, so prefill and token-by-token decode compute the same function
+    k, v = k.astype(x.dtype), v.astype(x.dtype)
 
     def mask_rows(i0, rows: int):
         if not (causal and kv_x is None):
@@ -704,8 +708,34 @@ def scan_layers_cache(body, x, stacked_params, stacked_cache, ctx, extra=None):
     return y, new_cache
 
 
+#: layers one eager ``vmap`` of a layer init covers: bounds the random
+#: generator's temporaries (about 4x its output) to a few layers' worth
+INIT_CHUNK = 4
+
+
 def stack_layer_params(make_layer, rng, n: int):
     """Initialize ``n`` layers stacked along a leading dim (vmapped so a
-    100-layer full config traces one layer, not 100)."""
+    100-layer full config traces one layer, not 100).
+
+    Run eagerly (a full-width init on a host), the layers are generated
+    ``INIT_CHUNK`` at a time and written into the stacked buffers in
+    place: the same values as one vmap over all layers, at the peak
+    memory of the result plus one chunk."""
     rngs = jax.random.split(rng, n)
-    return jax.vmap(make_layer)(rngs)
+    if isinstance(rngs, jax.core.Tracer) or n <= INIT_CHUNK:
+        return jax.vmap(make_layer)(rngs)
+    out = None
+    for i in range(0, n, INIT_CHUNK):
+        part = jax.vmap(make_layer)(rngs[i:i + INIT_CHUNK])
+        if out is None:
+            out = jax.tree.map(
+                lambda a: jnp.zeros((n,) + a.shape[1:], a.dtype), part)
+        out = _write_rows(out, part, i)
+    return out
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _write_rows(buf, part, start):
+    return jax.tree.map(
+        lambda b, p: jax.lax.dynamic_update_slice_in_dim(b, p, start, 0),
+        buf, part)

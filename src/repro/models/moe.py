@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.comm import CollectiveSpec, dispatch as comm_dispatch
-from repro.core import compat, schemes
+from repro.core import schemes
 from repro.core.policy import ExecutionPolicy
 
 from repro.configs.base import ModelConfig
@@ -184,10 +184,10 @@ def moe_forward_ep(cfg: ModelConfig, p, x, ctx: ParallelContext):
                                        concat_axis=0)
         return combine(out).reshape(bl, sl, d)
 
-    y = compat.shard_map(
+    y = jax.shard_map(
         body, mesh=mesh,
         in_specs=in_specs,
-        out_specs=x_spec,
+        out_specs=x_spec, check_vma=False,
     )(x, p["router"], p["experts"])
 
     if cfg.dense_residual:

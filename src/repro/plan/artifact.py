@@ -51,12 +51,16 @@ def config_hash(cfg) -> str:
 def policy_fields(policy: ExecutionPolicy) -> dict:
     """The manifest's view of an ``ExecutionPolicy`` (strings only).
 
-    ``kv`` and ``mesh`` are recorded for provenance (so a served stats
-    endpoint and the artifact agree on what was prepared) but excluded
-    from ``validate``'s comparison: the cache layout is a pure runtime
-    decision, and the device grid may differ per deployment as long as
-    the model-axis degree matches the shards (which ``validate``'s
-    ``tp`` check pins) — an artifact prepared dp1xtp2 serves dp4xtp2.
+    ``backend``, ``kv`` and ``mesh`` are recorded for provenance (so a
+    served stats endpoint and the artifact agree on what was prepared)
+    but excluded from ``validate``'s comparison: every kernel backend
+    computes the same GEMMs over the same planned layout (``auto`` picks
+    it on the serving platform — an artifact prepared on a CPU host
+    serves with the Pallas kernels on a TPU), the cache layout is a pure
+    runtime decision, and the device grid may differ per deployment as
+    long as the model-axis degree matches the shards (which
+    ``validate``'s ``tp`` check pins) — an artifact prepared dp1xtp2
+    serves dp4xtp2.
     """
     return {
         "scheme": policy.scheme,
@@ -140,8 +144,12 @@ class DeploymentArtifact:
 
     def policy(self) -> ExecutionPolicy:
         p = self.manifest["policy"]
+        backend = p["backend"]
+        if self.manifest["quant"]["backend"] == "auto":
+            # chosen again for the platform this process serves on
+            backend = ExecutionPolicy.auto(p["scheme"]).backend
         return ExecutionPolicy(
-            scheme=p["scheme"], backend=p["backend"],
+            scheme=p["scheme"], backend=backend,
             compute_dtype=p["compute_dtype"], accum_dtype=p["accum_dtype"],
             collective=p["collective"], kv=p.get("kv", "dense"),
             mesh=p.get("mesh"))
@@ -170,7 +178,8 @@ class DeploymentArtifact:
         leaves = []
         for key in keys:
             dim = shards.get(key)
-            if dim is None:
+            # one rank holds the global leaf: no concatenated copy of it
+            if dim is None or len(flats) == 1:
                 leaves.append(flats[0][key])
             else:
                 leaves.append(jnp.concatenate(
@@ -196,11 +205,11 @@ class DeploymentArtifact:
         if policy is not None:
             want = policy_fields(policy)
             have = dict(self.manifest["policy"])
-            # cache layout and device grid are runtime-only (see
-            # policy_fields): an artifact prepared dense serves paged,
-            # and dp may differ — only the TP degree (checked below
-            # against the shards) is load-bearing
-            for k in ("kv", "mesh"):
+            # kernel backend, cache layout and device grid are
+            # runtime-only (see policy_fields): an artifact prepared
+            # dense serves paged, and dp may differ — only the TP degree
+            # (checked below against the shards) is load-bearing
+            for k in ("backend", "kv", "mesh"):
                 want.pop(k, None)
                 have.pop(k, None)
             if want != have:

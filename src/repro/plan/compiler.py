@@ -102,6 +102,26 @@ def _vmap_stacked(fn, lead: int):
     return fn
 
 
+def _quantize_stack(w_up, w_down, w_gate, rngs, *, gs_up: int, gs_down: int,
+                    act_order: bool) -> PairBundle:
+    """``quantize_pair`` over a flat ``(n, ...)`` stack, one pair at a
+    time, then stacked: the transient codes and permuted copies are one
+    layer's, not the whole stack's — what keeps a full-width prepare
+    within a host's RAM.  Op by op like the stacked ``vmap`` it replaces,
+    so the plan stays bit-identical (a jitted body may rewrite the
+    scales' division by 15 into a multiply)."""
+    def one(wu, wd, wg, r):
+        return reorder.quantize_pair(
+            wu, wd, w_gate=wg, group_size_up=gs_up, group_size_down=gs_down,
+            act_order=act_order, rng=r)
+
+    if rngs.shape[0] == 0:       # an empty stack: only the shapes matter
+        return jax.vmap(one)(w_up, w_down, w_gate, rngs)
+    out = [one(w_up[i], w_down[i], None if w_gate is None else w_gate[i],
+               rngs[i]) for i in range(rngs.shape[0])]
+    return jax.tree.map(lambda *leaves: jnp.stack(leaves), *out)
+
+
 # ---------------------------------------------------------------------------
 # stage 1: quantize
 # ---------------------------------------------------------------------------
@@ -118,30 +138,25 @@ def stage_quantize(state: PlanState) -> PlanState:
         w_up, w_down = node["w_up"], node["w_down"]
         w_gate = node.get("w_gate")
         lead = w_up.ndim - 2
+        stack = w_up.shape[:lead]
         gs_up, gs_down = _pair_group_sizes(cfg, w_up, w_down)
 
-        def q_one(*args):
-            if w_gate is None:
-                wu, wd, r = args
-                wg = None
-            else:
-                wu, wd, wg, r = args
-            return reorder.quantize_pair(
-                wu, wd, w_gate=wg, group_size_up=gs_up,
-                group_size_down=gs_down, act_order=cfg.quant.act_order,
-                rng=r)
-
         if lead == 0:
-            rngs = sub
+            rngs = sub[None]
         else:
             nstack = 1
-            for d in w_up.shape[:lead]:
+            for d in stack:
                 nstack *= d
-            rngs = jax.random.split(sub, nstack).reshape(
-                *w_up.shape[:lead], 2)
-        args = (w_up, w_down, rngs) if w_gate is None else (
-            w_up, w_down, w_gate, rngs)
-        bundle = _vmap_stacked(q_one, lead)(*args)
+            rngs = jax.random.split(sub, nstack)
+
+        def flat(w):
+            return None if w is None else w.reshape((-1,) + w.shape[lead:])
+
+        bundle = _quantize_stack(
+            flat(w_up), flat(w_down), flat(w_gate), rngs, gs_up=gs_up,
+            gs_down=gs_down, act_order=cfg.quant.act_order)
+        bundle = jax.tree.map(lambda a: a.reshape(stack + a.shape[1:]),
+                              bundle)
         # dotted paths: the SAME string the runtime epilogues resolve
         # their per-layer collective by (models pass it to mlp_forward)
         meta.append({
@@ -310,8 +325,10 @@ def shard_params(cfg: ModelConfig, params: Any, tp: int,
         if dim is not None and leaf.shape[dim] % tp == 0 \
                 and leaf.shape[dim] >= tp:
             n = leaf.shape[dim] // tp
-            parts = [jax.lax.slice_in_dim(leaf, r * n, (r + 1) * n, axis=dim)
-                     for r in range(tp)]
+            # one rank holds the whole leaf: no slice copy
+            parts = [leaf] if tp == 1 else [
+                jax.lax.slice_in_dim(leaf, r * n, (r + 1) * n, axis=dim)
+                for r in range(tp)]
             leaf_shards[key] = dim
         else:
             parts = [leaf] * tp

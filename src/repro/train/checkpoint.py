@@ -90,7 +90,7 @@ def _schema(node: Any) -> dict:
 
 
 def _from_schema(schema: dict, leaves: dict[str, np.ndarray],
-                 prefix: tuple[str, ...] = ()) -> Any:
+                 prefix: tuple[str, ...] = (), asarray=jnp.asarray) -> Any:
     from repro.core.quantization import QuantizedLinear
     from repro.core.reorder import PlannedPair
 
@@ -98,25 +98,25 @@ def _from_schema(schema: dict, leaves: dict[str, np.ndarray],
     if t == "none":
         return None
     if t == "qlinear":
-        f = {k: _from_schema(v, leaves, prefix + (k,))
+        f = {k: _from_schema(v, leaves, prefix + (k,), asarray)
              for k, v in schema["fields"].items()}
         return QuantizedLinear(group_size=schema["group_size"],
                                kind=schema["kind"], **f)
     if t == "pair":
-        f = {k: _from_schema(v, leaves, prefix + (k,))
+        f = {k: _from_schema(v, leaves, prefix + (k,), asarray)
              for k, v in schema["fields"].items()}
         return PlannedPair(scheme=schema["scheme"], **f)
     if t == "dict":
-        return {k: _from_schema(v, leaves, prefix + (k,))
+        return {k: _from_schema(v, leaves, prefix + (k,), asarray)
                 for k, v in schema["keys"].items()}
     if t in ("list", "tuple"):
-        items = [_from_schema(v, leaves, prefix + (str(i),))
+        items = [_from_schema(v, leaves, prefix + (str(i),), asarray)
                  for i, v in enumerate(schema["items"])]
         return items if t == "list" else tuple(items)
     key = _SEP.join(prefix)
     if key not in leaves:
         raise KeyError(f"checkpoint missing leaf {key}")
-    return jnp.asarray(leaves[key], dtype=schema["dtype"])
+    return asarray(leaves[key], dtype=schema["dtype"])
 
 
 def save(path: str, tree: Any, *, step: int | None = None) -> str:
@@ -135,11 +135,15 @@ def save(path: str, tree: Any, *, step: int | None = None) -> str:
     return path
 
 
-def load(path: str) -> Any:
+def load(path: str, *, host: bool = False) -> Any:
     """Template-free restore: rebuild the exact saved pytree — including
     quantized-plan statics (scheme / group_size / kind) — from the schema
     ``save`` embedded.  Raises on checkpoints written before the schema
-    existed (use ``restore`` with a template for those)."""
+    existed (use ``restore`` with a template for those).
+
+    ``host=True`` leaves the leaves as numpy arrays in host memory, for a
+    caller that places each one itself; by default they are ``jax.Array``
+    leaves on the default device."""
     with np.load(path) as data:
         if _TREE_KEY not in data:
             raise ValueError(
@@ -151,7 +155,8 @@ def load(path: str) -> Any:
                 f"checkpoint {path} schema v{meta['version']} != "
                 f"supported v{_SCHEMA_VERSION}")
         leaves = {k: data[k] for k in data.files if k != _TREE_KEY}
-    return _from_schema(meta["tree"], leaves)
+    return _from_schema(meta["tree"], leaves,
+                        asarray=np.asarray if host else jnp.asarray)
 
 
 def restore(path: str, template: Any) -> Any:

@@ -189,7 +189,8 @@ def test_site_sweep_measured_equals_analytic():
     code = (
         "import os\n"
         "os.environ['XLA_FLAGS'] = "
-        "'--xla_force_host_platform_device_count=8'\n"
+        "'--xla_force_host_platform_device_count=8 "
+        "--xla_cpu_enable_concurrency_optimized_scheduler=false'\n"
         "from repro.analysis import hlo_lint\n"
         "fs = hlo_lint.run_site_sweep(tps=(2, 4, 8),"
         " specs=hlo_lint.SWEEP_SPECS)\n"

@@ -27,8 +27,11 @@ _ROOT = os.path.join(os.path.dirname(__file__), "..")
 
 def _run(script: str, devices: int = 8):
     env = dict(os.environ)
+    # sequential CPU schedule: the overlap-window tests read the printed
+    # instruction order as the program order (roofline.parse_overlap_windows)
     env["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={devices}")
+        f"--xla_force_host_platform_device_count={devices} "
+        "--xla_cpu_enable_concurrency_optimized_scheduler=false")
     env["PYTHONPATH"] = os.path.join(_ROOT, "src")
     p = subprocess.run([sys.executable, "-c", textwrap.dedent(script)],
                        capture_output=True, text=True, env=env, timeout=560)
@@ -82,7 +85,8 @@ def test_mesh_plan_geometry_and_policy_field():
 def test_single_device_mesh_local_ranks():
     from repro.dist import local_model_ranks
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"),
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"),
                          devices=jax.devices()[:1])
     assert local_model_ranks(mesh) == (0,)
     assert MeshPlan(dp=1, tp=1).local_model_ranks(mesh) == (0,)
@@ -312,7 +316,8 @@ def test_overlap_epilogue_bit_identical_and_spans_gemm_all_tp():
         x = jax.random.normal(jax.random.PRNGKey(1), (8, 64))
 
         for tp in (2, 4, 8):
-            mesh = jax.make_mesh((8 // tp, tp), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((8 // tp, tp), ("data", "model"))
             for base in ("quant-int8:32", "quant-int4:32",
                          "quant-int8:32:fused", "quant-int4:32:fused"):
                 outs, spans = {}, {}

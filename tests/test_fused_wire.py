@@ -23,7 +23,6 @@ import os
 import subprocess
 import sys
 import textwrap
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -165,23 +164,6 @@ def test_supports_wire_gating():
     assert not kdispatch.supports_wire(_ragged_ql(), q8, 2)
 
 
-def test_pallas_backend_falls_back_on_untileable_k():
-    """S1: the pallas backend warns once and runs the jnp kernel when the
-    grid cannot tile K, instead of raising at forward time."""
-    ql = _ragged_ql()                    # K=24, lcm(16, 8)=16 -> untileable
-    x = jax.random.normal(jax.random.PRNGKey(3), (4, 24))
-    pol = ExecutionPolicy(backend="pallas")
-    kdispatch._FALLBACK_WARNED.clear()
-    with pytest.warns(UserWarning, match="falling back to the jnp backend"):
-        y = kdispatch.qmatmul(x, ql, pol)
-    y_ref = kdispatch.qmatmul(x, ql, ExecutionPolicy(backend="jnp"))
-    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), atol=1e-5)
-    # warn-once: a second call is silent
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        kdispatch.qmatmul(x, ql, pol)
-
-
 def test_wire_backend_rejected_as_policy_backend():
     ql = _ordered_ql(64, 32, 32)
     x = jnp.zeros((2, 64))
@@ -199,7 +181,8 @@ def test_fused_spec_unfusable_site_warns_and_matches_plain():
         jax.random.normal(r[0], (32, 64)) * 0.1,
         jax.random.normal(r[1], (64, 32)) * 0.1,
         scheme="tp-aware", group_size_up=32, group_size_down=32, rng=r[2])
-    mesh = jax.make_mesh((1, 1), ("data", "model"),
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"),
                          devices=jax.devices()[:1])
     x = jax.random.normal(jax.random.PRNGKey(5), (4, 32))
     fused_pol = ExecutionPolicy(collective="quant-int8:128:fused")
@@ -235,7 +218,8 @@ def test_fused_epilogue_tp_bit_identical_and_same_wire_bytes():
         x = jax.random.normal(jax.random.PRNGKey(1), (8, 64))
 
         for tp, short in ((4, "quant-int8:32"), (2, "quant-int4:32")):
-            mesh = jax.make_mesh((1, tp), ("data", "model"),
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((1, tp), ("data", "model"),
                                  devices=jax.devices()[:tp])
             outs, bytes_ = {}, {}
             for tag, coll in (("plain", short),

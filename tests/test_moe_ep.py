@@ -30,7 +30,8 @@ def test_moe_ep_matches_reference_no_drops():
         from repro.models.registry import build_model
         from repro.models.common import ParallelContext, REPLICATED
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         for aid in ("qwen3-moe-235b-a22b", "arctic-480b"):
             cfg = get_smoke_config(aid).with_(capacity_factor=64.0)
             m = build_model(cfg)
@@ -58,7 +59,8 @@ def test_moe_ep_emits_all_to_all():
         from repro.models.registry import build_model
         from repro.models.common import ParallelContext
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = get_smoke_config("qwen3-moe-235b-a22b")
         m = build_model(cfg)
         params = m.init(jax.random.PRNGKey(0))
@@ -87,7 +89,8 @@ def test_moe_within_expert_collective_resolves_from_plan():
         from repro.models.registry import build_model
         from repro.models.common import ParallelContext
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = get_smoke_config("qwen3-moe-235b-a22b").with_(
             capacity_factor=64.0)
         m = build_model(cfg)

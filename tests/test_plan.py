@@ -50,9 +50,11 @@ def _assert_trees_equal(a, b):
 # checkpoint round-trip of quantized pytrees
 # ---------------------------------------------------------------------------
 
-def test_checkpoint_quantized_roundtrip(tmp_path):
+@pytest.mark.parametrize("host", [False, True])
+def test_checkpoint_quantized_roundtrip(tmp_path, host):
     """save -> template-free load -> bit-identical PlannedPair.forward,
-    statics (scheme / group_size / kind) included."""
+    statics (scheme / group_size / kind) included; ``host=True`` keeps
+    the leaves numpy arrays in host memory."""
     from repro.core import reorder
 
     rng = jax.random.PRNGKey(0)
@@ -64,7 +66,9 @@ def test_checkpoint_quantized_roundtrip(tmp_path):
         scheme="tp-aware", group_size_up=32, group_size_down=32, rng=rng)
     tree = {"layers": {"mlp": pp}, "scale": jnp.ones((4,))}
     path = checkpoint.save(str(tmp_path / "plan"), tree)
-    loaded = checkpoint.load(path)
+    loaded = checkpoint.load(path, host=host)
+    leaf_type = np.ndarray if host else jax.Array
+    assert all(isinstance(x, leaf_type) for x in jax.tree.leaves(loaded))
 
     lpp = loaded["layers"]["mlp"]
     assert isinstance(lpp, PlannedPair)
@@ -229,6 +233,44 @@ def test_artifact_rejects_mismatched_plan(tmp_path):
         art.validate(cfg=cfg.with_(d_ff=cfg.d_ff * 2))
     with pytest.raises(PlanMismatchError, match="compiled for"):
         art.validate(cfg=dataclasses.replace(cfg, arch_id="other"))
+
+
+def test_artifact_backend_is_chosen_where_served(tmp_path, monkeypatch):
+    """``backend=auto`` resolves on the serving platform, not where the
+    plan was prepared: an artifact prepared on this CPU host (jnp) loads
+    under a TPU's Pallas policy, and its own policy picks Pallas there."""
+    from repro.core import policy as policy_mod
+
+    cfg = _smoke_cfg()
+    assert cfg.quant.backend == "auto"
+    art_dir = str(tmp_path / "artifact")
+    _prepare(cfg, tp=2).save(art_dir)
+    art = DeploymentArtifact.load(art_dir)
+    assert art.manifest["policy"]["backend"] == "jnp"
+    monkeypatch.setattr(policy_mod, "platform_is_tpu", lambda: True)
+    served = ExecutionPolicy.from_config(cfg)
+    assert served.backend == "pallas"
+    art.validate(cfg=cfg, policy=served, tp=2)
+    assert art.policy() == served
+
+
+def test_prepare_cli_num_layers_cut(tmp_path):
+    """``serve prepare --num-layers N`` plans only the first N layers; the
+    manifest records the cut and serving rebuilds that config, so the
+    config hash still matches."""
+    from repro.launch import serve
+
+    art_dir = str(tmp_path / "artifact")
+    serve.prepare(["--arch", "qwen3-4b", "--smoke", "--num-layers", "1",
+                   "--out", art_dir])
+    man = DeploymentArtifact.load_manifest(art_dir)
+    assert man["num_layers"] == 1
+    (pair,) = man["pairs"]
+    assert pair["stacked"] == [1]
+    cfg = serve.config_from_manifest(man)
+    assert cfg.num_layers == 1
+    assert cfg.d_model == _smoke_cfg().d_model
+    DeploymentArtifact.load(art_dir).validate(cfg=cfg)
 
 
 def test_engine_refuses_mismatched_artifact(tmp_path):

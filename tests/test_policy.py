@@ -213,16 +213,16 @@ def test_engine_injects_policy_into_ctx():
 
 def test_tiling_flows_to_kernel():
     """KernelTiling is part of the policy and reaches the pallas wrapper."""
-    pp, x = _mk_pair(5, 128, 128, 64, 32, "tp-aware", gate=False)
+    pp, x = _mk_pair(5, 512, 512, 64, 32, "tp-aware", gate=False)
     pol = ExecutionPolicy(backend="pallas", tiling=KernelTiling(
-        block_m=32, block_n=64, block_k=64, interpret=True))
+        block_m=32, block_n=128, block_k=256, interpret=True))
     y = pp.forward(x, pol)
     y_ref = pp.forward(x, ExecutionPolicy(backend="ref"))
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                rtol=1e-4, atol=1e-3)
     # block_k is honored, not silently dropped: an un-tileable K errors
     bad = ExecutionPolicy(backend="pallas", tiling=KernelTiling(
-        block_m=32, block_n=64, block_k=48, interpret=True))
+        block_m=32, block_n=128, block_k=48, interpret=True))
     with pytest.raises(ValueError, match="bad tiling"):
         jax.block_until_ready(pp.forward(x, bad))
 

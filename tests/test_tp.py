@@ -39,7 +39,8 @@ def test_tp_schemes_match_reference():
         x = jax.random.normal(r[3], (m, k1))
 
         for tp, dp in ((2, 4), (4, 2), (8, 1)):
-            mesh = jax.make_mesh((dp, tp), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((dp, tp), ("data", "model"))
             for scheme in reorder.SCHEMES:
                 pp = reorder.plan_pair(
                     w_up, w_down, w_gate=w_gate, scheme=scheme,
@@ -67,7 +68,8 @@ def test_tp_model_forward_matches_single_device():
         from repro.models.registry import build_model
         from repro.models.common import ParallelContext, REPLICATED
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         for aid in ("granite-3-8b", "rwkv6-3b"):
             cfg = get_smoke_config(aid)
             m = build_model(cfg)
@@ -90,7 +92,8 @@ def test_multipod_mesh_constructs():
         from repro.launch import mesh as mesh_lib
         # 8 host devices: build a small (2, 2, 2) pod/data/model mesh the
         # same way the production (2, 16, 16) one is built.
-        m = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+        from repro.launch.mesh import make_mesh
+        m = make_mesh((2, 2, 2), ("pod", "data", "model"),
                           devices=jax.devices()[:8])
         assert m.axis_names == ("pod", "data", "model")
         assert mesh_lib.batch_axes_for(m, 8) == ("pod", "data")

@@ -1,0 +1,102 @@
+"""Compile the served path for a described TPU v5e chip (no chip needed).
+
+The TPU compiler is installed next to the CPU backend, so the kernels of
+the main path and the whole decode step compile here for a ``v5e:2x2``
+topology that is described, not attached.  That catches what the Pallas
+interpreter cannot: blocks off the (8, 128) grid, casts Mosaic lacks, too
+much VMEM, a step that does not fit the chip's HBM.
+
+The topology is described inside a module fixture (never at import): only
+one process may load the TPU library at a time, and every test worker
+imports this file.  Keep these compiles in this one file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+#: HBM of one v5e chip as its compiler reports it (15.75 GiB).
+V5E_HBM_BYTES = int(15.75 * 2**30)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache off meanwhile
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _on(sharding, tree):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+# qwen3-4b's MLP GEMMs under the tp-aware plan: the up/gate GEMM
+# (K=d_model, gs 128) and the down GEMM (K=d_ff, gs 76 = the group size
+# that tiles d_ff / tp_groups), whole and as one rank's shard at tp 4.
+@pytest.mark.parametrize("k,n,gs", [(2560, 9728, 128), (9728, 2560, 76),
+                                    (2432, 2560, 76)],
+                         ids=["up", "down", "down-tp4"])
+@pytest.mark.parametrize("m", [8, 128])
+def test_ordered_gemm_compiles_at_qwen3_4b_widths(one_chip, m, k, n, gs):
+    from repro.kernels import dequant_matmul as dk
+
+    g = k // gs
+    args = _on(one_chip, (jax.ShapeDtypeStruct((m, k), jnp.float32),
+                          jax.ShapeDtypeStruct((k // 8, n), jnp.uint32),
+                          jax.ShapeDtypeStruct((g, n), jnp.float32),
+                          jax.ShapeDtypeStruct((g, n), jnp.float32)))
+    fn = jax.jit(lambda x, q, s, z: dk.dequant_matmul_ordered(
+        x, q, s, z, group_size=gs, block_m=m, interpret=False))
+    hlo = fn.lower(*args).compile().as_text()
+    assert "tpu_custom_call" in hlo
+
+
+def test_qwen3_4b_decode_step_compiles_for_one_chip(one_chip):
+    """The full-width decode step the server runs (batch 8, max_seq 1024,
+    int4 tp-aware MLPs on the Pallas backend, compiled) fits one chip."""
+    from repro.configs import get_config
+    from repro.core.policy import ExecutionPolicy, KernelTiling
+    from repro.models.common import ParallelContext
+    from repro.models.registry import build_model
+
+    cfg = get_config("qwen3-4b").with_quant(mode="mlp", scheme="tp-aware")
+    model = build_model(cfg)
+    policy = ExecutionPolicy(scheme="tp-aware", backend="pallas",
+                             tiling=KernelTiling(interpret=False))
+    ctx = ParallelContext(policy=policy)
+    batch, max_seq = 8, 1024
+    params = _on(one_chip, jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = _on(one_chip, jax.eval_shape(
+        lambda: model.init_cache(batch, max_seq)))
+    tokens, pos = _on(one_chip, (
+        jax.ShapeDtypeStruct((batch,), jnp.int32),
+        jax.ShapeDtypeStruct((batch,), jnp.int32)))
+
+    def decode(p, c, t, q):
+        return model.decode_step(p, c, t, q, ctx)
+
+    compiled = jax.jit(decode, donate_argnums=1).lower(
+        params, cache, tokens, pos).compile()
+    # gate, up and down of the scanned layer body
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < V5E_HBM_BYTES
