@@ -116,10 +116,12 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, pos,
     x = cm.embed_tokens(cfg, params["embed"], tokens[:, None], ctx)
 
     def body(x, lp, lc, _):
-        h, nc = cm.attention_decode(cfg, lp["attn"],
-                                    cm.apply_norm(cfg, lp["ln1"], x),
-                                    lc, pos, ctx, window=window, pages=pages,
-                                    vo=lp.get("attn_vo"))
+        xn = cm.apply_norm(cfg, lp["ln1"], x)
+        # names the attention ops in the compiled step's op_name metadata
+        with jax.named_scope("attention"):
+            h, nc = cm.attention_decode(cfg, lp["attn"], xn, lc, pos, ctx,
+                                        window=window, pages=pages,
+                                        vo=lp.get("attn_vo"))
         x = x + h
         h = cm.mlp_forward(cfg, lp["mlp"], cm.apply_norm(cfg, lp["ln2"], x),
                            ctx, path="layers.mlp")

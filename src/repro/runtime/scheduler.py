@@ -69,6 +69,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.runtime import sampling
 from repro.runtime.serve import Engine
@@ -137,6 +138,10 @@ class Scheduler:
         self._slots: list[Optional[_Slot]] = []
         self._dirty: list[bool] = []   # slot lanes a retired request used
         self._step_no = 0
+        #: lane-steps that fed a prompt token whose logits were discarded
+        #: (prompt replay), and lane-steps that emitted a token
+        self.lanes_replay = 0
+        self.lanes_emit = 0
         self._cache_builds = 0
         self.manager = None
         if engine.uses_page_table:
@@ -237,11 +242,40 @@ class Scheduler:
         Returns a ``StepEvent`` per request that emitted a token or was
         retired this step.  Safe to call with an empty engine (returns
         ``[]`` without touching the device).
+
+        Each phase runs inside a profiler span named ``serve.<phase>``
+        that carries the step number as ``step``; the spans are flat, so
+        a trace attributes device idle time to the phase the host was in.
         """
         if not self.engine.supports_continuous:
             raise RuntimeError(
                 f"family '{self.engine.model.cfg.family}' does not support "
                 "token-granularity stepping (batch-drain only) — use run()")
+        n = self._step_no
+        with TraceAnnotation("serve.admit", step=n):
+            events = self._admit()
+        if not any(self._slots):
+            return events
+        with TraceAnnotation("serve.prepare", step=n):
+            args, keys, (temperature, top_p, top_k) = self._prepare()
+        with TraceAnnotation("serve.decode", step=n):
+            logits, self._cache = self.engine._decode(
+                self.engine.params, self._cache, *args)
+        # the sampling inputs go up after the decode dispatch, while the
+        # device runs it
+        with TraceAnnotation("serve.sample", step=n):
+            sampled = sampling.sample_slots(
+                jnp.stack(keys), logits, jnp.asarray(temperature),
+                jnp.asarray(top_p), jnp.asarray(top_k))
+        with TraceAnnotation("serve.readback", step=n):
+            sampled = np.asarray(sampled)
+        with TraceAnnotation("serve.update", step=n):
+            self._update(sampled, events)
+        return events
+
+    def _admit(self) -> list[StepEvent]:
+        """Build the cache on first use, retire cancelled requests, and
+        admit queued ones into free slots."""
         b = self.max_batch
         if self._cache is None:
             if self.manager is not None:
@@ -311,10 +345,14 @@ class Scheduler:
                 slots[i] = _Slot(req=req, key=self._request_key(req),
                                  fed=fed0)
                 self.admissions.append((self._step_no, req.rid))
+        return events
 
-        if not any(slots):
-            return events
-
+    def _prepare(self):
+        """The step's inputs: ``(decode args after params and cache, on
+        the device; per-slot sample keys; per-slot temperature, top-p and
+        top-k, on the host)``."""
+        slots = self._slots
+        b = self.max_batch
         tokens = np.zeros((b,), np.int32)
         pos = np.zeros((b,), np.int32)
         temperature = np.zeros((b,), np.float32)
@@ -344,22 +382,19 @@ class Scheduler:
             else:
                 keys.append(s.key)
 
+        args = [jnp.asarray(tokens), jnp.asarray(pos)]
         if self.manager is not None:
             for i, s in enumerate(slots):
                 if s is not None:
                     self.manager.ensure(i, s.fed)   # page for this scatter
-            logits, self._cache = self.engine._decode(
-                self.engine.params, self._cache, jnp.asarray(tokens),
-                jnp.asarray(pos), jnp.asarray(self.manager.table()))
-        else:
-            logits, self._cache = self.engine._decode(
-                self.engine.params, self._cache, jnp.asarray(tokens),
-                jnp.asarray(pos))
-        sampled = np.asarray(sampling.sample_slots(
-            jnp.stack(keys), logits, jnp.asarray(temperature),
-            jnp.asarray(top_p), jnp.asarray(top_k)))
+            args.append(jnp.asarray(self.manager.table()))
+        return args, keys, (temperature, top_p, top_k)
 
-        for i, s in enumerate(slots):
+    def _update(self, sampled: np.ndarray, events: list[StepEvent]):
+        """Advance every live slot past the token it fed, record what
+        it emitted, retire finished requests and count the lane-steps."""
+        replay = emit = 0
+        for i, s in enumerate(self._slots):
             if s is None:
                 continue
             s.fed += 1
@@ -369,6 +404,7 @@ class Scheduler:
             if s.fed >= s.req.prompt.size:
                 # this step consumed the prompt's last token (or a
                 # generated one): its logits yield the next token
+                emit += 1
                 s.last = int(sampled[i])
                 s.req.output.append(s.last)
                 final = len(s.req.output) >= s.req.max_new_tokens
@@ -377,8 +413,11 @@ class Scheduler:
                     s.req.done = True
                     self.finished[s.req.rid] = s.req
                     self._retire_slot(i)  # retired: refill next step
+            else:
+                replay += 1
+        self.lanes_replay += replay
+        self.lanes_emit += emit
         self._step_no += 1
-        return events
 
     def _retire_slot(self, i: int):
         """Free slot ``i``'s lane: paged mode returns its pages (shared

@@ -32,6 +32,7 @@ from collections import deque
 from typing import Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.runtime.scheduler import Request, Scheduler
 from repro.serving.queue import AdmissionQueue
@@ -220,64 +221,83 @@ class EngineLoop:
         return sched.max_batch - sched.live_slots - len(sched.queue)
 
     def _run(self):
+        # flat profiler spans, each carrying the engine step number:
+        # serve.admit, serve.wait or Scheduler.step's phases, serve.emit
         sched = self.scheduler
         while not self._stop:
-            # admit from the wait line only when a slot can take it (and,
-            # in paged mode, only when the head's worst-case page
-            # reservation fits — it stays parked in _pending, not the
-            # scheduler queue, so /v1/stats queue depth remains the real
-            # backlog and the bounded wait line 429s under pressure)
-            while self._free_capacity() > 0:
-                stream = self._pending or self.admission.pop(timeout=0)
-                self._pending = None
-                if stream is None:
-                    break
-                if stream.request.cancelled:
-                    self._finalize(stream, "cancelled")
-                    continue
-                if not sched.can_admit(stream.request):
-                    self._pending = stream
-                    break
-                stream.started = time.monotonic()
-                sched.submit(stream.request)
-                self.admitted += 1
-
+            n = sched._step_no
+            with TraceAnnotation("serve.admit", step=n):
+                self._admit()
             if not sched.has_work:
-                now = time.monotonic()
-                if self._idle_since is None:
-                    self._idle_since = now
-                elif (self._pending is None
-                        and now - self._idle_since >= self.cache_idle):
-                    if sched.release_cache():
-                        self._idle_since = now
-                self._wake.wait(self.idle_wait)
-                self._wake.clear()
+                with TraceAnnotation("serve.wait", step=n):
+                    self._idle()
                 continue
             self._idle_since = None
+            events = sched.step()
+            with TraceAnnotation("serve.emit", step=n):
+                self._emit(events)
 
-            for ev in sched.step():
-                with self._lock:
-                    stream = self._streams.get(ev.rid)
-                if stream is None:        # already finalized (races are
-                    continue              # benign: events are terminal)
-                if ev.cancelled:
-                    self._finalize(stream, "cancelled")
-                    continue
-                now = time.monotonic()
-                if stream.first_token is None:
-                    stream.first_token = now
-                    self._ttft_ms.append(1e3 * (now - stream.submitted))
-                else:
-                    itl = 1e3 * (now - stream.last_token)
-                    stream.itl_ms.append(itl)
-                    self._itl_ms.append(itl)
-                stream.last_token = now
-                self.tokens_out += 1
-                index = len(stream.request.output) - 1
-                stream.events.put(("token", {"index": index,
-                                             "token": ev.token}))
-                if ev.final:
-                    self._finalize(stream, "length")
+    def _admit(self):
+        """Move requests from the wait line into the scheduler while a
+        slot can take them.  In paged mode only when the head's
+        worst-case page reservation fits: it stays parked in
+        ``_pending``, not the scheduler queue, so /v1/stats queue depth
+        remains the real backlog and the bounded wait line 429s under
+        pressure."""
+        sched = self.scheduler
+        while self._free_capacity() > 0:
+            stream = self._pending or self.admission.pop(timeout=0)
+            self._pending = None
+            if stream is None:
+                break
+            if stream.request.cancelled:
+                self._finalize(stream, "cancelled")
+                continue
+            if not sched.can_admit(stream.request):
+                self._pending = stream
+                break
+            stream.started = time.monotonic()
+            sched.submit(stream.request)
+            self.admitted += 1
+
+    def _idle(self):
+        """No work: release the cache after ``cache_idle`` seconds, then
+        wait for a request."""
+        now = time.monotonic()
+        if self._idle_since is None:
+            self._idle_since = now
+        elif (self._pending is None
+                and now - self._idle_since >= self.cache_idle):
+            if self.scheduler.release_cache():
+                self._idle_since = now
+        self._wake.wait(self.idle_wait)
+        self._wake.clear()
+
+    def _emit(self, events):
+        """Stamp each step event and post it to its request's queue."""
+        for ev in events:
+            with self._lock:
+                stream = self._streams.get(ev.rid)
+            if stream is None:        # already finalized (races are
+                continue              # benign: events are terminal)
+            if ev.cancelled:
+                self._finalize(stream, "cancelled")
+                continue
+            now = time.monotonic()
+            if stream.first_token is None:
+                stream.first_token = now
+                self._ttft_ms.append(1e3 * (now - stream.submitted))
+            else:
+                itl = 1e3 * (now - stream.last_token)
+                stream.itl_ms.append(itl)
+                self._itl_ms.append(itl)
+            stream.last_token = now
+            self.tokens_out += 1
+            index = len(stream.request.output) - 1
+            stream.events.put(("token", {"index": index,
+                                         "token": ev.token}))
+            if ev.final:
+                self._finalize(stream, "length")
 
     def _finalize(self, stream: Stream, reason: str):
         with self._lock:
@@ -315,6 +335,8 @@ class EngineLoop:
                 "prompt_budget": sched.prompt_budget,
                 "max_seq": sched.engine.max_seq,
                 "steps": sched._step_no,
+                "lanes_replay": sched.lanes_replay,
+                "lanes_emit": sched.lanes_emit,
             },
             "requests": {
                 "admitted": self.admitted,
@@ -322,11 +344,7 @@ class EngineLoop:
                 "cancelled": self.cancelled,
                 "in_flight": in_flight,
             },
-            "tokens": {
-                "generated": self.tokens_out,
-                "per_s": round(self.tokens_out / uptime, 3) if uptime
-                else 0.0,
-            },
+            "tokens": {"generated": self.tokens_out},
             "latency_ms": {
                 "ttft": _histogram(self._ttft_ms),
                 "itl": _histogram(self._itl_ms),
