@@ -318,6 +318,16 @@ Q_CHUNK = 2048
 Q_CHUNK_MIN_SEQ = 8192
 
 
+def project(x: jax.Array, w: jax.Array) -> jax.Array:
+    """``x @ w`` for a dense attention projection, accumulated and returned
+    in f32.  ``x`` is read in ``w``'s dtype: where the plan compiler stored
+    ``w`` in bf16 (``plan.compiler.stage_attention_dtype``) both operands
+    enter the MXU as bf16, as a default-precision f32 dot rounds them; with
+    an f32 ``w`` this computes what ``x @ w`` computes."""
+    return jnp.matmul(x.astype(w.dtype), w,
+                      preferred_element_type=jnp.float32)
+
+
 def _vo_project_v(vo: PlannedPair, src, policy) -> jax.Array:
     """V projection through a precompiled V->O fold (``attention_fold``):
     gather the input by P1, run the folded quantized up GEMM.  The output
@@ -353,13 +363,13 @@ def attention_forward(cfg: ModelConfig, p, x, ctx: ParallelContext, *,
     src = kv_x if kv_x is not None else x
     t = src.shape[1]
 
-    q = (x @ p["wq"]).reshape(b, s, h, hd)
-    k = (src @ p["wk"]).reshape(b, t, kvh, hd)
+    q = project(x, p["wq"]).reshape(b, s, h, hd)
+    k = project(src, p["wk"]).reshape(b, t, kvh, hd)
     if vo is not None:
         v = _vo_project_v(vo, src, ctx.execution_policy)
         v = v.reshape(b, t, kvh, hd)
     else:
-        v = (src @ p["wv"]).reshape(b, t, kvh, hd)
+        v = project(src, p["wv"]).reshape(b, t, kvh, hd)
     q = ctx.shard(q, ctx.batch_spec, None, ctx.model_axis, None)
     k = ctx.shard(k, ctx.batch_spec, None, None, None)
     v = ctx.shard(v, ctx.batch_spec, None, None, None)
@@ -444,8 +454,8 @@ def attention_decode(cfg: ModelConfig, p, x, cache, pos, ctx: ParallelContext,
     pos = jnp.asarray(pos, jnp.int32)
     per_slot = pos.ndim == 1            # (B,) per-slot clocks
 
-    q = (x @ p["wq"]).reshape(b, 1, h, hd)
-    k = (x @ p["wk"]).reshape(b, 1, kvh, hd)
+    q = project(x, p["wq"]).reshape(b, 1, h, hd)
+    k = project(x, p["wk"]).reshape(b, 1, kvh, hd)
     if vo is not None:
         # folded V channels land in the cache; every read goes through
         # vo.down whose rows expect exactly this order (see
@@ -453,7 +463,7 @@ def attention_decode(cfg: ModelConfig, p, x, cache, pos, ctx: ParallelContext,
         v = _vo_project_v(vo, x, ctx.execution_policy)
         v = v.reshape(b, 1, kvh, hd)
     else:
-        v = (x @ p["wv"]).reshape(b, 1, kvh, hd)
+        v = project(x, p["wv"]).reshape(b, 1, kvh, hd)
     if cfg.qk_norm:
         q = rms_head_norm(q, p["q_norm"], cfg.norm_eps)
         k = rms_head_norm(k, p["k_norm"], cfg.norm_eps)
@@ -526,7 +536,7 @@ def _attn_out_proj(p, out, vo: Optional[PlannedPair], ctx, dtype):
     if vo is not None:
         return schemes.qmatmul(out, vo.down,
                                ctx.execution_policy).astype(dtype)
-    return out @ p["wo"]
+    return project(out, p["wo"])
 
 
 def init_kv_cache(cfg: ModelConfig, num_layers: int, batch: int, seq_len: int,

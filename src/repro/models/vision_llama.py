@@ -207,8 +207,8 @@ def precompute_cross(cfg: ModelConfig, params, patches, ctx: ParallelContext):
 
     def per_super(sp):
         xa = sp["cross"]["xattn"]
-        k = (patches @ xa["wk"]).reshape(b, t, kvh, hd)
-        v = (patches @ xa["wv"]).reshape(b, t, kvh, hd)
+        k = cm.project(patches, xa["wk"]).reshape(b, t, kvh, hd)
+        v = cm.project(patches, xa["wv"]).reshape(b, t, kvh, hd)
         return k, v
 
     return jax.vmap(per_super)(params["super"])
@@ -234,11 +234,13 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, pos,
         x, nsc = jax.lax.scan(self_body, x, (sp["self"], sc))
         cp = sp["cross"]
         b = x.shape[0]
-        q = (cm.apply_norm(cfg, cp["ln1"], x) @ cp["xattn"]["wq"]).reshape(
+        q = cm.project(cm.apply_norm(cfg, cp["ln1"], x),
+                       cp["xattn"]["wq"]).reshape(
             b, 1, cm.head_grid(cfg)[2], cfg.head_dim)
         out = cm._sdpa(cfg, ctx, q, xk.astype(x.dtype), xv.astype(x.dtype),
                        None)
-        x = x + jnp.tanh(cp["gate_attn"]) * (out @ cp["xattn"]["wo"])
+        x = x + jnp.tanh(cp["gate_attn"]) * cm.project(out,
+                                                        cp["xattn"]["wo"])
         h = cm.mlp_forward(cfg, cp["mlp"], cm.apply_norm(cfg, cp["ln2"], x),
                            ctx, path="super.cross.mlp")
         x = x + jnp.tanh(cp["gate_mlp"]) * h

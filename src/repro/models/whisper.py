@@ -207,8 +207,8 @@ def precompute_cross(cfg: ModelConfig, params, enc, ctx: ParallelContext):
     hd = cfg.head_dim
 
     def per_layer(lp):
-        k = (enc @ lp["xattn"]["wk"]).reshape(b, t, kvh, hd)
-        v = (enc @ lp["xattn"]["wv"]).reshape(b, t, kvh, hd)
+        k = cm.project(enc, lp["xattn"]["wk"]).reshape(b, t, kvh, hd)
+        v = cm.project(enc, lp["xattn"]["wv"]).reshape(b, t, kvh, hd)
         return k, v
 
     ks, vs = jax.vmap(per_layer, in_axes=(0,))(params["dec_layers"])
@@ -238,11 +238,11 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, pos,
         # cross-attn against precomputed encoder K/V
         xa = lp["xattn"]
         b = x.shape[0]
-        q = (cm.apply_norm(cfg, lp["lnx"], x) @ xa["wq"]).reshape(
+        q = cm.project(cm.apply_norm(cfg, lp["lnx"], x), xa["wq"]).reshape(
             b, 1, cm.head_grid(cfg)[2], cfg.head_dim)
         out = cm._sdpa(cfg, ctx, q, xk.astype(x.dtype), xv.astype(x.dtype),
                        None)
-        x = x + out @ xa["wo"]
+        x = x + cm.project(out, xa["wo"])
         h = cm.mlp_forward(cfg, lp["mlp"], cm.apply_norm(cfg, lp["ln2"], x),
                            ctx, path="dec_layers.mlp")
         return (x + h).astype(carry_dtype), nc

@@ -16,16 +16,22 @@ first token*.  This module is where that decision happens — once, offline
    ``cfg.quant.attn_tp_aware`` is set, plan the V->out_proj pairs with
    the head-block-constrained fold (``core/attention_fold.py``) into the
    artifact's aux tree.
-4. ``autotune_collectives`` (``plan/tuner.py``, opt-in) — score every
+4. ``stage_attention_dtype`` — store the dense attention projections
+   (``wq``/``wk``/``wv``/``wo`` of every attention dict) in the
+   activation dtype when that is a 16-bit float: the dtype the chip's
+   default-precision matmul reads them in, so the served program has no
+   weight to convert on each call.  After the V->O fold, which plans
+   from the f32 weights.
+5. ``autotune_collectives`` (``plan/tuner.py``, opt-in) — score every
    registered full-output collective per pair site (analytic wire bytes
    + a measured activation-error probe on calibration batches) and write
    the chosen per-layer ``CollectivePlan`` into the policy.
-5. ``stage_shard``      — pre-split the planned pytree into per-rank
+6. ``stage_shard``      — pre-split the planned pytree into per-rank
    row/column shards for the target TP degree, driven by the model's own
    ``param_specs`` (any leaf whose spec names the model axis is sliced;
    non-divisible leaves stay replicated and are recorded as such).
 
-``compile_params`` runs stages 1-2 in memory — this is what
+``compile_params`` runs stages 1, 2 and 4 in memory — this is what
 ``models/registry.Model.init`` calls, so building a quantized model IS
 running the compiler (bit-exact with serving from an artifact ``prepare``d
 from the same seed).  ``compile_plan`` runs all stages and wraps the
@@ -250,7 +256,36 @@ def stage_fold_attention(state: PlanState) -> PlanState:
 
 
 # ---------------------------------------------------------------------------
-# stage 4: TP pre-shard
+# stage 4: attention projection storage dtype
+# ---------------------------------------------------------------------------
+
+#: the dense projection leaves of an attention dict
+ATTN_PROJ = ("wq", "wk", "wv", "wo")
+
+
+def stage_attention_dtype(state: PlanState) -> PlanState:
+    """Store every attention dict's projections in ``cfg.dtype`` when that
+    is a 16-bit float (round to nearest even, as the compiler's own
+    converts round); an f32 ``cfg.dtype`` keeps them f32.  The models read
+    these leaves through ``models.common.project``, which accumulates in
+    f32."""
+    dt = jnp.dtype(state.cfg.dtype)
+    if not (jnp.issubdtype(dt, jnp.floating) and dt.itemsize == 2):
+        return state
+
+    def store(node: Any) -> Any:
+        if not isinstance(node, dict):
+            return node
+        if _is_attn_dict(node):
+            return {k: v.astype(dt) if k in ATTN_PROJ else v
+                    for k, v in node.items()}
+        return {k: store(v) for k, v in node.items()}
+
+    return dataclasses.replace(state, params=store(state.params))
+
+
+# ---------------------------------------------------------------------------
+# stage 6: TP pre-shard
 # ---------------------------------------------------------------------------
 
 def _model_axis_dim(spec, axis: str) -> Optional[int]:
@@ -357,7 +392,8 @@ def stage_shard(state: PlanState) -> PlanState:
 # pipeline entry points
 # ---------------------------------------------------------------------------
 
-STAGES = (stage_quantize, stage_layout, stage_fold_attention, stage_shard)
+STAGES = (stage_quantize, stage_layout, stage_fold_attention,
+          stage_attention_dtype, stage_shard)
 
 
 def run_stages(state: PlanState, stages=STAGES) -> PlanState:
@@ -370,7 +406,7 @@ def compile_params(cfg: ModelConfig, raw_params: Any, *,
                    rng: Optional[jax.Array] = None,
                    policy: Optional[ExecutionPolicy] = None,
                    scheme: Optional[str] = None) -> Any:
-    """In-memory compile: raw fp params -> planned pytree (stages 1-2).
+    """In-memory compile: raw fp params -> planned pytree (stages 1, 2, 4).
 
     This is the single quantize/reorder call site model construction goes
     through (``Model.init``) and what ``quant/gptq.quantize_model`` wraps
@@ -383,7 +419,8 @@ def compile_params(cfg: ModelConfig, raw_params: Any, *,
     state = PlanState(
         cfg=cfg, policy=policy, params=raw_params,
         rng=rng if rng is not None else jax.random.PRNGKey(0))
-    return run_stages(state, (stage_quantize, stage_layout)).params
+    return run_stages(state, (stage_quantize, stage_layout,
+                              stage_attention_dtype)).params
 
 
 def compile_plan(cfg: ModelConfig, raw_params: Any, *, tp: int,
@@ -396,12 +433,12 @@ def compile_plan(cfg: ModelConfig, raw_params: Any, *, tp: int,
                  tune_overlap: bool = False):
     """Full offline compile: raw fp params -> ``DeploymentArtifact``.
 
-    Runs every stage (quantize, layout, attention fold, optional
-    collective autotune, TP pre-shard) and freezes the result with its
-    manifest.  ``autotune=True`` inserts ``plan/tuner.py``'s
-    ``autotune_collectives`` (max rel-error ``tune_budget``; tuner
-    default when None) so the artifact carries a per-layer
-    ``CollectivePlan`` instead of one global collective.
+    Runs every stage (quantize, layout, attention fold, attention
+    storage dtype, optional collective autotune, TP pre-shard) and
+    freezes the result with its manifest.  ``autotune=True`` inserts
+    ``plan/tuner.py``'s ``autotune_collectives`` (max rel-error
+    ``tune_budget``; tuner default when None) so the artifact carries a
+    per-layer ``CollectivePlan`` instead of one global collective.
     ``tune_overlap=True`` marks the tuner's quantized pair choices
     ``:overlap`` (decomposed compute-overlapped ring, DESIGN.md §11).
     ``seed`` is provenance only (recorded so a served artifact can name
@@ -413,7 +450,8 @@ def compile_plan(cfg: ModelConfig, raw_params: Any, *, tp: int,
     state = PlanState(
         cfg=cfg, policy=policy, params=raw_params, tp=int(tp),
         rng=rng if rng is not None else jax.random.PRNGKey(0))
-    stages = [stage_quantize, stage_layout, stage_fold_attention]
+    stages = [stage_quantize, stage_layout, stage_fold_attention,
+              stage_attention_dtype]
     if autotune:
         from repro.plan import tuner
 

@@ -26,6 +26,17 @@ from repro.models.registry import Model, build_model
 from repro.runtime import sampling
 
 
+def param_bytes(params: Any) -> dict:
+    """Bytes of the whole parameter tree (global shapes) per dtype name;
+    concrete or abstract leaves alike."""
+    out: dict = {}
+    for a in jax.tree_util.tree_leaves(params):
+        dt = jnp.dtype(a.dtype)
+        out[dt.name] = out.get(dt.name, 0) + int(np.prod(a.shape)) \
+            * dt.itemsize
+    return dict(sorted(out.items()))
+
+
 @dataclasses.dataclass
 class Engine:
     model: Model
@@ -61,6 +72,8 @@ class Engine:
                 f"policy={self.policy} but ctx.policy={self.ctx.policy}; "
                 "pass one (the ctx policy is what model code executes)")
         aux = self.aux
+        # once, here: what the served weights hold, in each dtype
+        self.param_bytes = param_bytes(self.params)
 
         def prefill_logits(params, batch):
             return mod.forward(params, batch, self.ctx, window=self.window,

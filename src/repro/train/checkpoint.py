@@ -116,7 +116,13 @@ def _from_schema(schema: dict, leaves: dict[str, np.ndarray],
     key = _SEP.join(prefix)
     if key not in leaves:
         raise KeyError(f"checkpoint missing leaf {key}")
-    return asarray(leaves[key], dtype=schema["dtype"])
+    return asarray(_typed(leaves[key], schema["dtype"]), dtype=schema["dtype"])
+
+
+def _typed(arr: np.ndarray, dtype) -> np.ndarray:
+    """``.npz`` keeps a leaf of an ml_dtypes type (bfloat16) as raw bytes
+    (``V2``): view those bytes in the saved dtype again."""
+    return arr.view(jnp.dtype(dtype)) if arr.dtype.kind == "V" else arr
 
 
 def save(path: str, tree: Any, *, step: int | None = None) -> str:
@@ -173,7 +179,8 @@ def restore(path: str, template: Any) -> Any:
                 raise ValueError(
                     f"{key}: checkpoint shape {arr.shape} != template "
                     f"{leaf.shape}")
-            out.append(jnp.asarray(arr, dtype=leaf.dtype))
+            out.append(jnp.asarray(_typed(arr, leaf.dtype),
+                                   dtype=leaf.dtype))
     return jax.tree_util.tree_unflatten(
         jax.tree_util.tree_structure(template), out)
 

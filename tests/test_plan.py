@@ -163,6 +163,103 @@ def test_attention_fold_stage():
     assert plans.up.qweight.ndim == 3
 
 
+def _attn_dicts(tree, path=()):
+    """(dotted path, dict) of every attention dict in a param tree."""
+    if isinstance(tree, dict):
+        if compiler._is_attn_dict(tree):
+            yield ".".join(path), tree
+            return
+        for k, v in tree.items():
+            yield from _attn_dicts(v, path + (k,))
+
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.view(np.uint16 if a.dtype.itemsize == 2 else np.uint32)
+
+
+@pytest.mark.parametrize("arch", ["qwen3-4b", "llama-3.2-vision-90b",
+                                  "whisper-large-v3"])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_attention_projections_stored_in_activation_dtype(arch, dtype):
+    """compile_params stores every attention dict's wq/wk/wv/wo (cross-
+    attention included) in a 16-bit ``cfg.dtype``, each leaf the raw
+    one's round-to-nearest-even bit for bit; an f32 ``cfg.dtype`` leaves
+    them as they were."""
+    cfg = _smoke_cfg(arch).with_(dtype=dtype)
+    key = jax.random.PRNGKey(0)
+    raw = build_model(cfg).init_raw(key)
+    planned = compiler.compile_params(cfg, raw, rng=key)
+    raw_attn = dict(_attn_dicts(raw))
+    got_attn = dict(_attn_dicts(planned))
+    assert raw_attn and got_attn.keys() == raw_attn.keys()
+    for path, node in got_attn.items():
+        for k, leaf in node.items():
+            want = raw_attn[path][k]
+            if k in compiler.ATTN_PROJ:
+                want = want.astype(dtype)
+            assert leaf.dtype == want.dtype, (path, k)
+            np.testing.assert_array_equal(_bits(leaf), _bits(want))
+
+
+def test_float32_config_plans_and_computes_as_before():
+    """With ``cfg.dtype`` float32 the attention-dtype stage is the
+    identity (the plan is what quantize + layout alone give), and the
+    projection helper computes what ``x @ w`` computes."""
+    from repro.models.common import project
+
+    cfg = _smoke_cfg().with_(dtype="float32")
+    key = jax.random.PRNGKey(0)
+    raw = build_model(cfg).init_raw(key)
+    state = compiler.PlanState(cfg=cfg,
+                               policy=ExecutionPolicy.from_config(cfg),
+                               params=raw, rng=key)
+    before = compiler.run_stages(
+        state, (compiler.stage_quantize, compiler.stage_layout)).params
+    planned = compiler.compile_params(cfg, raw, rng=key)
+    _assert_trees_equal(planned, before)
+    w = planned["layers"]["attn"]["wq"][0]
+    for dt in (jnp.bfloat16, jnp.float32):
+        x = jax.random.normal(key, (3, w.shape[0])).astype(dt)
+        np.testing.assert_array_equal(np.asarray(project(x, w)),
+                                      np.asarray(x @ w))
+
+
+def test_attention_fold_plans_from_f32_weights():
+    """The V->O fold runs before the projections go to bf16: its plans are
+    those the f32 weights give, bit for bit."""
+    cfg = _smoke_cfg().with_quant(attn_tp_aware=True)
+    key = jax.random.PRNGKey(0)
+    raw = build_model(cfg).init_raw(key)
+    state = compiler.PlanState(cfg=cfg,
+                               policy=ExecutionPolicy.from_config(cfg),
+                               params=raw, rng=key)
+    f32 = compiler.run_stages(state, (compiler.stage_quantize,
+                                      compiler.stage_layout,
+                                      compiler.stage_fold_attention))
+    art = compiler.compile_plan(cfg, raw, tp=1, rng=key)
+    _assert_trees_equal(art.aux["attn_plans"], f32.attn_plans)
+    (_, attn), = _attn_dicts(art.params())
+    assert attn["wv"].dtype == jnp.bfloat16
+
+
+def test_artifact_keeps_bf16_attention(tmp_path):
+    """Written and loaded back (to the device, and to host memory as the
+    per-rank loader reads it), every rank's attention projections are
+    still bf16, and the assembled tree is the in-memory plan's."""
+    cfg = _smoke_cfg()
+    art_dir = str(tmp_path / "artifact")
+    _prepare(cfg, tp=2).save(art_dir)
+    loaded = DeploymentArtifact.load(art_dir)
+    host = checkpoint.load(f"{art_dir}/rank_01.npz", host=True)
+    for tree in (*loaded.rank_params, host):
+        (_, attn), = _attn_dicts(tree)
+        assert {str(attn[k].dtype) for k in compiler.ATTN_PROJ} == \
+            {"bfloat16"}
+    _assert_trees_equal(build_model(cfg).init(jax.random.PRNGKey(0)),
+                        loaded.params())
+
+
 # ---------------------------------------------------------------------------
 # artifact round-trip: no quantization at load, bit-identical serving
 # ---------------------------------------------------------------------------

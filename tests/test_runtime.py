@@ -100,10 +100,13 @@ def test_scheduler_admits_between_decode_steps():
     last_step = max(p.size for p in prompts[:2]) + max(new[:2])
     assert admitted[2] < last_step
 
+    # alone, at the scheduler's batch width: CPU matmuls sum in an order
+    # that depends on the batch size, so a batch of one differs in the
+    # last bits and greedy near-ties may tip
     for i, (p, mn) in enumerate(zip(prompts, new)):
-        inputs = {"tokens": jnp.asarray(p)[None, :]}
+        inputs = {"tokens": jnp.asarray(np.stack([p, p]))}
         ref = np.asarray(eng.generate(
-            jax.random.PRNGKey(9), inputs, jnp.asarray([p.size]),
+            jax.random.PRNGKey(9), inputs, jnp.asarray([p.size] * 2),
             max_new_tokens=mn, scfg=greedy))[0]
         np.testing.assert_array_equal(np.asarray(done[i].output), ref,
                                       err_msg=f"req {i}")
@@ -276,3 +279,58 @@ def test_scheduler_cancel_frees_slot():
     assert done[2].cancelled and done[2].output == []
     admitted = [rid for _, rid in sched.admissions]
     assert admitted == [0, 1]   # 2 was never admitted
+
+
+@pytest.mark.parametrize("step", ["decode", "forward"])
+def test_bf16_attention_projections_return_f32(step):
+    """The plan compiler stores the attention projections in bf16: decode
+    and full-sequence attention still return f32, and agree with the same
+    computation on the same bf16 values held in f32 at HIGHEST
+    precision."""
+    from repro.models import common as cm
+    from repro.plan.compiler import ATTN_PROJ
+
+    cfg = get_smoke_config("granite-3-8b")
+    eng = make_engine(cfg, jax.random.PRNGKey(0), max_seq=16)
+    p = jax.tree.map(lambda a: a[0], eng.params["layers"]["attn"])
+    assert {p[k].dtype for k in ATTN_PROJ} == {jnp.dtype(jnp.bfloat16)}
+    p32 = {k: v.astype(jnp.float32) for k, v in p.items()}
+    kx, kc = jax.random.split(jax.random.PRNGKey(3))
+    x = jax.random.normal(kx, (2, 6, cfg.d_model)).astype(jnp.bfloat16)
+    if step == "decode":
+        kvh = cm.head_grid(cfg)[0]
+        cache = {n: jax.random.normal(k, (2, 16, kvh, cfg.head_dim))
+                 .astype(jnp.bfloat16)
+                 for n, k in zip("kv", jax.random.split(kc))}
+
+        def run(q):
+            return cm.attention_decode(cfg, q, x[:, :1], cache,
+                                       jnp.asarray([3, 9]), cm.REPLICATED)[0]
+    else:
+        def run(q):
+            return cm.attention_forward(cfg, q, x, cm.REPLICATED)
+
+    got = run(p)
+    with jax.default_matmul_precision("highest"):
+        want = run(p32)
+    assert got.dtype == want.dtype == jnp.float32
+    err = float(jnp.abs(got - want).max())
+    scale = float(jnp.abs(want).max())
+    assert err < 5e-3 * scale, err / scale
+
+
+def test_engine_loop_reports_param_bytes_per_dtype():
+    """``EngineLoop.stats()["engine"]["param_bytes"]``: the served tree's
+    bytes per dtype, the attention projections under bfloat16."""
+    from repro.plan.compiler import ATTN_PROJ
+    from repro.serving import EngineLoop
+
+    cfg = get_smoke_config("granite-3-8b")
+    eng = make_engine(cfg, jax.random.PRNGKey(0), max_seq=16)
+    attn = eng.params["layers"]["attn"]
+    want = sum(attn[k].nbytes for k in ATTN_PROJ)
+    loop = EngineLoop(Scheduler(eng, max_batch=2, prompt_budget=8))
+    got = loop.stats()["engine"]["param_bytes"]
+    assert got["bfloat16"] == want
+    assert sum(got.values()) == sum(
+        a.nbytes for a in jax.tree_util.tree_leaves(eng.params))
