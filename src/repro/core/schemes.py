@@ -239,6 +239,9 @@ def _pair_local_forward(
         use_wire, reason = kdispatch.wire_support(pp.down, spec, tp)
         if not use_wire:
             _warn_unfusable(pair_path, pp, reason)
+    # The trailing collective runs under the "epilogue" scope, which names
+    # its ops in the compiled program's op_name metadata (with ':overlap'
+    # the pipelined down GEMMs are inside it too).
     if spec.overlap:
         from repro.dist import overlap as dist_overlap
         from repro.kernels import dispatch as kdispatch
@@ -247,18 +250,21 @@ def _pair_local_forward(
         gemm_wire = (functools.partial(
             kdispatch.qmatmul_wire, ql=pp.down, policy=policy, spec=spec,
             tp=tp) if use_wire else None)
-        return dist_overlap.pipelined_epilogue(
-            y1, axis=axis, spec=spec,
-            gemm=lambda y: mm(y, pp.down), gemm_wire=gemm_wire)
+        with jax.named_scope("epilogue"):
+            return dist_overlap.pipelined_epilogue(
+                y1, axis=axis, spec=spec,
+                gemm=lambda y: mm(y, pp.down), gemm_wire=gemm_wire)
     if use_wire:
         from repro.kernels import dispatch as kdispatch
 
         tp = comm.axis_size(axis)
         wp = kdispatch.qmatmul_wire(y1, pp.down, policy, spec=spec, tp=tp)
-        return comm.apply_wire(wp, axis, spec, policy)
+        with jax.named_scope("epilogue"):
+            return comm.apply_wire(wp, axis, spec, policy)
     y2 = mm(y1, pp.down)                             # l.2 / l.5 down GEMM
     # l.6 / l.3: close the row-TP layer with the planned collective.
-    return comm.apply(y2, axis, spec, policy)
+    with jax.named_scope("epilogue"):
+        return comm.apply(y2, axis, spec, policy)
 
 
 def pair_forward_tp(

@@ -438,6 +438,12 @@ def attention_decode(cfg: ModelConfig, p, x, cache, pos, ctx: ParallelContext,
     the scheduler admits a new request into a retired slot mid-stream, so
     each slot runs its own clock).  Returns (out, new_cache).
 
+    Under a mesh whose model axis divides the KV heads
+    (``kv_heads_local``), the dense cache is sharded by KV head
+    (``kv_cache_specs``) and the cache write and the attention over it run
+    inside a ``shard_map`` over heads: each rank holds its own KV heads
+    and the query heads that read them.
+
     ``pages``: (B, Pmax) int32 per-slot page table — the cache is then a
     page *pool* {"k","v": (N_pages, page_size, KV, D)} (plus scale/zero
     leaves for quantized pages; ``repro.cache.paged``) instead of dense
@@ -496,6 +502,37 @@ def attention_decode(cfg: ModelConfig, p, x, cache, pos, ctx: ParallelContext,
         y = _attn_out_proj(p, out, vo, ctx, x.dtype)
         return ctx.shard(y, ctx.batch_spec, None, None), new_cache
 
+    if kv_heads_local(cfg, ctx):
+        # head-local: each model-axis rank writes and reads its own KV
+        # heads of the cache with the query heads that pair with them
+        # (kv-major grid), so attention needs no collective; the only one
+        # left is the wo all-reduce below
+        heads = P(ctx.batch_spec, None, ctx.model_axis, None)
+        out, new_cache = jax.shard_map(
+            functools.partial(_dense_cache_attend, cfg, REPLICATED,
+                              window=window, dtype=x.dtype),
+            mesh=ctx.mesh,
+            in_specs=(heads, heads, heads, {"k": heads, "v": heads},
+                      P(ctx.batch_spec) if per_slot else P()),
+            out_specs=(P(ctx.batch_spec, None, ctx.model_axis),
+                       {"k": heads, "v": heads}),
+            check_vma=False,
+        )(q, k, v, cache, pos)
+    else:
+        out, new_cache = _dense_cache_attend(cfg, ctx, q, k, v, cache, pos,
+                                             window=window, dtype=x.dtype)
+    y = _attn_out_proj(p, out, vo, ctx, x.dtype)
+    return ctx.shard(y, ctx.batch_spec, None, None), new_cache
+
+
+def _dense_cache_attend(cfg: ModelConfig, ctx: ParallelContext, q, k, v,
+                        cache, pos, *, window, dtype):
+    """Write the new K/V rows into the dense cache, attend over it.
+
+    q: (B, 1, H, D); k/v: (B, 1, KV, D); cache: {"k","v": (B, C, KV, D)}.
+    Returns (out (B, 1, H*D), new_cache)."""
+    b = q.shape[0]
+    per_slot = pos.ndim == 1
     cap = cache["k"].shape[1]
     slot = pos % cap if window is not None else pos
     if per_slot:
@@ -509,8 +546,8 @@ def attention_decode(cfg: ModelConfig, p, x, cache, pos, ctx: ParallelContext,
             cache["k"], k.astype(cache["k"].dtype), (0, slot, 0, 0))
         cv = jax.lax.dynamic_update_slice(
             cache["v"], v.astype(cache["v"].dtype), (0, slot, 0, 0))
-    # shard the cache along its (long) sequence dim over the model axis —
-    # KV heads may be fewer than the axis size (GQA), sequence never is.
+    # without head-local attention (KV heads fewer than, or not a multiple
+    # of, the axis size) the cache shards along its (long) sequence dim
     ck = ctx.shard(ck, ctx.batch_spec, ctx.model_axis, None, None)
     cv = ctx.shard(cv, ctx.batch_spec, ctx.model_axis, None, None)
 
@@ -527,9 +564,8 @@ def attention_decode(cfg: ModelConfig, p, x, cache, pos, ctx: ParallelContext,
     mask = jnp.broadcast_to(valid[:, None, :], (b, 1, cap))
 
     q = ctx.shard(q, ctx.batch_spec, None, ctx.model_axis, None)
-    out = _sdpa(cfg, ctx, q, ck.astype(x.dtype), cv.astype(x.dtype), mask)
-    y = _attn_out_proj(p, out, vo, ctx, x.dtype)
-    return ctx.shard(y, ctx.batch_spec, None, None), {"k": ck, "v": cv}
+    out = _sdpa(cfg, ctx, q, ck.astype(dtype), cv.astype(dtype), mask)
+    return out, {"k": ck, "v": cv}
 
 
 def _attn_out_proj(p, out, vo: Optional[PlannedPair], ctx, dtype):
@@ -557,8 +593,25 @@ def init_paged_kv_cache(cfg: ModelConfig, num_layers: int, n_pages: int,
                                 cfg.head_dim, dtype=dtype, bits=bits)
 
 
-def kv_cache_specs(cfg: ModelConfig, ctx: ParallelContext):
-    s = P(None, ctx.batch_spec, ctx.model_axis, None, None)
+def kv_heads_local(cfg: ModelConfig, ctx: ParallelContext) -> bool:
+    """Whether decode attention over the dense cache runs head-local under
+    ``ctx``'s mesh: the model axis divides the deployed KV heads, so each
+    rank holds whole KV heads and the query heads that read them."""
+    if ctx.mesh is None:
+        return False
+    kvp, _, _ = head_grid(cfg)
+    return kvp % ctx.axis_size(ctx.model_axis) == 0
+
+
+def kv_cache_specs(cfg: ModelConfig, ctx: ParallelContext, lead: int = 1):
+    """Specs of the dense cache leaves ``(*lead dims, B, C, KV, D)``: the KV
+    heads over the model axis where attention runs head-local, else the
+    sequence (KV heads may be fewer than the axis size, sequence never
+    is)."""
+    if kv_heads_local(cfg, ctx):
+        s = P(*(None,) * lead, ctx.batch_spec, None, ctx.model_axis, None)
+    else:
+        s = P(*(None,) * lead, ctx.batch_spec, ctx.model_axis, None, None)
     return {"k": s, "v": s}
 
 

@@ -194,9 +194,9 @@ def init_paged_cache(cfg: ModelConfig, batch: int, n_pages: int,
 
 
 def cache_specs(cfg: ModelConfig, ctx: ParallelContext):
-    s = P(None, None, ctx.batch_spec, ctx.model_axis, None, None)
     xs = P(None, ctx.batch_spec, None, None, None)
-    return {"self": {"k": s, "v": s}, "cross_k": xs, "cross_v": xs}
+    return {"self": cm.kv_cache_specs(cfg, ctx, lead=2),
+            "cross_k": xs, "cross_v": xs}
 
 
 def precompute_cross(cfg: ModelConfig, params, patches, ctx: ParallelContext):

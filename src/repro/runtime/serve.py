@@ -19,6 +19,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.policy import ExecutionPolicy
 from repro.models.common import ParallelContext, REPLICATED
@@ -35,6 +36,19 @@ def param_bytes(params: Any) -> dict:
         out[dt.name] = out.get(dt.name, 0) + int(np.prod(a.shape)) \
             * dt.itemsize
     return dict(sorted(out.items()))
+
+
+def _exact(spec: P, shape, mesh) -> P:
+    """``spec`` less the mesh axes that do not divide their dim: a jitted
+    output must shard exactly (a sequence-sharded cache of odd length is
+    then replicated along the sequence)."""
+    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    out = []
+    for dim, axes in zip(shape, tuple(spec) + (None,) * len(shape)):
+        names = (axes,) if isinstance(axes, str) else (axes or ())
+        out.append(axes if dim % int(np.prod([sizes[a] for a in names]))
+                   == 0 else None)
+    return P(*out)
 
 
 @dataclasses.dataclass
@@ -72,8 +86,10 @@ class Engine:
                 f"policy={self.policy} but ctx.policy={self.ctx.policy}; "
                 "pass one (the ctx policy is what model code executes)")
         aux = self.aux
-        # once, here: what the served weights hold, in each dtype
+        # once, here: what the served weights hold, in each dtype, and the
+        # model-axis size they are split over
         self.param_bytes = param_bytes(self.params)
+        self.tp = self.ctx.axis_size(self.ctx.model_axis)
 
         def prefill_logits(params, batch):
             return mod.forward(params, batch, self.ctx, window=self.window,
@@ -111,8 +127,6 @@ class Engine:
         if jax.process_count() == 1:
             return logits
         if self._replicate is None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-
             self._replicate = jax.jit(
                 lambda a: a,
                 out_shardings=NamedSharding(self.ctx.mesh, P()))
@@ -144,13 +158,20 @@ class Engine:
         return self.policy.kv.paged and self.model.supports_paged
 
     def init_cache(self, batch: int):
-        cache = self.model.init_cache(batch, self.max_seq,
-                                      window=self.window)
-        cfg = self.model.cfg
-        if cfg.family in ("audio", "vlm"):
-            # cross K/V filled at prefill (precompute_cross)
-            pass
-        return cache
+        """The dense per-slot cache; under a mesh, made in the sharding of
+        the model's ``cache_specs``, so the first donated decode call and
+        every later one see the same layout (one compile)."""
+        make = functools.partial(self.model.init_cache, batch, self.max_seq,
+                                 window=self.window)
+        if self.ctx.mesh is None:
+            return make()
+        mesh = self.ctx.mesh
+        shapes = jax.eval_shape(make)
+        shard = jax.tree.map(
+            lambda s, a: NamedSharding(mesh, _exact(s, a.shape, mesh)),
+            self.model.cache_specs(self.ctx), shapes,
+            is_leaf=lambda x: isinstance(x, P))
+        return jax.jit(make, out_shardings=shard)()
 
     def init_paged_cache(self, batch: int, n_pages: int):
         spec = self.policy.kv

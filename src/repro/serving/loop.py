@@ -338,6 +338,7 @@ class EngineLoop:
                 "lanes_replay": sched.lanes_replay,
                 "lanes_emit": sched.lanes_emit,
                 "param_bytes": sched.engine.param_bytes,
+                "tp": sched.engine.tp,
             },
             "requests": {
                 "admitted": self.admitted,

@@ -23,13 +23,13 @@ V5E_HBM_BYTES = int(15.75 * 2**30)
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        desc = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
@@ -38,8 +38,13 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield desc
     jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _on(sharding, tree):
@@ -100,3 +105,54 @@ def test_qwen3_4b_decode_step_compiles_for_one_chip(one_chip):
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         < V5E_HBM_BYTES
+
+
+def test_mistral_large_stage_decode_step_compiles_for_four_chips(topo):
+    """One tp=4 pipeline stage of Mistral-Large-2407 (22 of 88 layers at
+    full width) on a (1, 4) mesh of the described v5e:2x2: the decode step
+    fits each chip, and its layer loop holds two all-reduces (wo and the
+    MLP epilogue) and no other collective."""
+    import re
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.configs import get_config
+    from repro.core.policy import ExecutionPolicy, KernelTiling
+    from repro.launch.mesh import make_mesh
+    from repro.models.common import ParallelContext
+    from repro.models.registry import build_model
+    from repro.runtime.serve import Engine
+
+    cfg = get_config("mistral-large-123b").with_(num_layers=22).with_quant(
+        mode="mlp", scheme="tp-aware")
+    model = build_model(cfg)
+    mesh = make_mesh((1, 4), ("data", "model"), topo.devices)
+    policy = ExecutionPolicy(scheme="tp-aware", backend="pallas",
+                             tiling=KernelTiling(interpret=False))
+    ctx = ParallelContext(mesh=mesh, policy=policy)
+    abstract = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+
+    def placed(tree, specs):
+        return jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=NamedSharding(mesh, s)),
+            tree, specs, is_leaf=lambda x: isinstance(x, P))
+
+    params = placed(abstract, model.param_specs(abstract, ctx))
+    engine = Engine(model=model, params=params, ctx=ctx, max_seq=512)
+    cache = placed(jax.eval_shape(lambda: model.init_cache(8, 512)),
+                   model.cache_specs(ctx))
+    lanes = jax.ShapeDtypeStruct((8,), jnp.int32,
+                                 sharding=NamedSharding(mesh, P()))
+    compiled = engine._decode.lower(params, cache, lanes, lanes).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < V5E_HBM_BYTES
+    text = compiled.as_text()
+    body = re.search(r"while\(.*?\bbody=(%[\w.\-]+)", text)[1]
+    comp = re.search(r"^" + re.escape(body) + r" .*?^}", text,
+                     re.M | re.S)[0]
+    found = re.findall(r"\s(all-reduce|all-gather|all-to-all|reduce-scatter"
+                       r"|collective-permute)(?:-start)?\(", comp)
+    assert found == ["all-reduce", "all-reduce"], found
+    assert comp.count("tpu_custom_call") >= 3
