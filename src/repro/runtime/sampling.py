@@ -13,11 +13,18 @@ bit-identical:
   equals ``sample`` on the (1, V) slice with the same key: the masking
   math is the same code, and ``jax.random.categorical`` draws identical
   gumbel bits for shapes (1, V) and (V,).
+
+The continuous scheduler runs ``sample_step``: ``sample_slots`` with each
+slot's PRNG chain advanced beside it, compiled into one program per
+decode step by ``compile_sample_step`` (``jit_sample_step`` in a trace),
+so the keys stay on the device from step to step.  Compiled, it draws
+the same bits as the eager calls.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import jax
@@ -99,3 +106,42 @@ def sample_slots(keys: jax.Array, logits: jax.Array,
         lambda k, row: jax.random.categorical(k, row))(keys, masked)
     return jnp.where(temperature <= 0.0, greedy,
                      drawn.astype(jnp.int32))
+
+
+def sample_step(keys: jax.Array, advance: jax.Array, logits: jax.Array,
+                temperature: jax.Array, top_p: jax.Array,
+                top_k: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """One decode step of the per-slot key chain and ``sample_slots``.
+
+    ``keys``: (B, 2) uint32, each slot's current key; ``advance``: (B,)
+    bool.  An advancing row does ``key, sub = jax.random.split(key)`` and
+    draws with ``sub``; any other row draws with ``key`` and keeps it.
+    This is ``Engine.generate``'s chain: a request's first emission draws
+    with its key itself, every later one splits first, and steps that
+    emit nothing (prompt replay, empty slots) leave the key alone.
+    Returns ``(tokens (B,) int32, next keys (B, 2))``.
+    """
+    split = jax.vmap(jax.random.split)(keys)              # (B, 2, 2)
+    adv = advance[:, None]
+    draw = jnp.where(adv, split[:, 1], keys)
+    nxt = jnp.where(adv, split[:, 0], keys)
+    return sample_slots(draw, logits, temperature, top_p, top_k), nxt
+
+
+def compile_sample_step(out_sharding=None):
+    """``sample_step`` as one compiled program that donates ``keys``.
+
+    ``out_sharding``: where the tokens and keys come out; under a mesh,
+    replicated on it, so reading the tokens back is one small transfer
+    whatever the logits' sharding, and every controller of a
+    multi-process mesh can read them.  Every parameter is a traced
+    input, so one program serves any mix of greedy, sampled, replaying
+    and empty slots.  Like ``Engine``'s step functions, each call wraps
+    a function of its own, which traces the module's ``sample_slots``
+    as it stands at the program's first call.
+    """
+    @functools.wraps(sample_step)
+    def step(*args):
+        return sample_step(*args)
+
+    return jax.jit(step, donate_argnums=0, out_shardings=out_sharding)

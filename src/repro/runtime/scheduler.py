@@ -57,7 +57,10 @@ seeded from the request (``seed`` if given, else the scheduler seed
 folded with the rid), advanced only on emission steps — so a request's
 tokens are bit-identical to a solo ``Engine.generate(PRNGKey(seed),
 ...)`` run with the same params, no matter which other requests share
-the batch.
+the batch.  The chains live on the device, one (B, 2) key array written
+at admission, and advance inside the step's one compiled sampling
+program (``sampling.sample_step``); the host only fills and uploads
+numpy inputs, and blocks once a step, reading the tokens back.
 """
 
 from __future__ import annotations
@@ -70,6 +73,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.runtime import sampling
 from repro.runtime.serve import Engine
@@ -110,7 +114,6 @@ class _Slot:
     """One live lane of the fixed-shape decode program."""
 
     req: Request
-    key: jax.Array                 # this request's private sample stream
     fed: int = 0                   # tokens fed so far == this slot's pos
     last: int = 0                  # last sampled token (next input when
                                    # the prompt is exhausted)
@@ -139,9 +142,21 @@ class Scheduler:
         self._dirty: list[bool] = []   # slot lanes a retired request used
         self._step_no = 0
         #: lane-steps that fed a prompt token whose logits were discarded
-        #: (prompt replay), and lane-steps that emitted a token
+        #: (prompt replay), lane-steps that emitted a token, and those of
+        #: the emitting ones whose token was drawn (temperature > 0)
         self.lanes_replay = 0
         self.lanes_emit = 0
+        self.lanes_drawn = 0
+        # per-slot sampling state: the PRNG keys on the device, the
+        # parameters on the host (rows written at admission) with their
+        # uploaded copy (None until the next step uploads them)
+        mesh = engine.ctx.mesh
+        self._replicated = None if mesh is None else NamedSharding(mesh,
+                                                                   P())
+        self._sample = sampling.compile_sample_step(self._replicated)
+        self._keys = None
+        self._params = None
+        self._params_dev = None
         self._cache_builds = 0
         self.manager = None
         if engine.uses_page_table:
@@ -257,16 +272,13 @@ class Scheduler:
         if not any(self._slots):
             return events
         with TraceAnnotation("serve.prepare", step=n):
-            args, keys, (temperature, top_p, top_k) = self._prepare()
+            args, advance, params = self._prepare()
         with TraceAnnotation("serve.decode", step=n):
             logits, self._cache = self.engine._decode(
                 self.engine.params, self._cache, *args)
-        # the sampling inputs go up after the decode dispatch, while the
-        # device runs it
         with TraceAnnotation("serve.sample", step=n):
-            sampled = sampling.sample_slots(
-                jnp.stack(keys), logits, jnp.asarray(temperature),
-                jnp.asarray(top_p), jnp.asarray(top_k))
+            sampled, self._keys = self._sample(self._keys, advance, logits,
+                                               *params)
         with TraceAnnotation("serve.readback", step=n):
             sampled = np.asarray(sampled)
         with TraceAnnotation("serve.update", step=n):
@@ -294,6 +306,13 @@ class Scheduler:
                 self._cache = self.engine.init_cache(b)
             self._slots = [None] * b
             self._dirty = [False] * b
+            self._keys = jax.device_put(np.zeros((b, 2), np.uint32),
+                                        self._replicated)
+            # temperature, top-p, top-k (0 disables) per slot
+            self._params = (np.zeros((b,), np.float32),
+                            np.ones((b,), np.float32),
+                            np.zeros((b,), np.int32))
+            self._params_dev = None
             self._cache_builds += 1
         slots = self._slots
         events: list[StepEvent] = []
@@ -326,6 +345,7 @@ class Scheduler:
         # page reservation to fit; the queue stays FIFO (no skipping), so
         # a too-big head waits rather than being starved by later
         # requests.
+        admitted = []
         for i in range(b):
             if slots[i] is None and self.queue:
                 req = self.queue[0]
@@ -342,45 +362,50 @@ class Scheduler:
                     self._cache = self.engine.reset_slot(self._cache, i)
                     self._dirty[i] = False
                 self.queue.popleft()
-                slots[i] = _Slot(req=req, key=self._request_key(req),
-                                 fed=fed0)
+                slots[i] = _Slot(req=req, fed=fed0)
+                admitted.append(i)
                 self.admissions.append((self._step_no, req.rid))
+        if admitted:
+            self._admit_sampling(admitted)
         return events
 
+    def _admit_sampling(self, admitted: list[int]):
+        """Write the admitted slots' request keys into the device key
+        array (read back whole: the last step's readback already waited
+        for it) and their sampling parameters into the host rows."""
+        keys = np.array(self._keys)
+        temperature, top_p, top_k = self._params
+        for i in admitted:
+            req = self._slots[i].req
+            keys[i] = np.asarray(self._request_key(req))
+            temperature[i] = (self.scfg.temperature if req.temperature is None
+                              else req.temperature)
+            p = self.scfg.top_p if req.top_p is None else req.top_p
+            top_p[i] = 1.0 if p is None else p
+            top_k[i] = 0 if self.scfg.top_k is None else self.scfg.top_k
+        self._keys = jax.device_put(keys, self._replicated)
+        self._params_dev = None
+
     def _prepare(self):
-        """The step's inputs: ``(decode args after params and cache, on
-        the device; per-slot sample keys; per-slot temperature, top-p and
-        top-k, on the host)``."""
+        """The step's inputs, filled on the host and uploaded, with no
+        other device work: ``(decode args after params and cache; the
+        sampler's (B,) advance mask; its temperature, top-p and top-k)``.
+        """
         slots = self._slots
         b = self.max_batch
         tokens = np.zeros((b,), np.int32)
         pos = np.zeros((b,), np.int32)
-        temperature = np.zeros((b,), np.float32)
-        top_p = np.ones((b,), np.float32)
-        top_k = np.zeros((b,), np.int32)
-        keys = []
+        advance = np.zeros((b,), bool)
         for i, s in enumerate(slots):
             if s is None:
-                keys.append(jax.random.PRNGKey(0))
                 continue
             plen = s.req.prompt.size
             tokens[i] = (s.req.prompt[s.fed] if s.fed < plen else s.last)
             pos[i] = s.fed
-            temperature[i] = (self.scfg.temperature
-                              if s.req.temperature is None
-                              else s.req.temperature)
-            p = self.scfg.top_p if s.req.top_p is None else s.req.top_p
-            top_p[i] = 1.0 if p is None else p
-            top_k[i] = 0 if self.scfg.top_k is None else self.scfg.top_k
             # the chain mirrors Engine.generate exactly: the first
             # emission samples with the request key itself, every later
-            # one splits first — non-emitting (prompt replay) steps pass
-            # the current key but never advance it
-            if s.fed + 1 >= plen and s.req.output:
-                s.key, sub = jax.random.split(s.key)
-                keys.append(sub)
-            else:
-                keys.append(s.key)
+            # one splits first — prompt replay never advances it
+            advance[i] = bool(s.req.output)
 
         args = [jnp.asarray(tokens), jnp.asarray(pos)]
         if self.manager is not None:
@@ -388,12 +413,15 @@ class Scheduler:
                 if s is not None:
                     self.manager.ensure(i, s.fed)   # page for this scatter
             args.append(jnp.asarray(self.manager.table()))
-        return args, keys, (temperature, top_p, top_k)
+        if self._params_dev is None:
+            self._params_dev = tuple(jnp.asarray(a) for a in self._params)
+        return args, jnp.asarray(advance), self._params_dev
 
     def _update(self, sampled: np.ndarray, events: list[StepEvent]):
         """Advance every live slot past the token it fed, record what
         it emitted, retire finished requests and count the lane-steps."""
-        replay = emit = 0
+        replay = emit = drawn = 0
+        temperature = self._params[0]
         for i, s in enumerate(self._slots):
             if s is None:
                 continue
@@ -405,6 +433,7 @@ class Scheduler:
                 # this step consumed the prompt's last token (or a
                 # generated one): its logits yield the next token
                 emit += 1
+                drawn += int(temperature[i] > 0)
                 s.last = int(sampled[i])
                 s.req.output.append(s.last)
                 final = len(s.req.output) >= s.req.max_new_tokens
@@ -417,6 +446,7 @@ class Scheduler:
                 replay += 1
         self.lanes_replay += replay
         self.lanes_emit += emit
+        self.lanes_drawn += drawn
         self._step_no += 1
 
     def _retire_slot(self, i: int):
@@ -442,6 +472,7 @@ class Scheduler:
         self._cache = None
         self._slots = []
         self._dirty = []
+        self._keys = None
         return True
 
     def cache_stats(self) -> dict:

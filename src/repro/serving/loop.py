@@ -337,6 +337,7 @@ class EngineLoop:
                 "steps": sched._step_no,
                 "lanes_replay": sched.lanes_replay,
                 "lanes_emit": sched.lanes_emit,
+                "lanes_drawn": sched.lanes_drawn,
                 "param_bytes": sched.engine.param_bytes,
                 "tp": sched.engine.tp,
             },

@@ -210,6 +210,170 @@ def test_sample_slots_matches_scalar_sample():
                                           err_msg=f"{t},{p},{k},{seed}")
 
 
+#: per-row (temperature, top_p, top_k) of the compiled sampler's cases
+SAMPLER_ROWS = {
+    "greedy": [(0.0, 1.0, 0)] * 4,
+    "top_k": [(0.8, 1.0, 40), (1.3, 1.0, 5), (0.5, 1.0, 1), (1.0, 1.0, 64)],
+    "top_p": [(0.9, 0.9, 0), (1.2, 0.5, 0), (0.7, 0.99, 0), (1.0, 0.1, 0)],
+    "mixed": [(0.0, 1.0, 0), (0.8, 1.0, 40), (0.9, 0.9, 0), (1.1, 0.8, 10)],
+}
+
+
+def _eager_step(keys, advance, logits, temperature, top_p, top_k):
+    """The chain the scheduler used to run on the host, one eager
+    ``jax.random.split`` per advancing slot, then ``sample_slots``."""
+    draw, nxt = [], []
+    for key, adv in zip(keys, np.asarray(advance)):
+        if adv:
+            key, sub = jax.random.split(key)
+            draw.append(sub)
+        else:
+            draw.append(key)
+        nxt.append(key)
+    toks = sampling.sample_slots(jnp.stack(draw), logits, temperature,
+                                 top_p, top_k)
+    return np.asarray(toks), np.asarray(jnp.stack(nxt))
+
+
+@pytest.mark.parametrize("mix", sorted(SAMPLER_ROWS))
+def test_compiled_sample_step_matches_eager_chain(mix):
+    """``compile_sample_step`` draws the eager path's tokens and keys bit
+    for bit, on advancing and non-advancing rows, over several keys."""
+    rows = SAMPLER_ROWS[mix]
+    temperature, top_p, top_k = (jnp.asarray(c, d) for c, d in zip(
+        zip(*rows), (jnp.float32, jnp.float32, jnp.int32)))
+    step = sampling.compile_sample_step()
+    for seed in range(3):
+        lk, kk, ak = jax.random.split(jax.random.PRNGKey(100 + seed), 3)
+        logits = 3 * jax.random.normal(lk, (len(rows), 97))
+        keys = jax.random.split(kk, len(rows))
+        for advance in (np.zeros(len(rows), bool), np.ones(len(rows), bool),
+                        np.asarray(jax.random.bernoulli(ak, 0.5,
+                                                        (len(rows),)))):
+            want = _eager_step(keys, advance, logits, temperature, top_p,
+                               top_k)
+            got = step(jnp.array(keys), jnp.asarray(advance), logits,
+                       temperature, top_p, top_k)
+            np.testing.assert_array_equal(np.asarray(got[0]), want[0],
+                                          err_msg=f"{mix} {seed} {advance}")
+            np.testing.assert_array_equal(np.asarray(got[1]), want[1])
+    assert step._cache_size() == 1
+
+
+def test_compiled_sample_step_key_chain_over_steps():
+    """After k steps the device-side chain holds each slot's key split
+    once per advancing step and untouched on the others, and one program
+    served every step, whatever its masks and parameters."""
+    b, steps = 4, 12
+    rng = np.random.default_rng(7)
+    step = sampling.compile_sample_step()
+    keys = jax.random.split(jax.random.PRNGKey(3), b)
+    want = list(keys)
+    for k in range(steps):
+        advance = rng.random(b) < 0.6
+        temperature = np.where(rng.random(b) < 0.25, 0.0,
+                               rng.uniform(0.5, 1.5, b)).astype(np.float32)
+        top_p = rng.choice([1.0, 0.9, 0.5], b).astype(np.float32)
+        top_k = rng.choice([0, 5, 40], b).astype(np.int32)
+        logits = jnp.asarray(rng.normal(size=(b, 61)), jnp.float32)
+        before = np.asarray(keys)
+        _, keys = step(keys, jnp.asarray(advance), logits,
+                       jnp.asarray(temperature), jnp.asarray(top_p),
+                       jnp.asarray(top_k))
+        want = [jax.random.split(w)[0] if a else w
+                for w, a in zip(want, advance)]
+        np.testing.assert_array_equal(np.asarray(keys),
+                                      np.asarray(jnp.stack(want)),
+                                      err_msg=f"step {k}")
+        moved = (np.asarray(keys) != before).any(axis=1)
+        np.testing.assert_array_equal(moved, advance)
+    assert step._cache_size() == 1
+
+
+def _forbid_eager_keys(monkeypatch):
+    def refuse(*args, **kw):
+        raise AssertionError("per-slot key work on the host")
+
+    for name in ("split", "PRNGKey", "fold_in", "key"):
+        monkeypatch.setattr(jax.random, name, refuse)
+
+
+def test_scheduler_prepare_fills_host_arrays_only(monkeypatch):
+    """``Scheduler._prepare`` derives no key: it returns the uploaded
+    (B,) decode and sampler inputs, and the keys stay one (B, 2) device
+    array held by the scheduler."""
+    cfg = get_smoke_config("qwen3-4b")
+    eng = make_engine(cfg, jax.random.PRNGKey(0), max_seq=24)
+    sched = Scheduler(eng, max_batch=3, prompt_budget=8,
+                      scfg=sampling.SamplingConfig(temperature=0.8,
+                                                   top_k=20))
+    rng = np.random.default_rng(1)
+    for i, (t, n) in enumerate(((0.0, 3), (None, 6))):
+        sched.submit(Request(rid=i, prompt=rng.integers(
+            0, cfg.vocab_size, size=n).astype(np.int32), max_new_tokens=6,
+            temperature=t))
+    for _ in range(4):     # slot 0 has emitted twice, slot 1 replays
+        sched.step()
+    _forbid_eager_keys(monkeypatch)
+    args, advance, params = sched._prepare()
+    arrays = [*args, advance, *params]
+    assert all(isinstance(a, jax.Array) and a.shape == (3,) for a in arrays)
+    np.testing.assert_array_equal(np.asarray(advance), [True, False, False])
+    assert sched._keys.shape == (3, 2)
+    assert not hasattr(sched._slots[0], "key")
+
+
+@pytest.mark.parametrize("kv", ["dense", "paged:16"])
+def test_scheduler_mixed_requests_match_solo_through_reuse_and_cancel(kv):
+    """Greedy, sampled and seeded requests through slot reuse after
+    retirement and a cancellation mid-stream: every request's tokens are
+    its solo ``Engine.generate`` tokens (a prefix of them for the
+    cancelled one), and ``lanes_drawn`` counts the sampled ones'."""
+    from repro.cache import PageSpec
+    from repro.core.policy import ExecutionPolicy
+
+    cfg = get_smoke_config("qwen3-4b")
+    policy = ExecutionPolicy.from_config(cfg)
+    if kv != "dense":
+        policy = policy.with_(kv=PageSpec.parse(kv))
+    eng = make_engine(cfg, jax.random.PRNGKey(0), max_seq=24, policy=policy)
+    assert eng.uses_page_table == (kv != "dense")
+    scfg = sampling.SamplingConfig(temperature=0.7, top_k=30)
+    rng = np.random.default_rng(11)
+    # (temperature, top_p, seed, max_new): None takes the scheduler's
+    reqs = [(0.0, None, None, 3), (None, None, 5, 7), (1.1, 0.8, None, 9),
+            (None, 0.9, 21, 4), (0.0, None, 8, 5)]
+    prompts = [rng.integers(0, cfg.vocab_size, size=n).astype(np.int32)
+               for n in (4, 6, 3, 5, 2)]
+    sched = Scheduler(eng, max_batch=2, prompt_budget=8, scfg=scfg, seed=4)
+    for i, (p, (t, tp, sd, mn)) in enumerate(zip(prompts, reqs)):
+        sched.submit(Request(rid=i, prompt=p, max_new_tokens=mn,
+                             temperature=t, top_p=tp, seed=sd))
+    for _ in range(9):
+        sched.step()
+    assert sched.cancel(1)             # live, mid-stream
+    done = sched.run()
+    assert done[1].cancelled and 0 < len(done[1].output) < reqs[1][3]
+    admitted = dict((rid, step) for step, rid in sched.admissions)
+    assert admitted[2] > 0 and admitted[4] > admitted[3]   # reused slots
+    drawn = 0
+    for i, (p, (t, tp, sd, mn)) in enumerate(zip(prompts, reqs)):
+        temp = scfg.temperature if t is None else t
+        key = (jax.random.PRNGKey(sd) if sd is not None else
+               jax.random.fold_in(jax.random.PRNGKey(4), i))
+        ref = np.asarray(eng.generate(
+            key, {"tokens": jnp.asarray(p)[None]}, jnp.asarray([p.size]),
+            max_new_tokens=mn, scfg=sampling.SamplingConfig(
+                temperature=temp, top_k=scfg.top_k, top_p=tp)))[0]
+        out = np.asarray(done[i].output)
+        np.testing.assert_array_equal(out, ref[:out.size],
+                                      err_msg=f"{kv} req {i}")
+        assert done[i].cancelled or out.size == mn
+        drawn += out.size if temp > 0 else 0
+    assert sched.lanes_drawn == drawn
+    assert sched._sample._cache_size() == 1
+
+
 def test_scheduler_per_request_params_bit_identical():
     """Concurrent requests with different temperature/top_p/seed each
     reproduce a solo Engine.generate run with the same params."""
@@ -330,7 +494,9 @@ def test_engine_loop_reports_param_bytes_per_dtype():
     attn = eng.params["layers"]["attn"]
     want = sum(attn[k].nbytes for k in ATTN_PROJ)
     loop = EngineLoop(Scheduler(eng, max_batch=2, prompt_budget=8))
-    got = loop.stats()["engine"]["param_bytes"]
+    stats = loop.stats()["engine"]
+    assert stats["lanes_drawn"] == 0
+    got = stats["param_bytes"]
     assert got["bfloat16"] == want
     assert sum(got.values()) == sum(
         a.nbytes for a in jax.tree_util.tree_leaves(eng.params))

@@ -70,6 +70,7 @@ def serve(ctx):
 
 sched4, prompts, toks4 = serve(ParallelContext(mesh=mesh))
 tp4_compiles = compiles.count("jit(decode)")
+out["sample_compiles"] = compiles.count("jit(sample_step)")
 _, _, toks1 = serve(ParallelContext())
 out["tokens_equal"] = toks4 == toks1
 out["tp"] = EngineLoop(sched4).stats()["engine"]["tp"]
@@ -87,11 +88,20 @@ heads = NamedSharding(mesh, P(None, ("data",), None, "model", None))
 lanes = jnp.zeros((MAX_BATCH,), jnp.int32)
 shardings = [cache["k"].sharding, cache["v"].sharding]
 for _ in range(2):
-    _, cache = eng._decode(eng.params, cache, lanes, lanes)
+    logits, cache = eng._decode(eng.params, cache, lanes, lanes)
     shardings += [cache["k"].sharding, cache["v"].sharding]
 out["cache_heads"] = [s.is_equivalent_to(heads, 5) for s in shardings]
 out["decode_compiles"] = tp4_compiles
 out["decode_compiles_after"] = compiles.count("jit(decode)") - tp4_compiles - 1
+
+# the scheduler's sampler on the mesh's logits: tokens and keys come out
+# replicated, from the program the served steps compiled
+n = compiles.count("jit(sample_step)")
+toks, keys = sched4._sample(sched4._keys, jnp.ones((MAX_BATCH,), bool),
+                            logits, *map(jnp.asarray, sched4._params))
+out["sample_replicated"] = [a.sharding.is_fully_replicated
+                            for a in (logits, toks, keys)]
+out["sample_compiles_after"] = compiles.count("jit(sample_step)") - n
 
 # collectives of the compiled step's scan body
 hlo = eng._decode.lower(eng.params, cache, lanes, lanes).compile().as_text()
@@ -144,6 +154,15 @@ def test_tp4_served_greedy_decode_agrees_with_reference_and_tp1(report):
     assert report["max_gap"] < GAP_LIMIT, report["max_gap"]
     assert report["tokens_equal"]
     assert report["tp"] == 4
+
+
+def test_tp4_sampler_is_one_program_with_replicated_outputs(report):
+    # the logits come out of the step sharded; the sampler's tokens and
+    # keys replicated, and one compile served every step and the call
+    # after them
+    assert report["sample_replicated"] == [False, True, True]
+    assert report["sample_compiles"] == 1
+    assert report["sample_compiles_after"] == 0
 
 
 def test_tp4_decode_step_body_holds_only_the_wo_and_epilogue_all_reduce(

@@ -156,3 +156,34 @@ def test_mistral_large_stage_decode_step_compiles_for_four_chips(topo):
                        r"|collective-permute)(?:-start)?\(", comp)
     assert found == ["all-reduce", "all-reduce"], found
     assert comp.count("tpu_custom_call") >= 3
+
+
+# the served vocabularies: granite-3-8b's on one chip, Mistral-Large's
+# over a (1, 4) mesh with the logits split along it
+@pytest.mark.parametrize("vocab,chips", [(49155, 1), (32768, 4)],
+                         ids=["granite-one-chip", "mistral-tp4"])
+def test_sample_step_compiles_at_the_served_vocab(topo, vocab, chips):
+    """The scheduler's per-step sampler compiles for the described chip
+    at batch 8; under a mesh its tokens and keys come out replicated."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.runtime import sampling
+
+    mesh = Mesh(np.array(topo.devices[:chips]).reshape(1, chips),
+                ("data", "model"))
+    rep = NamedSharding(mesh, P())
+
+    def arg(shape, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    step = sampling.compile_sample_step(rep if chips > 1 else None)
+    compiled = step.lower(
+        arg((8, 2), jnp.uint32), arg((8,), jnp.bool_),
+        arg((8, vocab), jnp.float32, P(None, "model")),
+        arg((8,), jnp.float32), arg((8,), jnp.float32),
+        arg((8,), jnp.int32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**26
+    if chips > 1:
+        assert all(s.is_fully_replicated for s in compiled.output_shardings)
