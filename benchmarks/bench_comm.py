@@ -17,20 +17,7 @@ exactly from lowered HLO rather than wall time) — three tables:
    reference.  This is the communication-compression table:
    ``quant-int8`` lands at ~(1 + 2/block)/4 ≈ 25% of the f32 psum bytes.
 
-3. **fused wire epilogue vs plain quant**: the ``:fused`` spec routes
-   the down GEMM through the Pallas wire-epilogue kernel (DESIGN.md
-   §10); measured HLO collective bytes and outputs must be identical to
-   the unfused strategy — the fusion saves HBM traffic inside the
-   kernel, never wire bytes — both asserted per row.
-
-4. **exposed vs overlapped quant ring**: the ``:overlap`` spec
-   (DESIGN.md §11) pipelines the decomposed ppermute ring against the
-   next microbatch's dequant-GEMM; outputs and wire bytes must be
-   identical to the synchronous epilogue while
-   ``roofline.parse_overlap_windows`` proves the compiled schedule
-   issues the permutes with a GEMM inside their in-flight windows.
-
-5. **per pair path under a ``CollectivePlan``**: the per-layer selection
+3. **per pair path under a ``CollectivePlan``**: the per-layer selection
    table — each pair resolves its own collective from the plan's glob
    map, shown with the lowered HLO's collective instruction counts
    (quant epilogues lower to all_to_all + all_gather phases, psum/cast
@@ -48,7 +35,6 @@ from repro.comm import (CollectivePlan, CollectiveSpec,
                         dispatch as comm_dispatch)
 from repro.configs import PAPER_PROBLEMS
 from repro.core.policy import ExecutionPolicy
-from repro.launch import roofline
 
 from benchmarks.bench_mlp import _mesh, _plan, _collective_bytes
 
@@ -147,117 +133,6 @@ def _strategy_table(out_lines: list, m: int):
                 out_lines.append(line)
 
 
-def _fused_wire_table(out_lines: list, m: int):
-    """Fused wire epilogue vs the plain quantized collective: same wire.
-
-    For each quant strategy × TP degree, the ``:fused`` spec must change
-    *nothing* the HLO parser can see — the payload the ring moves is
-    byte-for-byte what the unfused path quantizes from ``y_partial``
-    (DESIGN.md §10), so measured collective bytes are identical and the
-    outputs are bit-identical (both asserted, not just tabulated).  The
-    fused win is the skipped 2*M*N*4 B HBM round trip inside the kernel
-    (see bench_kernels' epilogue table), invisible to wire accounting by
-    construction.  Small problem on purpose: the wire kernel runs in
-    Pallas interpret mode on CPU."""
-    title = "# bench_comm: fused wire epilogue vs plain quant (M=8)"
-    print(title)
-    out_lines.append(title)
-    header = ("k1_n1_n2,TP,spec,epi,hlo_B,vs_plain_B,max_abs_diff")
-    print(header)
-    out_lines.append(header)
-    k1, n1, n2 = 256, 512, 256
-    pp = _plan(k1, n1, n2, "tp-aware", gs=32)
-    x = jax.random.normal(jax.random.PRNGKey(1), (m, k1))
-    for tp in (2, 4, 8):
-        if tp > len(jax.devices()):
-            continue
-        mesh = _mesh(tp)
-        for base in ("quant-int8:32", "quant-int4:32"):
-            ys, bytes_ = {}, {}
-            for epi in ("plain", "fused"):
-                short = base + (":fused" if epi == "fused" else "")
-                pol = ExecutionPolicy(scheme="tp-aware", backend="jnp",
-                                      compute_dtype=jnp.float32,
-                                      collective=CollectiveSpec.parse(short))
-                with mesh:
-                    fn = lambda xx, p, pol=pol: p.forward(
-                        xx, pol, mesh, activation=None)
-                    bytes_[epi] = _collective_bytes(
-                        fn, (x, pp), mesh)["total_per_device"]
-                    ys[epi] = np.asarray(jax.jit(fn)(x, pp))
-            diff = float(np.abs(ys["fused"] - ys["plain"]).max())
-            assert diff == 0.0, f"fused output diverged ({base}, tp={tp})"
-            assert bytes_["fused"] == bytes_["plain"], (base, tp, bytes_)
-            for epi in ("plain", "fused"):
-                line = (f"{k1}_{n1}_{n2},{tp},{base},{epi},"
-                        f"{bytes_[epi]:.0f},"
-                        f"{bytes_[epi] - bytes_['plain']:.0f},{diff:.1e}")
-                print(line)
-                out_lines.append(line)
-
-
-def _overlap_table(out_lines: list, m: int):
-    """Exposed vs overlapped quantized ring (DESIGN.md §11).
-
-    Per quant strategy × TP degree: the ``:overlap`` spec decomposes the
-    two-phase ring into explicit ppermute rotations microbatch-pipelined
-    against the down GEMM.  Three properties asserted per row, not just
-    tabulated: the output is bit-identical to the synchronous epilogue,
-    the measured HLO wire bytes are identical (only the *exposure*
-    changes), and ``roofline.parse_overlap_windows`` finds ppermute
-    windows spanning a GEMM in the overlapped schedule (and none in the
-    synchronous one).  Wall time is reported for trend tracking but the
-    hiding is only real on backends with async collectives — CPU runs
-    the schedule serially, so ``wall_ms`` parity is expected here."""
-    import time as _time
-
-    title = "# bench_comm: exposed vs overlapped quant ring (M=8)"
-    print(title)
-    out_lines.append(title)
-    header = ("k1_n1_n2,TP,spec,epi,hlo_B,spanning,wall_ms,max_abs_diff")
-    print(header)
-    out_lines.append(header)
-    k1, n1, n2 = 256, 512, 256
-    pp = _plan(k1, n1, n2, "tp-aware", gs=32)
-    x = jax.random.normal(jax.random.PRNGKey(1), (m, k1))
-    for tp in (2, 4, 8):
-        if tp > len(jax.devices()):
-            continue
-        mesh = _mesh(tp)
-        for base in ("quant-int8:32", "quant-int4:32"):
-            ys, bytes_, spans, wall = {}, {}, {}, {}
-            for epi in ("sync", "overlap"):
-                short = base + (":overlap" if epi == "overlap" else "")
-                pol = ExecutionPolicy(scheme="tp-aware", backend="jnp",
-                                      compute_dtype=jnp.float32,
-                                      collective=CollectiveSpec.parse(short))
-                with mesh:
-                    fn = lambda xx, p, pol=pol: p.forward(
-                        xx, pol, mesh, activation=None)
-                    bytes_[epi] = _collective_bytes(
-                        fn, (x, pp), mesh)["total_per_device"]
-                    jfn = jax.jit(fn)
-                    spans[epi] = roofline.parse_overlap_windows(
-                        jfn.lower(x, pp).compile().as_text())["spanning"]
-                    ys[epi] = np.asarray(jfn(x, pp))
-                    jfn(x, pp).block_until_ready()    # warm
-                    t0 = _time.perf_counter()
-                    for _ in range(5):
-                        jfn(x, pp).block_until_ready()
-                    wall[epi] = (_time.perf_counter() - t0) / 5 * 1e3
-            diff = float(np.abs(ys["overlap"] - ys["sync"]).max())
-            assert diff == 0.0, f"overlap diverged ({base}, tp={tp})"
-            assert bytes_["overlap"] == bytes_["sync"], (base, tp, bytes_)
-            assert spans["overlap"] >= 1, (base, tp, spans)
-            assert spans["sync"] == 0, (base, tp, spans)
-            for epi in ("sync", "overlap"):
-                line = (f"{k1}_{n1}_{n2},{tp},{base},{epi},"
-                        f"{bytes_[epi]:.0f},{spans[epi]},"
-                        f"{wall[epi]:.2f},{diff:.1e}")
-                print(line)
-                out_lines.append(line)
-
-
 #: the demo per-layer plan the third table resolves pairs against —
 #: mirrors what `prepare --autotune-collectives` compiles into artifacts
 PER_LAYER_PLAN = ("per-layer:*.mlp=quant-int8:128,"
@@ -308,8 +183,6 @@ def run(out_lines: list):
     m = 8
     _scheme_table(out_lines, m)
     _strategy_table(out_lines, m)
-    _fused_wire_table(out_lines, m)
-    _overlap_table(out_lines, m)
     _per_layer_table(out_lines, m)
 
 

@@ -13,14 +13,10 @@ from __future__ import annotations
 import time
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 
-from repro.comm import dispatch as comm_dispatch
-from repro.comm.wire import wire_params
 from repro.core import quantization as qz
 from repro.core.policy import ExecutionPolicy
-from repro.kernels import dequant_matmul as dk, dispatch, ops
+from repro.kernels import dequant_matmul as dk, dispatch
 
 
 def metadata_traffic(k, n, gs, bm, bn, bk, m, *, ordered: bool) -> int:
@@ -32,82 +28,6 @@ def metadata_traffic(k, n, gs, bm, bn, bk, m, *, ordered: bool) -> int:
     else:
         per_tile = g * bn * 4 * 2                   # FULL table per tile
     return tiles * per_tile
-
-
-def epilogue_hbm_traffic(m, n_pad, block, bits, *, fused: bool) -> int:
-    """Modeled HBM bytes the down-GEMM *epilogue* moves per forward.
-
-    Both variants emit the same wire payload + f16 metadata (that part is
-    unavoidable — it IS ring phase 1's input).  The unfused variant
-    additionally round-trips the f32 partial through HBM: the dense
-    kernel writes ``y_partial`` (m*n_pad*4 B) and the collective's
-    quantize step reads it back.  The fused kernel (DESIGN.md §10)
-    quantizes in VMEM at the last K-step, so that 2*m*n_pad*4 B vanishes.
-    """
-    payload = m * (n_pad if bits == 8 else n_pad // 2)
-    meta_arrays = 1 if bits == 8 else 2            # scales (+zeros, int4)
-    meta = m * (n_pad // block) * 2 * meta_arrays  # f16
-    extra = 0 if fused else 2 * m * n_pad * 4
-    return payload + meta + extra
-
-
-def _fused_epilogue_table(out_lines: list):
-    """Fused wire epilogue vs dense GEMM + separate blockwise quantize.
-
-    The wall columns are interpret-mode CPU (caveat as above); the
-    modeled column is the TPU-relevant one.  Bit-identity of the two
-    payloads is asserted, not just tabulated — the bench doubles as a
-    smoke check."""
-    title = ("# bench_kernels: wire epilogue, fused vs dense+quantize "
-             "(tp=4)")
-    print(title)
-    out_lines.append(title)
-    header = ("M,K,N,gs,bits,epi,epi_hbm_B,vs_fused,wall_ms")
-    print(header)
-    out_lines.append(header)
-    tp = 4
-    for (m, k, n, gs, bits) in [(16, 4096, 256, 128, 8),
-                                (16, 4096, 256, 128, 4),
-                                (128, 4096, 256, 128, 8)]:
-        rng = jax.random.PRNGKey(0)
-        w = jax.random.normal(rng, (k, n))
-        ql = qz.quantize(w, gs, act_order=True, rng=rng).ordered
-        x = jax.random.normal(rng, (m, k))
-        n_pad, _, bs = wire_params(n, tp, bits, 128)
-
-        def unfused():
-            y = ops.dequant_matmul(x, ql)
-            if n_pad != n:
-                y = jnp.pad(y, ((0, 0), (0, n_pad - n)))
-            if bits == 8:
-                q, s = comm_dispatch._blockwise_quantize(y, bs)
-                return q.astype(jnp.int8), s, None
-            q, s, z = comm_dispatch._blockwise_quantize_int4(y, bs)
-            return comm_dispatch._pack4_last(q), s, z
-
-        def fused():
-            return ops.dequant_matmul_wire(x, ql, tp=tp, wire_bits=bits,
-                                           wire_block=128)
-
-        walls, outs = {}, {}
-        for epi, fn in (("unfused", unfused), ("fused", fused)):
-            fn()  # warm (trace + interpret setup)
-            t0 = time.perf_counter()
-            outs[epi] = jax.block_until_ready(fn())
-            walls[epi] = (time.perf_counter() - t0) * 1e3
-        for a, b in zip(outs["unfused"], outs["fused"]):
-            assert (a is None) == (b is None)
-            if a is not None:
-                assert (np.asarray(a) == np.asarray(b)).all(), \
-                    "fused payload diverged from dense+quantize"
-        base = epilogue_hbm_traffic(m, n_pad, bs, bits, fused=True)
-        for epi in ("unfused", "fused"):
-            hbm = epilogue_hbm_traffic(m, n_pad, bs, bits,
-                                       fused=(epi == "fused"))
-            line = (f"{m},{k},{n},{gs},{bits},{epi},{hbm},"
-                    f"{hbm / base:.1f},{walls[epi]:.1f}")
-            print(line)
-            out_lines.append(line)
 
 
 def run(out_lines: list):
@@ -143,7 +63,6 @@ def run(out_lines: list):
                     f"{meta / base:.1f},{wall:.1f}")
             print(line)
             out_lines.append(line)
-    _fused_epilogue_table(out_lines)
 
 
 if __name__ == "__main__":

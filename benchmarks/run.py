@@ -3,8 +3,7 @@ import sys
 
 # TP benchmarks need multiple host devices (8, like the paper's 8-GPU node).
 os.environ.setdefault(
-    "XLA_FLAGS", "--xla_force_host_platform_device_count=8 "
-    "--xla_cpu_enable_concurrency_optimized_scheduler=false")
+    "XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 # Fallback for `python benchmarks/run.py` without PYTHONPATH=src (the
 # documented invocation is `python -m benchmarks.run` from the repo root
 # with PYTHONPATH=src): both the repo root (the `benchmarks` package) and

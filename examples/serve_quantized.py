@@ -106,11 +106,8 @@ def main():
           f"{'one-shot compile' if args.one_shot else 'from artifact'}")
     if artifact is not None:
         for site in artifact.manifest.get("collective_tuner", ()):
-            # ':fused' sites run the wire-epilogue kernel: the down GEMM
-            # emits ring phase 1's quantized payload (DESIGN.md §10)
             print(f"  site {site['path']} [{site.get('kind', 'pair')}] -> "
-                  f"{site['chosen']}"
-                  + (" (fused wire epilogue)" if site.get("fused") else ""))
+                  f"{site['chosen']}")
         if artifact.aux:
             print(f"  aux plans: {', '.join(artifact.aux)} "
                   "(attention V->O folds served)")

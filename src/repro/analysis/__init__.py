@@ -9,15 +9,14 @@ DESIGN.md §12 the prose):
   with zero FLOPs (CT rules).
 * ``hlo_lint``   — compiled-HLO rule engine grown out of
   ``launch/roofline.py``: measured collective bytes must equal the
-  analytic ring model, no widening converts in the residual stream,
-  overlap windows must span a GEMM (HL rules).
+  analytic ring model, no widening converts in the residual stream
+  (HL rules).
 * ``ast_lint``   — source hygiene: raw ``lax`` collectives outside
   comm/+dist/, kernel calls bypassing the dispatch registry, unfrozen
   spec dataclasses, mutable defaults (AS rules).
 * ``manifest_lint`` — offline ``DeploymentArtifact`` audit: plan-glob
-  reachability, fused/overlap eligibility provenance re-derived from
-  the shards on disk, fold coverage, BENCH snapshot schema (MF/BN
-  rules).
+  reachability, rank shards on disk, fold coverage, BENCH snapshot
+  schema (MF/BN rules).
 
 None of these runs the model; all of them fail CI when an invariant
 the serving stack depends on stops holding.

@@ -19,11 +19,8 @@ import sys
 
 # the HLO/contract sweeps need a multi-device host platform; set BEFORE
 # jax (transitively) imports, harmless when a real backend is present.
-# The sequential CPU scheduler keeps the printed instruction order the
-# program order HL003's overlap windows are read from.
 os.environ.setdefault(
-    "XLA_FLAGS", "--xla_force_host_platform_device_count=8 "
-    "--xla_cpu_enable_concurrency_optimized_scheduler=false")
+    "XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 
 def main(argv=None) -> int:
@@ -35,7 +32,7 @@ def main(argv=None) -> int:
     ap.add_argument("--contracts", action="store_true",
                     help="CT rules: eval_shape dtype/shape contracts")
     ap.add_argument("--hlo", action="store_true",
-                    help="HL rules: compiled-HLO byte/convert/overlap sweep")
+                    help="HL rules: compiled-HLO byte/convert sweep")
     ap.add_argument("--bench", action="store_true",
                     help="BN rules: committed BENCH_*.json schema")
     ap.add_argument("--artifact", default=None, metavar="DIR",

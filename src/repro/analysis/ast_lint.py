@@ -6,10 +6,9 @@ milliseconds and a broken one is reported instead of crashing the
 linter's own process):
 
 * AS001 — raw ``jax.lax`` collectives outside ``comm/`` + ``dist/``.
-  The comm registry is the only place allowed to issue collectives
-  (plus ``dist/`` for the decomposed overlap ring); anywhere else the
-  per-layer plan, the wire-byte accounting, and the dtype contract
-  silently don't apply.
+  The comm registry and the mesh runtime are the only places allowed to
+  issue collectives; anywhere else the per-layer plan, the wire-byte
+  accounting, and the dtype contract silently don't apply.
 * AS002 — kernel entry points (``kernels.ops`` / ``kernels.ref``
   functions) called outside ``kernels/`` — everything must route
   through ``kernels/dispatch.py``'s registry.
@@ -34,13 +33,13 @@ COLLECTIVE_NAMES = frozenset({
 })
 
 #: directories (repo-relative, '/'-normalized) allowed to issue raw
-#: collectives: the strategy registry itself and the decomposed ring
+#: collectives: the strategy registry itself and the mesh runtime
 COLLECTIVE_ALLOWED_DIRS = ("repro/comm/", "repro/dist/")
 
 #: kernel entry-point names only ``kernels/`` may call directly
 KERNEL_ENTRY_NAMES = frozenset({
     "pallas_dequant_matmul_ordered", "pallas_dequant_matmul_gidx",
-    "dequant_matmul_wire", "dequant_matmul",
+    "dequant_matmul",
 })
 KERNEL_ALLOWED_DIRS = ("repro/kernels/",)
 

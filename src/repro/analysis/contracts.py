@@ -20,9 +20,9 @@ silently lose quality, made checkable before a single token is served.
   the GPTQ/reorder/fold pipeline traces abstractly too).
 
 With ``specs=None`` the collective checks sweep every registered
-strategy plus the ``:overlap`` quant variants; a caller holding a
-prepared artifact passes that plan's resolved ``specs()`` instead so
-the exact deployed sites are what gets verified.
+strategy; a caller holding a prepared artifact passes that plan's
+resolved ``specs()`` instead so the exact deployed sites are what gets
+verified.
 """
 
 from __future__ import annotations
@@ -43,10 +43,7 @@ def _default_specs():
     from repro.comm import dispatch as comm_dispatch
     from repro.comm.spec import CollectiveSpec
 
-    out = [CollectiveSpec.parse(n) for n in comm_dispatch.strategies()]
-    out += [CollectiveSpec.parse("quant-int8:32:overlap"),
-            CollectiveSpec.parse("quant-int4:32:overlap")]
-    return out
+    return [CollectiveSpec.parse(n) for n in comm_dispatch.strategies()]
 
 
 def _tp_mesh(tp: int):

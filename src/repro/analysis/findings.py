@@ -79,11 +79,6 @@ RULES: dict[str, Rule] = {r.id: r for r in [
          "the pre-fix 'cast' collective returning its bf16 wire dtype: "
          "the residual add widened it back every layer, visible in HLO "
          "as an unmatched bf16->f32 convert"),
-    Rule("HL003", "hlo", "error",
-         "every ':overlap' site's collective window spans at least one "
-         "GEMM in the scheduled module (parse_overlap_windows)",
-         "a sync ring where ':overlap' promised a pipelined one — the "
-         "epilogue serializes and the microbatching is pure overhead"),
     Rule("HL004", "hlo", "warn",
          "no copy instruction duplicates a donated (input/output "
          "aliased) parameter",
@@ -121,13 +116,6 @@ RULES: dict[str, Rule] = {r.id: r for r in [
          "match for at least one planned site)",
          "an earlier catch-all glob silently overriding a later, more "
          "specific per-layer choice"),
-    Rule("MF003", "manifest", "error",
-         "every ':fused'/':overlap' mark is backed by recorded "
-         "eligibility provenance AND by kernels.dispatch.wire_support "
-         "re-derived from the rank-0 shard on disk",
-         "a plan marked ':fused' whose serve-time wire_support check "
-         "fails — the runtime silently falls back to the dense epilogue "
-         "while dashboards report the fused one"),
     Rule("MF004", "manifest", "error",
          "the manifest's leaf_shards map matches the rank_NN.npz files "
          "on disk: tp files present, every key in every rank, no "

@@ -1,10 +1,10 @@
 """HLO lint — comm-schedule rules decided from compiled HLO text.
 
 Xu et al. 2025 (PAPERS.md) shows the properties this repo's past bugs
-violated are fully decidable from the compiled module: wire bytes,
-dtype round trips, and overlap exposure are all in the text.  This
-module grows ``launch/roofline.py``'s parser (``iter_collectives`` /
-``parse_overlap_windows``) into a rule engine with two surfaces:
+violated are fully decidable from the compiled module: wire bytes and
+dtype round trips are both in the text.  This module grows
+``launch/roofline.py``'s parser (``iter_collectives``) into a rule
+engine with two surfaces:
 
 * ``lint_hlo_text`` — rules over one module's text (a dump on disk, a
   CI artifact, a freshly lowered program):
@@ -16,8 +16,6 @@ module grows ``launch/roofline.py``'s parser (``iter_collectives`` /
     the value entered the stream already narrowed — exactly how the old
     ``cast`` bf16 leak surfaces in multi-layer HLO), plus an optional
     root-dtype check against the activation input dtype,
-  - HL003 when the caller expects overlap: every collective window of
-    the given kinds must span a GEMM (``parse_overlap_windows``),
   - HL004 always: no ``copy`` of a donated (input/output aliased)
     parameter.
 
@@ -25,8 +23,8 @@ module grows ``launch/roofline.py``'s parser (``iter_collectives`` /
   (collective spec × TP degree) site it compiles the paper's pair
   program under ``schemes.pair_forward_tp`` exactly like
   ``benchmarks/bench_comm.py`` does and asserts measured == analytic
-  (rel diff < 1e-6) per site, overlap exposure for ``:overlap`` specs,
-  and the dtype rules over every lowered module.
+  (rel diff < 1e-6) per site, and the dtype rules over every lowered
+  module.
 """
 
 from __future__ import annotations
@@ -144,7 +142,6 @@ def _donated_copies(hlo_text: str) -> list[Finding]:
 def lint_hlo_text(hlo_text: str, *, chips: int = 1,
                   expected_bytes: Optional[dict] = None,
                   expect_root_dtype: Optional[str] = None,
-                  expect_overlap_kinds: Optional[Sequence[str]] = None,
                   location: str = "hlo") -> list[Finding]:
     """Apply every text-decidable rule to one compiled module.
 
@@ -152,8 +149,7 @@ def lint_hlo_text(hlo_text: str, *, chips: int = 1,
     measured per-device collective total must match the summed analytic
     prediction within ``BYTE_RTOL`` (HL001).  ``expect_root_dtype``:
     the activation input dtype (HLO name, e.g. ``"f32"``) the ENTRY
-    root must preserve (HL002).  ``expect_overlap_kinds``: collective
-    kinds whose windows must span a GEMM (HL003).
+    root must preserve (HL002).
     """
     out: list[Finding] = []
     if expected_bytes:
@@ -180,23 +176,6 @@ def lint_hlo_text(hlo_text: str, *, chips: int = 1,
                 f"residual stream",
                 location=location,
                 detail={"root": root, "expect": expect_root_dtype}))
-    if expect_overlap_kinds:
-        win = roofline.parse_overlap_windows(
-            hlo_text, kinds=tuple(expect_overlap_kinds))
-        if win["collectives"] == 0:
-            out.append(Finding(
-                "HL003",
-                f"':overlap' promised a decomposed ring but the module "
-                f"has no {'/'.join(expect_overlap_kinds)} instruction",
-                location=location, detail=win))
-        elif win["spanning"] == 0:
-            out.append(Finding(
-                "HL003",
-                f"no collective window spans a GEMM "
-                f"({win['collectives']} windows, all exposed) — the "
-                f"':overlap' schedule serializes",
-                location=location,
-                detail={k: win[k] for k in ("collectives", "spanning")}))
     out.extend(_donated_copies(hlo_text))
     return out
 
@@ -209,10 +188,6 @@ def lint_hlo_text(hlo_text: str, *, chips: int = 1,
 #: ``cast`` is excluded on CPU — XLA promotes the bf16 all-reduce to f32
 #: (the wire stays bf16 on TPU), a backend artifact, not a plan bug
 SWEEP_SPECS = ("psum", "psum_scatter", "quant-int8", "quant-int4")
-
-#: ':overlap' variants checked for pipelined exposure (block 32 divides
-#: the per-rank chunk at every swept TP degree)
-SWEEP_OVERLAP_SPECS = ("quant-int8:32:overlap", "quant-int4:32:overlap")
 
 _SWEEP_SHAPE = (256, 512, 256)   # (k1, n1, n2): shards to tp 8, gs 32
 _SWEEP_M = 8
@@ -263,11 +238,8 @@ def run_site_sweep(tps: Iterable[int] = (2, 4, 8),
 
     from repro.comm.spec import CollectiveSpec
 
-    if specs is None:
-        specs = [CollectiveSpec.parse(s) for s in SWEEP_SPECS]
-        specs += [CollectiveSpec.parse(s) for s in SWEEP_OVERLAP_SPECS]
-    else:
-        specs = [CollectiveSpec.parse(s) for s in specs]
+    specs = [CollectiveSpec.parse(s)
+             for s in (SWEEP_SPECS if specs is None else specs)]
 
     out: list[Finding] = []
     n2 = _SWEEP_SHAPE[2]
@@ -281,10 +253,7 @@ def run_site_sweep(tps: Iterable[int] = (2, 4, 8),
                 txt, chips=tp,
                 expected_bytes={label: spec.bytes_on_wire(
                     (_SWEEP_M, n2), tp)},
-                expect_root_dtype="f32",
-                expect_overlap_kinds=(("collective-permute",)
-                                      if spec.overlap else None),
-                location=label))
+                expect_root_dtype="f32", location=label))
     return out
 
 

@@ -10,10 +10,6 @@ at forward time (or worse, never):
 * MF002 — no entry is fully shadowed by earlier entries (matches sites,
   wins none) — shadowed entries silently serve a different collective
   than the plan text suggests.
-* MF003 — every ``:fused``/``:overlap`` mark is backed by recorded
-  tuner eligibility provenance AND re-derivable from the rank-0 shard
-  on disk via ``kernels.dispatch.wire_support`` — a mark the kernel
-  cannot serve would fall back (or die) at forward time.
 * MF004 — the manifest's ``leaf_shards`` map and the ``rank_NN.npz``
   files agree: all TP files present, identical key sets, consistent
   per-rank shapes, no stray rank files beyond the TP degree.
@@ -79,8 +75,8 @@ def _parse_plan(manifest: dict, location: str):
 
 def lint_manifest_dict(manifest: dict, aux: Optional[dict] = None, *,
                        location: str = "manifest") -> list[Finding]:
-    """Pure-dict checks (MF001/MF002/MF003-provenance/MF006) — no disk."""
-    from repro.comm.spec import CollectivePlan, _match, parse_collective
+    """Pure-dict checks (MF001/MF002/MF006) — no disk."""
+    from repro.comm.spec import CollectivePlan, _match
 
     coll, out = _parse_plan(manifest, location)
     sites = _site_paths(manifest, aux)
@@ -128,41 +124,6 @@ def lint_manifest_dict(manifest: dict, aux: Optional[dict] = None, *,
                     f"shadowed by earlier entries — it never resolves",
                     location=location))
 
-    # MF003 (provenance half): every fused/overlap mark needs a tuner
-    # eligibility record that says the kernel can actually serve it
-    report = {e.get("path"): e
-              for e in manifest.get("collective_tuner", ())}
-    for pat, short in (echo or {}).get("entries", ()):
-        try:
-            spec = parse_collective(short)
-        except ValueError:
-            continue   # already reported by the round-trip check
-        if not (getattr(spec, "fused", False)
-                or getattr(spec, "overlap", False)):
-            continue
-        entry = report.get(pat)
-        if entry is None:
-            out.append(Finding(
-                "MF003",
-                f"site {pat!r} is marked {short!r} but the manifest has "
-                f"no tuner record for it — unprovenanced eligibility",
-                location=location))
-            continue
-        elig = entry.get("eligibility")
-        if spec.fused:
-            if not elig:
-                out.append(Finding(
-                    "MF003",
-                    f"site {pat!r} is marked ':fused' but its tuner "
-                    f"record carries no eligibility provenance",
-                    location=location))
-            elif not elig.get("fusable"):
-                out.append(Finding(
-                    "MF003",
-                    f"site {pat!r} is marked ':fused' but the recorded "
-                    f"eligibility says it is not "
-                    f"({elig.get('reason', 'no reason recorded')})",
-                    location=location, detail=elig))
     return out
 
 
@@ -231,65 +192,6 @@ def _lint_rank_shards(dirpath: str, manifest: dict) -> list[Finding]:
     return out
 
 
-def _leaf_index(tree, path: str, stacked) -> object:
-    """The layer-0 node at a dotted path of a (possibly stacked) tree."""
-    import jax
-
-    node = tree
-    for part in path.split("."):
-        node = node[part]
-    lead = len(stacked or ())
-    if lead:
-        node = jax.tree.map(lambda a: a[(0,) * lead], node)
-    return node
-
-
-def _lint_fused_on_disk(dirpath: str, manifest: dict) -> list[Finding]:
-    """MF003 (disk half): re-derive wire eligibility from rank 0."""
-    from repro.comm.spec import parse_collective
-    from repro.kernels import dispatch as kdispatch
-    from repro.train import checkpoint
-
-    out: list[Finding] = []
-    marked = []
-    for pat, short in manifest.get("collective_plan", {}).get(
-            "entries", ()):
-        try:
-            spec = parse_collective(short)
-        except ValueError:
-            continue
-        if getattr(spec, "fused", False):
-            marked.append((pat, spec))
-    if not marked:
-        return out
-    rank0 = os.path.join(dirpath, "rank_00.npz")
-    if not os.path.exists(rank0):
-        return out       # MF004 already reports the missing file
-    tree = checkpoint.load(rank0)
-    meta = {m["path"]: m for m in manifest.get("pairs", ())}
-    tp = int(manifest["tp"])
-    for pat, spec in marked:
-        m = meta.get(pat)
-        if m is None:
-            continue     # an attn_vo/unknown site; provenance half covers it
-        try:
-            pair = _leaf_index(tree, pat, m.get("stacked"))
-            ok, why = kdispatch.wire_support(pair.down, spec, tp)
-        except Exception as e:
-            out.append(Finding(
-                "MF003",
-                f"could not re-derive wire eligibility for {pat!r}: {e}",
-                location=dirpath))
-            continue
-        if not ok:
-            out.append(Finding(
-                "MF003",
-                f"site {pat!r} is marked ':fused' but the rank-0 shard "
-                f"on disk cannot take the wire epilogue: {why}",
-                location=dirpath))
-    return out
-
-
 def _lint_fold_coverage(manifest: dict, aux: Optional[dict], *,
                         location: str) -> list[Finding]:
     """MF005: every shipped V->O fold is consumed or explicitly waived."""
@@ -333,7 +235,7 @@ def _lint_fold_coverage(manifest: dict, aux: Optional[dict], *,
 
 
 def lint_artifact(dirpath: str) -> list[Finding]:
-    """Full offline audit of one artifact directory (MF001–MF006)."""
+    """Full offline audit of one artifact directory (MF rules)."""
     from repro.plan.artifact import DeploymentArtifact
     from repro.train import checkpoint
 
@@ -346,7 +248,6 @@ def lint_artifact(dirpath: str) -> list[Finding]:
     aux = checkpoint.load(aux_path) if os.path.exists(aux_path) else None
     out = lint_manifest_dict(manifest, aux, location=dirpath)
     out += _lint_rank_shards(dirpath, manifest)
-    out += _lint_fused_on_disk(dirpath, manifest)
     out += _lint_fold_coverage(manifest, aux, location=dirpath)
     return out
 

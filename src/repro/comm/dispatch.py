@@ -48,7 +48,6 @@ Seed strategies (see DESIGN.md §1):
 from __future__ import annotations
 
 import math
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -66,19 +65,9 @@ class CollectiveStrategy:
     #: True when ``apply`` returns a result sharded along its last dim.
     scatters_output: bool = False
 
-    #: True when ``apply_wire`` accepts a kernel-emitted ``WirePayload``
-    #: (the fused Pallas epilogue of DESIGN.md §10).
-    accepts_wire: bool = False
-
     def apply(self, y: jax.Array, axis: str, spec: CollectiveSpec,
               policy) -> jax.Array:
         raise NotImplementedError
-
-    def apply_wire(self, wp, axis: str, spec: CollectiveSpec,
-                   policy) -> jax.Array:
-        raise NotImplementedError(
-            f"collective {spec.name!r} does not accept a pre-quantized "
-            f"wire payload")
 
     def bytes_on_wire(self, shape: tuple, tp: int,
                       spec: CollectiveSpec) -> float:
@@ -111,28 +100,8 @@ def resolve(name: str) -> CollectiveStrategy:
 
 
 def apply(y: jax.Array, axis: str, spec: CollectiveSpec, policy=None):
-    """Close a row-TP layer: run ``spec`` on one rank's partial sums.
-    ``:overlap`` quant specs route to the decomposed ``ppermute`` ring
-    (``dist/overlap.py``) — bit-identical, same wire bytes, but issued
-    as rotations the scheduler can hide behind compute."""
-    if spec.overlap:
-        from repro.dist import overlap as _overlap  # deferred: dist imports us
-        return _overlap.apply_overlapped(y, axis, spec, policy)
+    """Close a row-TP layer: run ``spec`` on one rank's partial sums."""
     return resolve(spec.name).apply(y, axis, spec, policy)
-
-
-def apply_wire(wp, axis: str, spec: CollectiveSpec, policy=None):
-    """Close a row-TP layer from a kernel-emitted ``WirePayload``: the
-    fused Pallas epilogue already ran ring phase 1's quantize, so the
-    collective starts directly at the payload exchange (DESIGN.md §10)."""
-    if spec.overlap:
-        from repro.dist import overlap as _overlap
-        return _overlap.apply_wire_overlapped(wp, axis, spec, policy)
-    return resolve(spec.name).apply_wire(wp, axis, spec, policy)
-
-
-def accepts_wire(spec: CollectiveSpec) -> bool:
-    return resolve(spec.name).accepts_wire
 
 
 def scatters_output(spec: CollectiveSpec) -> bool:
@@ -150,11 +119,6 @@ def bytes_on_wire(spec: CollectiveSpec, shape, tp: int) -> float:
 # and dist/ (``repro.analysis.ast_lint`` rule AS001): scheme and model
 # code goes through these wrappers, so every cross-rank byte traces to a
 # site the roofline cost model and the plan compiler account for.
-
-def axis_size(axis: str) -> int:
-    """Ring size of a named mesh axis (``lax.psum(1, axis)``)."""
-    return jax.lax.psum(1, axis)
-
 
 def raw_psum(y: jax.Array, axis: str) -> jax.Array:
     """Full-precision all-reduce outside the strategy registry — for
@@ -295,23 +259,6 @@ class _QuantInt8(CollectiveStrategy):
     implementation and the accounting are now the ring model.)
     """
 
-    accepts_wire = True
-
-    @staticmethod
-    def _exchange(q, s, axis, bs):
-        """Both ring phases from the chunked phase-1 payload ``(tp, ...,
-        chunk)``: exchange, dequant-accumulate, re-quantize, gather,
-        local dequantize.  Shared by ``apply`` and ``apply_wire``."""
-        q = jax.lax.all_to_all(q, axis, split_axis=0, concat_axis=0,
-                               tiled=True)
-        s = jax.lax.all_to_all(s, axis, split_axis=0, concat_axis=0,
-                               tiled=True)
-        red = jnp.sum(_blockwise_dequantize(q, s, bs), axis=0)
-        q2, s2 = _blockwise_quantize(red, bs)
-        qg = jax.lax.all_gather(q2, axis, axis=q2.ndim - 1, tiled=True)
-        sg = jax.lax.all_gather(s2, axis, axis=s2.ndim - 1, tiled=True)
-        return _blockwise_dequantize(qg, sg, bs)
-
     def apply(self, y, axis, spec, policy):
         tp = jax.lax.psum(1, axis)
         if tp == 1:
@@ -326,26 +273,18 @@ class _QuantInt8(CollectiveStrategy):
         bs = choose_group_size(chunk, spec.block_size)
         yc = jnp.moveaxis(y32.reshape(*y32.shape[:-1], tp, chunk), -2, 0)
         q, s = _blockwise_quantize(yc, bs)
-        out = self._exchange(q, s, axis, bs)
+        # phase 1: exchange the (tp, ..., chunk) payload, dequant-accumulate
+        q = jax.lax.all_to_all(q, axis, split_axis=0, concat_axis=0,
+                               tiled=True)
+        s = jax.lax.all_to_all(s, axis, split_axis=0, concat_axis=0,
+                               tiled=True)
+        red = jnp.sum(_blockwise_dequantize(q, s, bs), axis=0)
+        # phase 2: re-quantize, gather, dequantize locally
+        q2, s2 = _blockwise_quantize(red, bs)
+        qg = jax.lax.all_gather(q2, axis, axis=q2.ndim - 1, tiled=True)
+        sg = jax.lax.all_gather(s2, axis, axis=s2.ndim - 1, tiled=True)
+        out = _blockwise_dequantize(qg, sg, bs)
         return (out[..., :n] if pad else out).astype(out_dtype)
-
-    def apply_wire(self, wp, axis, spec, policy):
-        tp = jax.lax.psum(1, axis)
-        if tp == 1 or tp != wp.tp or wp.bits != 8:
-            raise ValueError(
-                f"wire payload (tp={wp.tp}, bits={wp.bits}) does not fit "
-                f"a {tp}-rank {spec.name} ring")
-        lead = wp.payload.shape[:-1]
-        n_pad = wp.payload.shape[-1]
-        chunk = n_pad // tp
-        bs = wp.block
-        # the flat payload chunks exactly (bs | chunk), so this reshape
-        # IS ring phase 1's quantized form — see comm/wire.py.
-        q = jnp.moveaxis(wp.payload.reshape(*lead, tp, chunk), -2, 0)
-        s = jnp.moveaxis(wp.scales.reshape(*lead, tp, chunk // bs), -2, 0)
-        out = self._exchange(q, s, axis, bs)
-        return (out[..., :wp.n] if n_pad != wp.n else out).astype(
-            wp.out_dtype)
 
     def bytes_on_wire(self, shape, tp, spec):
         if tp <= 1:
@@ -422,28 +361,6 @@ class _QuantInt4(CollectiveStrategy):
     the old full-payload one-phase all-gather fallback is gone.
     """
 
-    accepts_wire = True
-
-    @staticmethod
-    def _exchange(qp, s, z, axis, bs):
-        """Both ring phases from the chunked packed phase-1 payload
-        ``(tp, ..., chunk//8)`` — shared by ``apply`` and
-        ``apply_wire``."""
-        qp = jax.lax.all_to_all(qp, axis, split_axis=0, concat_axis=0,
-                                tiled=True)
-        s = jax.lax.all_to_all(s, axis, split_axis=0, concat_axis=0,
-                               tiled=True)
-        z = jax.lax.all_to_all(z, axis, split_axis=0, concat_axis=0,
-                               tiled=True)
-        red = jnp.sum(_blockwise_dequantize_int4(
-            _unpack4_last(qp), s, z, bs), axis=0)
-        q2, s2, z2 = _blockwise_quantize_int4(red, bs)
-        qp2 = _pack4_last(q2)
-        qg = jax.lax.all_gather(qp2, axis, axis=qp2.ndim - 1, tiled=True)
-        sg = jax.lax.all_gather(s2, axis, axis=s2.ndim - 1, tiled=True)
-        zg = jax.lax.all_gather(z2, axis, axis=z2.ndim - 1, tiled=True)
-        return _blockwise_dequantize_int4(_unpack4_last(qg), sg, zg, bs)
-
     def apply(self, y, axis, spec, policy):
         tp = jax.lax.psum(1, axis)
         if tp == 1:
@@ -458,29 +375,24 @@ class _QuantInt4(CollectiveStrategy):
         bs = choose_group_size(chunk, spec.block_size)
         yc = jnp.moveaxis(y32.reshape(*y32.shape[:-1], tp, chunk), -2, 0)
         q, s, z = _blockwise_quantize_int4(yc, bs)
-        out = self._exchange(_pack4_last(q), s, z, axis, bs)
+        # phase 1: exchange the packed (tp, ..., chunk//8) payload,
+        # dequant-accumulate
+        qp = jax.lax.all_to_all(_pack4_last(q), axis, split_axis=0,
+                                concat_axis=0, tiled=True)
+        s = jax.lax.all_to_all(s, axis, split_axis=0, concat_axis=0,
+                               tiled=True)
+        z = jax.lax.all_to_all(z, axis, split_axis=0, concat_axis=0,
+                               tiled=True)
+        red = jnp.sum(_blockwise_dequantize_int4(
+            _unpack4_last(qp), s, z, bs), axis=0)
+        # phase 2: re-quantize, pack, gather, dequantize locally
+        q2, s2, z2 = _blockwise_quantize_int4(red, bs)
+        qp2 = _pack4_last(q2)
+        qg = jax.lax.all_gather(qp2, axis, axis=qp2.ndim - 1, tiled=True)
+        sg = jax.lax.all_gather(s2, axis, axis=s2.ndim - 1, tiled=True)
+        zg = jax.lax.all_gather(z2, axis, axis=z2.ndim - 1, tiled=True)
+        out = _blockwise_dequantize_int4(_unpack4_last(qg), sg, zg, bs)
         return (out[..., :n] if pad else out).astype(out_dtype)
-
-    def apply_wire(self, wp, axis, spec, policy):
-        tp = jax.lax.psum(1, axis)
-        if tp == 1 or tp != wp.tp or wp.bits != 4:
-            raise ValueError(
-                f"wire payload (tp={wp.tp}, bits={wp.bits}) does not fit "
-                f"a {tp}-rank {spec.name} ring")
-        lead = wp.payload.shape[:-1]
-        n_pad = wp.payload.shape[-1] * PACK
-        bs = wp.block
-        # packed words never straddle chunk boundaries (8 | chunk), so
-        # the flat word array chunks exactly — see comm/wire.py.
-        words = n_pad // (tp * PACK)
-        qp = jnp.moveaxis(wp.payload.reshape(*lead, tp, words), -2, 0)
-        s = jnp.moveaxis(
-            wp.scales.reshape(*lead, tp, n_pad // (tp * bs)), -2, 0)
-        z = jnp.moveaxis(
-            wp.zeros.reshape(*lead, tp, n_pad // (tp * bs)), -2, 0)
-        out = self._exchange(qp, s, z, axis, bs)
-        return (out[..., :wp.n] if n_pad != wp.n else out).astype(
-            wp.out_dtype)
 
     def bytes_on_wire(self, shape, tp, spec):
         if tp <= 1:

@@ -20,17 +20,11 @@ and CLIs:
   payload is packed 8-nibbles-per-uint32 with the same
   ``quantization.pack_int4`` layout the weights use (block default 32 —
   15 levels need tighter blocks than int8's 255).
-* Either quant shorthand takes a trailing ``:fused`` flag
-  (``"quant-int8:128:fused"``) — the wire payload is emitted directly
-  from the Pallas dequant-GEMM accumulator tiles instead of a separate
-  quantize pass over ``y_partial`` (DESIGN.md §10); bit-identical on the
-  wire, so ``bytes_on_wire`` is unchanged.
-* ... and a trailing ``:overlap`` flag (``"quant-int8:128:fused:overlap"``;
-  flag order is accepted either way, the shorthand prints ``:fused``
-  first) — the two-phase ring is decomposed into explicit ``ppermute``
-  rotations and microbatch-pipelined against the down GEMM
-  (``dist/overlap.py``, DESIGN.md §11); bit-identical output and
-  identical wire bytes, only the *exposure* of the collective changes.
+
+Any other ``:`` token after a quant name is refused with a
+``ValueError`` naming it: configs, CLI strings and prepared manifests
+come from outside the program, so a token it does not know never runs
+as something else.
 
 ``CollectivePlan`` lifts the spec to a *per-layer* decision (tolerance
 to wire compression varies sharply by layer — Hansen-Palmus et al.
@@ -100,8 +94,6 @@ class CollectiveSpec:
     wire_dtype: Optional[Any] = None
     block_size: int = 128
     bits: Optional[int] = None   # None -> the strategy's payload width
-    fused: bool = False          # wire payload produced by the GEMM kernel
-    overlap: bool = False        # decomposed ring pipelined with the GEMM
 
     def __post_init__(self):
         from repro.comm import dispatch  # deferred: dispatch imports spec
@@ -128,14 +120,6 @@ class CollectiveSpec:
             raise ValueError(
                 f"{self.name} carries {want_bits}-bit payloads, got "
                 f"bits={self.bits}")
-        if self.fused and self.name not in ("quant-int8", "quant-int4"):
-            raise ValueError(
-                f"fused wire epilogue only applies to quant-int8/quant-int4 "
-                f"collectives, not {self.name!r}")
-        if self.overlap and self.name not in ("quant-int8", "quant-int4"):
-            raise ValueError(
-                f"overlapped epilogue only applies to quant-int8/quant-int4 "
-                f"collectives, not {self.name!r}")
 
     # ---- construction -----------------------------------------------------
 
@@ -154,27 +138,16 @@ class CollectiveSpec:
         if name == "cast":
             return cls(name="cast", wire_dtype=arg or "bfloat16")
         if name in ("quant-int8", "quant-int4"):
-            # quant shorthands: "<name>[:<block>][:fused][:overlap]" —
-            # "fused" means the GEMM kernel emits the wire payload,
-            # "overlap" the decomposed pipelined ring; trailing flags are
-            # accepted in either order, each at most once.
-            parts = [p for p in arg.split(":") if p] if arg else []
-            flags = set()
-            while parts and parts[-1] in ("fused", "overlap"):
-                if parts[-1] in flags:
+            # quant shorthands: "<name>[:<block>]", nothing else
+            tokens = arg.split(":") if arg else []
+            for i, token in enumerate(tokens):
+                if i > 0 or not token.isdigit():
                     raise ValueError(
-                        f"collective shorthand {value!r} repeats the "
-                        f"':{parts[-1]}' flag")
-                flags.add(parts.pop())
-            if len(parts) > 1:
-                raise ValueError(
-                    f"collective shorthand {value!r} has too many ':' "
-                    f"arguments (expected "
-                    f"'<name>[:<block>][:fused][:overlap]')")
+                        f"collective shorthand {value!r}: unexpected token "
+                        f"{token!r} (expected '{name}[:<block>]')")
             default_block = 128 if name == "quant-int8" else 32
             return cls(name=name, bits=4 if name == "quant-int4" else None,
-                       block_size=int(parts[0]) if parts else default_block,
-                       fused="fused" in flags, overlap="overlap" in flags)
+                       block_size=int(tokens[0]) if tokens else default_block)
         if arg:
             raise ValueError(
                 f"collective {name!r} takes no ':' argument (got {value!r})")
@@ -185,9 +158,7 @@ class CollectiveSpec:
         if self.name == "cast":
             return f"cast:{jnp.dtype(self.wire_dtype).name}"
         if self.name in ("quant-int8", "quant-int4"):
-            suffix = (":fused" if self.fused else "") + (
-                ":overlap" if self.overlap else "")
-            return f"{self.name}:{self.block_size}{suffix}"
+            return f"{self.name}:{self.block_size}"
         return self.name
 
     def with_(self, **kw) -> "CollectiveSpec":

@@ -141,30 +141,6 @@ def pair_pspecs(pp: PlannedPair, axis: str, x_batch_axes=()) -> PlannedPair:
     )
 
 
-_UNFUSABLE_WARNED: set = set()
-
-
-def _warn_unfusable(pair_path, pp: PlannedPair, reason: str) -> None:
-    """One-line, once-per-(site, reason) warning when a ':fused'
-    collective spec cannot use the wire kernel here (wrong layout / tp=1
-    / untileable K) — the dense GEMM + plain collective run instead of
-    erroring.  The cache key is (site path, reason): under ``lax.scan``
-    tracing (and re-traces for new shapes) the same site re-enters this
-    function per trace, and the old shape-derived key let one site warn
-    once per (K, N, tp) combination it was traced with."""
-    import warnings
-
-    key = (pair_path, reason)
-    if key in _UNFUSABLE_WARNED:
-        return
-    _UNFUSABLE_WARNED.add(key)
-    warnings.warn(
-        f"collective spec is ':fused' but the wire kernel cannot serve "
-        f"pair {pair_path!r} (scheme={pp.scheme}, down layout "
-        f"{pp.down.kind!r}: {reason}); using the plain epilogue",
-        stacklevel=3)
-
-
 def _pair_local_forward(
     x: jax.Array,
     pp: PlannedPair,
@@ -222,47 +198,11 @@ def _pair_local_forward(
     else:
         raise ValueError(f"unknown scheme {pp.scheme!r}")
 
-    # Down GEMM + trailing collective.  A ':fused' quant spec asks the
-    # Pallas wire-epilogue kernel to emit ring phase 1's payload straight
-    # from the accumulator tiles (DESIGN.md §10) — y_partial never lands
-    # in HBM; otherwise the dense GEMM + plain collective run.  An
-    # ':overlap' quant spec additionally pipelines the epilogue: the down
-    # GEMM runs per row-microbatch with the decomposed ppermute ring of
-    # one microbatch in flight across the next microbatch's GEMM
-    # (dist/overlap.py, DESIGN.md §11) — bit-identical either way.
-    spec = policy.collective.resolve(pair_path)
-    use_wire = False
-    if spec.fused:
-        from repro.kernels import dispatch as kdispatch
-
-        tp = comm.axis_size(axis)
-        use_wire, reason = kdispatch.wire_support(pp.down, spec, tp)
-        if not use_wire:
-            _warn_unfusable(pair_path, pp, reason)
-    # The trailing collective runs under the "epilogue" scope, which names
-    # its ops in the compiled program's op_name metadata (with ':overlap'
-    # the pipelined down GEMMs are inside it too).
-    if spec.overlap:
-        from repro.dist import overlap as dist_overlap
-        from repro.kernels import dispatch as kdispatch
-
-        tp = comm.axis_size(axis)
-        gemm_wire = (functools.partial(
-            kdispatch.qmatmul_wire, ql=pp.down, policy=policy, spec=spec,
-            tp=tp) if use_wire else None)
-        with jax.named_scope("epilogue"):
-            return dist_overlap.pipelined_epilogue(
-                y1, axis=axis, spec=spec,
-                gemm=lambda y: mm(y, pp.down), gemm_wire=gemm_wire)
-    if use_wire:
-        from repro.kernels import dispatch as kdispatch
-
-        tp = comm.axis_size(axis)
-        wp = kdispatch.qmatmul_wire(y1, pp.down, policy, spec=spec, tp=tp)
-        with jax.named_scope("epilogue"):
-            return comm.apply_wire(wp, axis, spec, policy)
     y2 = mm(y1, pp.down)                             # l.2 / l.5 down GEMM
-    # l.6 / l.3: close the row-TP layer with the planned collective.
+    # l.6 / l.3: close the row-TP layer with the planned collective, under
+    # the "epilogue" scope, which names its ops in the compiled program's
+    # op_name metadata.
+    spec = policy.collective.resolve(pair_path)
     with jax.named_scope("epilogue"):
         return comm.apply(y2, axis, spec, policy)
 

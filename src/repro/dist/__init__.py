@@ -1,6 +1,6 @@
 """Distributed runtime subsystem (DESIGN.md §11).
 
-Three pillars over the serve path:
+Two pillars over the serve path:
 
 * ``topology``  — ``MeshPlan``: the frozen DP×TP(×EP) device grid,
   carried on ``ExecutionPolicy.mesh`` and recorded in the artifact
@@ -10,11 +10,6 @@ Three pillars over the serve path:
   name, and assembles global arrays from per-device addressable shards
   (``jax.make_array_from_single_device_arrays``) — no host ever
   materializes another rank's slices.
-* ``overlap``   — the ``:overlap`` epilogue mode for the quantized
-  collectives: the two-phase ring is decomposed into explicit
-  ``ppermute`` rotations and the epilogue is microbatch-pipelined so the
-  ring of one microbatch is in flight while the next microbatch's
-  dequant-GEMM computes — bit-identical to the synchronous strategy.
 """
 
 from repro.dist.loader import RankLoadStats, load_per_rank

@@ -136,12 +136,10 @@ def _expand_groups(meta_ref, group_size: int):
 # ordered-groups kernel
 # ---------------------------------------------------------------------------
 
-def _ordered_gemm_step(x_ref, qw_ref, s_ref, z_ref, acc_ref, *,
-                       group_size: int, bk: int, compute_dtype):
-    """One K-step of the ordered dequant-GEMM: unpack + dequant one
-    ``(bk, bn)`` weight tile and accumulate into the f32 scratch.  Shared
-    by the dense and the fused-wire-epilogue kernels so both produce
-    bit-identical accumulator contents."""
+def _dequant_matmul_ordered_kernel(x_ref, qw_ref, s_ref, z_ref, o_ref,
+                                   acc_ref, *, group_size: int, bk: int,
+                                   compute_dtype):
+    """Grid (M/bm, N/bn, K/bk); K innermost so acc_ref carries the sum."""
     kk = pl.program_id(2)
 
     @pl.when(kk == 0)
@@ -163,15 +161,6 @@ def _ordered_gemm_step(x_ref, qw_ref, s_ref, z_ref, acc_ref, *,
         precision=(jax.lax.Precision.HIGHEST
                    if jnp.dtype(compute_dtype) == jnp.float32 else None),
         preferred_element_type=jnp.float32)
-
-
-def _dequant_matmul_ordered_kernel(x_ref, qw_ref, s_ref, z_ref, o_ref,
-                                   acc_ref, *, group_size: int, bk: int,
-                                   compute_dtype):
-    """Grid (M/bm, N/bn, K/bk); K innermost so acc_ref carries the sum."""
-    _ordered_gemm_step(x_ref, qw_ref, s_ref, z_ref, acc_ref,
-                       group_size=group_size, bk=bk,
-                       compute_dtype=compute_dtype)
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
     def _done():
@@ -215,158 +204,6 @@ def dequant_matmul_ordered(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
-    )(x, qweight, scales, zeros)
-
-
-# ---------------------------------------------------------------------------
-# fused wire-epilogue kernels (ordered layout only, DESIGN.md §10)
-#
-# The quantized collectives (comm/dispatch quant-int8/int4) re-read the
-# dense GEMM output from HBM just to blockwise-quantize it onto the wire.
-# These variants emit the wire payload (+f16 scales[/zeros]) DIRECTLY from
-# the f32 accumulator tile at the last K step — y_partial never exists in
-# HBM.  The quantize math replicates comm/dispatch._blockwise_quantize /
-# _blockwise_quantize_int4 operation-for-operation so the payload is
-# bit-identical to quantize(dense-kernel output).
-# ---------------------------------------------------------------------------
-
-def _dequant_matmul_wire8_kernel(x_ref, qw_ref, s_ref, z_ref, p_ref, ws_ref,
-                                 acc_ref, *, group_size: int, bk: int,
-                                 wire_block: int, compute_dtype, out_dtype):
-    """Dense kernel's GEMM + symmetric-int8 wire quantize of the output
-    tile: ``p_ref`` (bm, bn) int8 payload, ``ws_ref`` (bm, bn/wire_block)
-    f16 scales."""
-    _ordered_gemm_step(x_ref, qw_ref, s_ref, z_ref, acc_ref,
-                       group_size=group_size, bk=bk,
-                       compute_dtype=compute_dtype)
-
-    @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
-    def _done():
-        # match the unfused dtype chain: kernel output in out_dtype, then
-        # the collective's f32 upcast — required for bit-identity.
-        y = acc_ref[...].astype(out_dtype).astype(jnp.float32)
-        bm, bn = y.shape
-        vb = y.reshape(bm, bn // wire_block, wire_block)
-        s = jnp.max(jnp.abs(vb), axis=-1) / 127.0
-        s = jnp.maximum(s, jnp.finfo(jnp.float32).tiny)
-        q = jnp.clip(jnp.round(vb / s[..., None]), -127, 127)
-        p_ref[...] = q.reshape(bm, bn).astype(jnp.int8)
-        ws_ref[...] = s.astype(jnp.float16)
-
-
-def _dequant_matmul_wire4_kernel(x_ref, qw_ref, s_ref, z_ref, p_ref, ws_ref,
-                                 wz_ref, acc_ref, *, group_size: int, bk: int,
-                                 wire_block: int, compute_dtype, out_dtype):
-    """Dense kernel's GEMM + asymmetric-int4 wire quantize with in-kernel
-    nibble packing (the weights' ``pack_int4`` layout: 8 values per
-    uint32): ``p_ref`` (bm, bn/8) uint32, ``ws_ref``/``wz_ref``
-    (bm, bn/wire_block) f16."""
-    _ordered_gemm_step(x_ref, qw_ref, s_ref, z_ref, acc_ref,
-                       group_size=group_size, bk=bk,
-                       compute_dtype=compute_dtype)
-
-    @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
-    def _done():
-        y = acc_ref[...].astype(out_dtype).astype(jnp.float32)
-        bm, bn = y.shape
-        vb = y.reshape(bm, bn // wire_block, wire_block)
-        vmax = jnp.maximum(jnp.max(vb, axis=-1), 0.0)
-        vmin = jnp.minimum(jnp.min(vb, axis=-1), 0.0)
-        s = (vmax - vmin) / 15.0
-        s = jnp.where(s <= 0, 1.0, s)
-        z = jnp.clip(jnp.round(-vmin / s), 0, 15)
-        q = jnp.clip(jnp.round(vb / s[..., None] + z[..., None]), 0, 15)
-        q = q.reshape(bm, bn).astype(jnp.uint32)
-        shifts = (jnp.arange(PACK, dtype=jnp.uint32) * 4)[None, None, :]
-        p_ref[...] = jnp.sum(q.reshape(bm, bn // PACK, PACK) << shifts,
-                             axis=-1, dtype=jnp.uint32)
-        ws_ref[...] = s.astype(jnp.float16)
-        wz_ref[...] = z.astype(jnp.float16)
-
-
-def pick_block_wire(n: int, wire_block: int, wire_bits: int,
-                    target: int = 128) -> int:
-    """N-tile for the wire kernels: wire-quant blocks (and, for int4,
-    packed uint32 words) must not straddle tiles, so bn is a multiple of
-    ``wire_block`` (int8) / ``lcm(wire_block, 8)`` (int4) dividing N."""
-    base = wire_block if wire_bits == 8 else _lcm(wire_block, PACK)
-    if n % base:
-        raise ValueError(
-            f"N={n} not tileable with wire_block={wire_block} "
-            f"(bits={wire_bits})")
-    bn = base
-    while bn * 2 <= min(n, target) and n % (bn * 2) == 0:
-        bn *= 2
-    return bn
-
-
-def dequant_matmul_wire_ordered(
-    x: jax.Array,           # (M, K)
-    qweight: jax.Array,     # (K//8, N) uint32
-    scales: jax.Array,      # (G, N)
-    zeros: jax.Array,       # (G, N)
-    *,
-    group_size: int,
-    wire_block: int,
-    wire_bits: int,
-    block_m: int = 128,
-    block_n: int = 128,
-    block_k: int | None = None,
-    compute_dtype=jnp.float32,
-    out_dtype=None,
-    interpret: bool,
-):
-    """Fused GEMM + wire quantize.  Returns the flat wire tuple:
-    int8 -> ``(payload (M, N) int8, scales (M, N/wire_block) f16)``;
-    int4 -> ``(payload (M, N/8) uint32, scales, zeros)``.  Bit-identical
-    to ``_blockwise_quantize[_int4](dequant_matmul_ordered(...))``."""
-    m, k = x.shape
-    n = qweight.shape[1]
-    if wire_bits not in (4, 8):
-        raise ValueError(f"wire_bits must be 4 or 8, got {wire_bits}")
-    bk = block_k or pick_block_k(k, group_size)
-    bm = min(block_m, m)
-    bn = pick_block_wire(n, wire_block, wire_bits, target=block_n)
-    if m % bm or k % bk or bk % group_size:
-        raise ValueError(f"bad tiling m={m},k={k} bm={bm},bk={bk}")
-    out_dtype = out_dtype or compute_dtype
-
-    grid = (m // bm, n // bn, k // bk)
-    in_specs = [
-        pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-        pl.BlockSpec((bk // PACK, bn), lambda i, j, kk: (kk, j)),
-        pl.BlockSpec((bk // group_size, bn), lambda i, j, kk: (kk, j)),
-        pl.BlockSpec((bk // group_size, bn), lambda i, j, kk: (kk, j)),
-    ]
-    wb = bn // wire_block
-    if wire_bits == 8:
-        kernel = functools.partial(
-            _dequant_matmul_wire8_kernel, group_size=group_size, bk=bk,
-            wire_block=wire_block, compute_dtype=compute_dtype,
-            out_dtype=out_dtype)
-        out_shape = (jax.ShapeDtypeStruct((m, n), jnp.int8),
-                     jax.ShapeDtypeStruct((m, n // wire_block), jnp.float16))
-        out_specs = [pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-                     pl.BlockSpec((bm, wb), lambda i, j, kk: (i, j))]
-    else:
-        kernel = functools.partial(
-            _dequant_matmul_wire4_kernel, group_size=group_size, bk=bk,
-            wire_block=wire_block, compute_dtype=compute_dtype,
-            out_dtype=out_dtype)
-        out_shape = (jax.ShapeDtypeStruct((m, n // PACK), jnp.uint32),
-                     jax.ShapeDtypeStruct((m, n // wire_block), jnp.float16),
-                     jax.ShapeDtypeStruct((m, n // wire_block), jnp.float16))
-        out_specs = [pl.BlockSpec((bm, bn // PACK), lambda i, j, kk: (i, j)),
-                     pl.BlockSpec((bm, wb), lambda i, j, kk: (i, j)),
-                     pl.BlockSpec((bm, wb), lambda i, j, kk: (i, j))]
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
     )(x, qweight, scales, zeros)

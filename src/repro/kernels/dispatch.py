@@ -20,12 +20,6 @@ Seed entries (see DESIGN.md §1):
   sees real FLOPs/bytes.
 * ``pallas`` — the fused kernels: Algorithm-1 ordered layout
   (``pallas-ordered``) and the naive g_idx gather (``pallas-gidx``).
-* ``pallas-fused`` — the fused WIRE-epilogue kernel (ordered layout
-  only, DESIGN.md §10): its output contract is the quantized-collective
-  wire tuple ``(payload, scales[, zeros])``, not a dense ``y_partial``.
-  It is never selected by ``ExecutionPolicy.backend``; the per-site
-  ``CollectivePlan`` opts in via a ``:fused`` quant spec and
-  ``schemes._pair_local_forward`` calls ``qmatmul_wire``.
 
 A site the Pallas kernels cannot tile raises ``ValueError`` with the
 reason (``dequant_matmul.check_tiling``) on every platform: the pallas
@@ -48,10 +42,6 @@ KernelFn = Callable[[jax.Array, QuantizedLinear, ExecutionPolicy], jax.Array]
 _REGISTRY: dict[tuple[str, str], KernelFn] = {}
 
 KINDS = ("ordered", "naive")
-
-#: backends whose output is a wire tuple, not a dense (..., N) array —
-#: resolvable via the same registry but excluded from ``qmatmul``.
-WIRE_BACKENDS = ("pallas-fused",)
 
 
 def register(kind: str, backend: str):
@@ -86,54 +76,7 @@ def resolve(kind: str, backend: str) -> KernelFn:
 def qmatmul(x: jax.Array, ql: QuantizedLinear,
             policy: ExecutionPolicy) -> jax.Array:
     """``x @ dequantize(ql)`` via the policy-selected kernel."""
-    if policy.backend in WIRE_BACKENDS:
-        raise ValueError(
-            f"backend {policy.backend!r} emits a wire payload, not a dense "
-            f"output; it is selected per site by a ':fused' collective spec "
-            f"(CollectivePlan), not by ExecutionPolicy.backend")
     return resolve(ql.kind, policy.backend)(x, ql, policy)
-
-
-def qmatmul_wire(x: jax.Array, ql: QuantizedLinear, policy: ExecutionPolicy,
-                 *, spec, tp: int):
-    """Fused GEMM + wire quantize -> ``comm.wire.WirePayload`` ready for
-    ``comm.apply_wire`` (ring phase 1 starts from the kernel output).
-    ``spec`` is the resolved quant-int8/int4 ``CollectiveSpec`` with
-    ``fused=True``; caller guarantees ``supports_wire(ql, spec, tp)``."""
-    from repro.comm.wire import WirePayload, wire_params
-
-    payload, scales, zeros = resolve(ql.kind, "pallas-fused")(
-        x, ql, policy, spec=spec, tp=tp)
-    _, _, bs = wire_params(ql.n, tp, spec.bits, spec.block_size)
-    return WirePayload(payload, scales, zeros, n=ql.n, tp=tp,
-                       bits=spec.bits, block=bs,
-                       out_dtype=policy.compute_dtype)
-
-
-def supports_wire(ql: QuantizedLinear, spec, tp: int) -> bool:
-    """True when the fused wire epilogue CAN serve this GEMM site: a
-    quantized full-output collective, a real ring (``tp > 1``), the
-    ordered layout, and a Pallas-tileable K.  The tuner uses this to
-    decide whether to mark a chosen spec ``fused``; the runtime gate in
-    ``schemes._pair_local_forward`` re-checks it (plus ``spec.fused``),
-    so a compiled ``:fused`` plan never dies at forward time."""
-    return wire_support(ql, spec, tp)[0]
-
-
-def wire_support(ql: QuantizedLinear, spec, tp: int) -> tuple[bool, str]:
-    """``supports_wire`` with the reason it fails — ``(True, "")`` when
-    the wire kernel applies, else ``(False, why)``.  The reason string is
-    shape/layout-derived (never trace-dependent), which is what
-    ``schemes._warn_unfusable`` keys its once-per-(site, reason) cache
-    on."""
-    name = getattr(spec, "name", None)
-    if name not in ("quant-int8", "quant-int4"):
-        return False, f"collective {name!r} has no wire payload form"
-    if tp <= 1:
-        return False, "tp=1 (no ring to feed)"
-    if ql.kind != "ordered" or ("ordered", "pallas-fused") not in _REGISTRY:
-        return False, f"layout {ql.kind!r} has no wire-epilogue kernel"
-    return _tileable(ql)
 
 
 # ---------------------------------------------------------------------------
@@ -208,20 +151,5 @@ def _pallas_gidx(x, ql, policy):
     t = policy.tiling
     return ops.pallas_dequant_matmul_gidx(
         x, ql, compute_dtype=policy.compute_dtype,
-        block_m=t.block_m, block_n=t.block_n, block_k=t.block_k,
-        interpret=t.interpret)
-
-
-@register("ordered", "pallas-fused")
-def _pallas_fused_wire(x, ql, policy, *, spec, tp):
-    """Wire-contract entry (DESIGN.md §10): returns ``(payload, scales,
-    zeros-or-None)`` over the ring-padded width instead of a dense
-    ``y_partial`` — use via ``qmatmul_wire``, never ``qmatmul``."""
-    from repro.kernels import ops
-
-    t = policy.tiling
-    return ops.dequant_matmul_wire(
-        x, ql, tp=tp, wire_bits=spec.bits, wire_block=spec.block_size,
-        compute_dtype=policy.compute_dtype,
         block_m=t.block_m, block_n=t.block_n, block_k=t.block_k,
         interpret=t.interpret)

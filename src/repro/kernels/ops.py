@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.policy import interpret_mode
-from repro.core.quantization import PACK, QuantizedLinear
+from repro.core.quantization import QuantizedLinear
 from repro.kernels import dequant_matmul as dk
 
 
@@ -80,75 +80,6 @@ def dequant_matmul(
             block_m=bm, block_n=bn, compute_dtype=compute_dtype,
             interpret=interpret, **bk_kw)
     return y[:m, :n].reshape(*lead, n)
-
-
-@functools.partial(jax.jit, static_argnames=("tp", "wire_bits", "wire_block",
-                                             "compute_dtype", "block_m",
-                                             "block_n", "block_k",
-                                             "interpret"))
-def dequant_matmul_wire(
-    x: jax.Array,
-    ql: QuantizedLinear,
-    *,
-    tp: int,
-    wire_bits: int,
-    wire_block: int,
-    compute_dtype=jnp.float32,
-    block_m: int = 128,
-    block_n: int = 128,
-    block_k: int | None = None,
-    interpret: bool | None = None,
-):
-    """Fused GEMM + blockwise wire quantize (DESIGN.md §10).
-
-    ``x``: (..., K).  Returns the FLAT wire tuple over the ring-padded
-    width ``n_pad`` (see ``comm/wire.wire_params``): ``(payload, scales,
-    zeros-or-None)`` with shapes ``(..., n_pad)`` int8 / ``(..., n_pad //
-    8)`` uint32 packed, and ``(..., n_pad // block)`` f16 — bit-identical
-    to blockwise-quantizing the zero-padded dense kernel output.
-    ``wire_block`` is the spec's PREFERRED block; the block actually used
-    is ``choose_group_size(n_pad // tp, wire_block)``, exactly as the
-    unfused collective picks it.
-    """
-    from repro.comm.wire import wire_params
-
-    interpret = interpret_mode(interpret)
-    if ql.kind != "ordered":
-        raise ValueError(f"wire kernel needs the ordered layout, "
-                         f"got {ql.kind!r}")
-    *lead, k = x.shape
-    if k != ql.k:
-        raise ValueError(f"x K={k} != weight K={ql.k}")
-    n = ql.n
-    n_pad, _, bs = wire_params(n, tp, wire_bits, wire_block)
-    m = 1
-    for d in lead:
-        m *= d
-    x2 = x.reshape(m, k)
-    bm = min(block_m, max(8, m))
-    x2 = _pad_to(x2, bm, 0)
-
-    qweight, scales, zeros = ql.qweight, ql.scales, ql.zeros
-    if n_pad != n:
-        widths = [(0, 0), (0, n_pad - n)]
-        qweight = jnp.pad(qweight, widths)
-        # zero-padded SCALES make the padded columns dequantize to an
-        # exact 0.0 — the same zeros the unfused path pads y_partial with.
-        scales = jnp.pad(scales, widths)
-        zeros = jnp.pad(zeros, widths)
-
-    out = dk.dequant_matmul_wire_ordered(
-        x2, qweight, scales, zeros, group_size=ql.group_size,
-        wire_block=bs, wire_bits=wire_bits, block_m=bm, block_n=block_n,
-        block_k=block_k, compute_dtype=compute_dtype, interpret=interpret)
-    if wire_bits == 8:
-        p, s = out
-        return (p[:m].reshape(*lead, n_pad),
-                s[:m].reshape(*lead, n_pad // bs), None)
-    p, s, z = out
-    return (p[:m].reshape(*lead, n_pad // PACK),
-            s[:m].reshape(*lead, n_pad // bs),
-            z[:m].reshape(*lead, n_pad // bs))
 
 
 def pallas_dequant_matmul_ordered(x, ql, *, compute_dtype=jnp.float32,

@@ -143,119 +143,6 @@ def parse_collective_bytes(hlo_text: str, *, chips: int) -> dict:
     return out
 
 
-# ---------------------------------------------------------------------------
-# async-window verification (dist/overlap.py, DESIGN.md §11)
-# ---------------------------------------------------------------------------
-
-# an instruction definition: "  %name = <result> <opcode>(operands...)" —
-# opcode is the first bare token after the result type(s)
-_DEF_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?%?([A-Za-z0-9_.\-]+)\s*=\s*"
-    r"(?:\([^)]*\)|[a-z0-9_]+\[[\d,]*\]\S*)\s+([a-z0-9\-]+)")
-_OPERAND_RE = re.compile(r"%([A-Za-z0-9_.\-]+)")
-# a computation header: "%comp_name (param: ...) -> result {" / "ENTRY %..."
-_COMP_RE = re.compile(r"^\s*(?:ENTRY\s+)?%?([A-Za-z0-9_.\-]+)\s*\(.*\{\s*$")
-_GEMM_OPS = ("dot", "custom-call")   # plain dots (CPU/interpret mode) or
-                                     # Pallas custom-calls (TPU)
-
-
-def parse_overlap_windows(hlo_text: str,
-                          kinds=("collective-permute",)) -> dict:
-    """Async-window analysis of a *scheduled* compiled HLO module.
-
-    For every collective of ``kinds`` (synchronous form or async
-    ``-start``), the window is the span of scheduled instructions
-    strictly between the collective and the first instruction that
-    consumes its result (for async pairs that consumer is the ``-done``).
-    A window containing a GEMM means the scheduler placed compute inside
-    the collective's in-flight span — the overlap ``dist/overlap.py``
-    pipelines for, on both encodings: backends with async collectives
-    emit explicit start/done tuples, while CPU XLA keeps the
-    instructions synchronous but the printed module *is* the schedule
-    (``is_scheduled=true``), so instruction order between issue and
-    first use is exactly the overlap window.  That holds for CPU XLA's
-    sequential scheduler only: compile with
-    ``--xla_cpu_enable_concurrency_optimized_scheduler=false`` (the
-    default concurrency-optimized one hoists independent dots ahead of
-    every collective and runs them concurrently instead).
-
-    A GEMM is a ``dot`` or ``custom-call`` instruction, directly or
-    transitively inside a called computation (fusions, Pallas interpret
-    grid loops, and scanned layers wrap the dot in ``fusion`` / ``call``
-    / ``while`` ops whose bodies are separate computations).  Windows are
-    scanned per computation body — ``lax.scan`` rings live in while-loop
-    bodies, not ENTRY.
-
-    Returns ``{"collectives": N, "spanning": M, "windows": [...]}`` where
-    each window records the instruction name, window length, and how
-    many GEMM-containing instructions it spans.
-    """
-    # pass 1: per computation, the instruction list and referenced comps
-    comps: dict = {}
-    cur_name, body = None, []
-    for line in hlo_text.splitlines():
-        mdef = _DEF_RE.match(line)
-        if mdef:
-            name, opcode = mdef.groups()
-            rhs = line.split("=", 1)[1]
-            operands = set(_OPERAND_RE.findall(rhs)) - {name}
-            body.append((name, opcode, operands))
-            continue
-        mcomp = _COMP_RE.match(line)
-        if mcomp:
-            cur_name, body = mcomp.group(1), []
-            comps[cur_name] = body
-        elif line.strip().startswith("}") and cur_name is not None:
-            cur_name = None
-
-    # pass 2: which computations (transitively) contain a GEMM
-    has_gemm: dict = {}
-
-    def _contains_gemm(comp, seen=()):
-        if comp in has_gemm:
-            return has_gemm[comp]
-        if comp in seen:
-            return False
-        out = False
-        for _, opcode, operands in comps.get(comp, ()):
-            if opcode in _GEMM_OPS:
-                out = True
-                break
-            if any(_contains_gemm(ref, seen + (comp,))
-                   for ref in operands if ref in comps):
-                out = True
-                break
-        has_gemm[comp] = out
-        return out
-
-    def _is_gemm(opcode, operands):
-        return opcode in _GEMM_OPS or any(
-            _contains_gemm(ref) for ref in operands if ref in comps)
-
-    # pass 3: windows
-    windows = []
-    for comp, instrs in comps.items():
-        for i, (name, opcode, _) in enumerate(instrs):
-            if not any(opcode == k or opcode == k + "-start"
-                       for k in kinds):
-                continue
-            gemms, wlen = 0, 0
-            for _, opcode2, operands2 in instrs[i + 1:]:
-                if name in operands2:
-                    break
-                wlen += 1
-                if _is_gemm(opcode2, operands2):
-                    gemms += 1
-            windows.append({"computation": comp, "name": name,
-                            "opcode": opcode, "window_len": wlen,
-                            "gemms": gemms})
-    return {
-        "collectives": len(windows),
-        "spanning": sum(1 for w in windows if w["gemms"]),
-        "windows": windows,
-    }
-
-
 @dataclasses.dataclass
 class Roofline:
     arch: str

@@ -149,15 +149,7 @@ def prepare(argv=None):
                     help="max relative activation error a tuned "
                          "collective may introduce (default: the "
                          "tuner's DEFAULT_BUDGET, 0.05)")
-    ap.add_argument("--overlap-collectives", action="store_true",
-                    help="mark tuned quantized epilogues ':overlap' — "
-                         "the serve-time ring is decomposed into "
-                         "ppermute rotations pipelined against the next "
-                         "microbatch's dequant-GEMM (bit-identical; "
-                         "requires --autotune-collectives)")
     args = ap.parse_args(argv)
-    if args.overlap_collectives and not args.autotune_collectives:
-        ap.error("--overlap-collectives requires --autotune-collectives")
 
     cfg = _build_cfg(args)
     if args.num_layers is not None:
@@ -171,8 +163,7 @@ def prepare(argv=None):
                            extra_manifest={"smoke": bool(args.smoke),
                                            "num_layers": cfg.num_layers},
                            autotune=args.autotune_collectives,
-                           tune_budget=args.tune_budget,
-                           tune_overlap=args.overlap_collectives)
+                           tune_budget=args.tune_budget)
     path = art.save(args.out)
     dt = time.time() - t0
     n_pairs = len(art.manifest["pairs"])
@@ -182,8 +173,7 @@ def prepare(argv=None):
           f"tp={args.tp}) -> {path}: {n_pairs} planned pair(s), "
           f"{len(art.manifest['leaf_shards'])} leaves, {dt:.1f}s")
     for site in art.manifest.get("collective_tuner", ()):
-        # ':fused'-suffixed choices run the wire-epilogue kernel
-        # (DESIGN.md §10); attn_vo sites are the V->O fold epilogues
+        # attn_vo sites are the V->O fold epilogues
         print(f"  tuned {site['path']} [{site.get('kind', 'pair')}]: "
               f"{site['chosen']} ({site['status']})")
     return path

@@ -429,8 +429,7 @@ def compile_plan(cfg: ModelConfig, raw_params: Any, *, tp: int,
                  seed: Optional[int] = None,
                  extra_manifest: Optional[dict] = None,
                  autotune: bool = False,
-                 tune_budget: Optional[float] = None,
-                 tune_overlap: bool = False):
+                 tune_budget: Optional[float] = None):
     """Full offline compile: raw fp params -> ``DeploymentArtifact``.
 
     Runs every stage (quantize, layout, attention fold, attention
@@ -439,8 +438,6 @@ def compile_plan(cfg: ModelConfig, raw_params: Any, *, tp: int,
     ``plan/tuner.py``'s ``autotune_collectives`` (max rel-error
     ``tune_budget``; tuner default when None) so the artifact carries a
     per-layer ``CollectivePlan`` instead of one global collective.
-    ``tune_overlap=True`` marks the tuner's quantized pair choices
-    ``:overlap`` (decomposed compute-overlapped ring, DESIGN.md §11).
     ``seed`` is provenance only (recorded so a served artifact can name
     the init stream it came from).
     """
@@ -456,7 +453,6 @@ def compile_plan(cfg: ModelConfig, raw_params: Any, *, tp: int,
         from repro.plan import tuner
 
         kw = {} if tune_budget is None else {"budget": tune_budget}
-        kw["overlap"] = tune_overlap
         stages.append(lambda s: tuner.autotune_collectives(s, **kw))
     stages.append(stage_shard)
     state = run_stages(state, tuple(stages))
@@ -468,8 +464,7 @@ def prepare(cfg: ModelConfig, *, tp: int, seed: int = 0,
             policy: Optional[ExecutionPolicy] = None,
             extra_manifest: Optional[dict] = None,
             autotune: bool = False,
-            tune_budget: Optional[float] = None,
-            tune_overlap: bool = False):
+            tune_budget: Optional[float] = None):
     """Seed -> artifact, the canonical prepare recipe.
 
     Derives the raw init and the plan rng exactly the way ``Model.init``
@@ -484,5 +479,4 @@ def prepare(cfg: ModelConfig, *, tp: int, seed: int = 0,
     return compile_plan(
         cfg, raw, tp=tp, rng=jax.random.fold_in(key, PLAN_RNG_STREAM),
         policy=policy, seed=seed, extra_manifest=extra_manifest,
-        autotune=autotune, tune_budget=tune_budget,
-        tune_overlap=tune_overlap)
+        autotune=autotune, tune_budget=tune_budget)

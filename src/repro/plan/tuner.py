@@ -31,20 +31,10 @@ Sites the tuner cannot shard for the target TP degree (non-divisible N1,
 group-misaligned shards) keep the default — recorded as ``untunable`` in
 the report, never silently dropped.
 
-Two site-level refinements (DESIGN.md §10):
-
-* When the winning spec is a quantized collective AND the site's down
-  GEMM can run the fused wire-epilogue kernel
-  (``kernels.dispatch.supports_wire``: ordered layout, tp > 1, tileable
-  K), the compiled entry is marked ``:fused`` — the Pallas kernel emits
-  ring phase 1's payload straight from the accumulator tiles.  The wire
-  bytes and numerics are bit-identical to the unfused spec, so the
-  score carries over; the report records ``fused: true``.
-* Aux attention V->O folds (``cfg.quant.attn_tp_aware``) are probed as
-  sites too (``kind: "attn_vo"`` in the report) now that the attention
-  runtime consumes them; their entries join the plan under the fold's
-  dotted path.  They are never marked fused — the attention forward
-  closes its epilogue through GSPMD, not the explicit-collective path.
+Aux attention V->O folds (``cfg.quant.attn_tp_aware``) are probed as
+sites too (``kind: "attn_vo"`` in the report) now that the attention
+runtime consumes them; their entries join the plan under the fold's
+dotted path.
 """
 
 from __future__ import annotations
@@ -147,8 +137,6 @@ def _site_attn_pair(plans):
 def _probe_site(pp, tp: int, rng, calib_batch: int, candidates,
                 activation: Optional[str]):
     """Score every candidate on one pair site; returns {shorthand: dict}."""
-    from repro.kernels import dispatch as kdispatch
-
     shards = reorder.shard_pair(pp, tp)
     x = jax.random.normal(rng, (calib_batch, pp.k1), jnp.float32)
     partials = [
@@ -161,19 +149,11 @@ def _probe_site(pp, tp: int, rng, calib_batch: int, candidates,
     for spec in candidates:
         sim = simulate_wire(partials, spec)
         err = float(jnp.max(jnp.abs(sim - exact))) / max(scale, 1e-30)
-        # can the fused wire-epilogue kernel serve this site's down
-        # GEMM? (per-rank shard geometry, so probe the shard) — keep the
-        # verdict AND the reason: the manifest records this eligibility
-        # provenance and repro.analysis cross-checks ':fused' marks
-        # against it offline
-        fusable, why = kdispatch.wire_support(shards[0].down, spec, tp)
         scores[spec.shorthand()] = {
             "spec": spec,
             "rel_err": err,
             # per-token wire bytes (batch-independent ranking)
             "bytes_per_token": spec.bytes_on_wire((1, pp.n2), tp),
-            "fusable": fusable,
-            "fuse_reason": why,
         }
     return scores
 
@@ -181,23 +161,13 @@ def _probe_site(pp, tp: int, rng, calib_batch: int, candidates,
 def autotune_collectives(state, mesh=None, *,
                          budget: float = DEFAULT_BUDGET,
                          calib_batch: int = 8,
-                         candidates=None,
-                         overlap: bool = False):
+                         candidates=None):
     """Compiler stage: choose a per-layer ``CollectivePlan`` for ``state``.
 
     ``mesh`` (optional) only supplies the TP degree when ``state.tp`` is
     unset — the probe itself is mesh-free (see ``simulate_wire``).
     Returns a new ``PlanState`` whose policy carries the tuned plan and
     whose ``tuner_report`` records every candidate's score per site.
-
-    ``overlap=True`` (opt-in, the CLI's ``--overlap-collectives``)
-    additionally marks each chosen *quantized* pair-site spec
-    ``:overlap`` — the runtime then decomposes the two-phase ring into
-    ppermute rotations pipelined against the next microbatch's
-    dequant-GEMM (``dist/overlap.py``).  Bit-identical to the
-    synchronous epilogue, so the tuned scores carry over unchanged;
-    never applied to attn_vo sites (their epilogue closes through
-    GSPMD, not the explicit-collective path).
     """
     tp = state.tp
     if tp is None and mesh is not None:
@@ -241,40 +211,10 @@ def autotune_collectives(state, mesh=None, *,
             # nothing scored / nothing within budget -> the safe default
             chosen = (min(ok, key=lambda v: v["bytes_per_token"])["spec"]
                       if ok else default)
-            if kind == "pair":
-                # fuse the wire epilogue into the down GEMM where the
-                # Pallas kernel can serve it: same wire bytes + numerics
-                # (bit-identical payload), one less HBM round trip.
-                win = scores.get(chosen.shorthand())
-                if win is not None and win.get("fusable"):
-                    chosen = chosen.with_(fused=True)
-                    scores[chosen.shorthand()] = {**win, "spec": chosen}
-                if overlap and chosen.name in ("quant-int8", "quant-int4"):
-                    # same wire bytes + numerics, the ring just overlaps
-                    # the next microbatch's GEMM (see docstring)
-                    chosen = chosen.with_(overlap=True)
         entries.append((path, chosen))
-        # eligibility provenance: WHY this site may (or may not) carry a
-        # ':fused' wire epilogue, re-derivable offline from the shard on
-        # disk — repro.analysis.manifest_lint cross-checks the mark
-        # against this record and against kernels.dispatch.wire_support.
-        if tp == 1:
-            elig = {"fusable": False, "reason": status}
-        elif kind != "pair":
-            elig = {"fusable": False,
-                    "reason": "attn_vo epilogue closes through GSPMD"}
-        else:
-            base = scores.get(chosen.shorthand()) or scores.get(
-                chosen.with_(fused=False, overlap=False).shorthand())
-            elig = ({"fusable": base["fusable"],
-                     "reason": base["fuse_reason"]}
-                    if base is not None
-                    else {"fusable": False, "reason": status})
         report.append({
             "path": path, "kind": kind, "tp": tp, "budget": budget,
             "status": status, "chosen": chosen.shorthand(),
-            "fused": chosen.fused, "overlap": chosen.overlap,
-            "eligibility": elig,
             "candidates": {
                 short: {"rel_err": v["rel_err"],
                         "bytes_per_token": v["bytes_per_token"]}

@@ -7,6 +7,7 @@ the clean runs are the contract that the current tree actually holds
 the invariants.
 """
 
+import ast
 import json
 import os
 import shutil
@@ -112,6 +113,36 @@ def test_clean_tree_has_zero_ast_findings():
     assert ast_lint.run() == []
 
 
+def _imports(path):
+    """Every module ``path`` imports, at module level or deferred inside
+    a function: ``import a.b`` and ``from a.b import c`` give ``a.b``
+    and ``a.b.c``."""
+    with open(path) as f:
+        tree = ast.parse(f.read(), path)
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            yield from (a.name for a in node.names)
+        elif isinstance(node, ast.ImportFrom) and node.module:
+            yield node.module
+            yield from (f"{node.module}.{a.name}" for a in node.names)
+
+
+@pytest.mark.parametrize("layer,above", [
+    ("kernels", "comm"), ("kernels", "dist"),
+    ("comm", "dist"), ("comm", "kernels")])
+def test_layers_import_downward(layer, above):
+    """The kernels know nothing of collectives, and the collectives
+    nothing of the kernels or the mesh runtime: no module of a layer
+    imports one of ``above``'s, deferred imports included."""
+    root = os.path.join(REPO, "src", "repro", layer)
+    banned = f"repro.{above}"
+    found = [(name, mod)
+             for name in sorted(os.listdir(root)) if name.endswith(".py")
+             for mod in _imports(os.path.join(root, name))
+             if mod == banned or mod.startswith(banned + ".")]
+    assert found == []
+
+
 # ---------------------------------------------------------------------------
 # HLO rules (seeded dumps + compiled sweep)
 # ---------------------------------------------------------------------------
@@ -162,13 +193,6 @@ def test_hl001_byte_mismatch_fires():
     assert fs[0].detail["analytic"] == 1024.0
 
 
-def test_hl003_missing_overlap_fires():
-    fs = hlo_lint.lint_hlo_text(
-        "ENTRY %x () -> f32[2] {\n}\n",
-        expect_overlap_kinds=("collective-permute",))
-    assert _rules(fs) == {"HL003"}
-
-
 def test_hl004_donated_copy_fires():
     fs = hlo_lint.lint_hlo_text(HLO_DONATED)
     assert _rules(fs) == {"HL004"}
@@ -183,19 +207,16 @@ def test_hl004_donated_copy_fires():
 def test_site_sweep_measured_equals_analytic():
     """The acceptance sweep: at tp {2,4,8} the measured HLO collective
     bytes equal the analytic ``bytes_on_wire`` (rel < 1e-6) for psum /
-    psum_scatter / quant-int8 / quant-int4, overlap windows span a GEMM
-    for the ':overlap' variants, and no dtype rule fires.  Runs in a
-    subprocess: the host device count must be set before jax imports."""
+    psum_scatter / quant-int8 / quant-int4 and no dtype rule fires.  Runs
+    in a subprocess: the host device count must be set before jax
+    imports."""
     code = (
         "import os\n"
         "os.environ['XLA_FLAGS'] = "
-        "'--xla_force_host_platform_device_count=8 "
-        "--xla_cpu_enable_concurrency_optimized_scheduler=false'\n"
+        "'--xla_force_host_platform_device_count=8'\n"
         "from repro.analysis import hlo_lint\n"
         "fs = hlo_lint.run_site_sweep(tps=(2, 4, 8),"
         " specs=hlo_lint.SWEEP_SPECS)\n"
-        "fs += hlo_lint.run_site_sweep(tps=(2,),"
-        " specs=hlo_lint.SWEEP_OVERLAP_SPECS)\n"
         "assert not fs, [str(f) for f in fs]\n"
         "print('SWEEP-CLEAN')\n")
     env = dict(os.environ,
@@ -306,8 +327,7 @@ def test_contracts_clean_at_tp1():
 # manifest rules (seeded manifests + clean artifact)
 # ---------------------------------------------------------------------------
 
-def _plan_manifest(entries, default="psum", pairs=("layers.mlp",),
-                   tuner=None):
+def _plan_manifest(entries, default="psum", pairs=("layers.mlp",)):
     short = "per-layer:" + ",".join(
         f"{p}={s}" for p, s in entries) + f",*={default}"
     man = {
@@ -318,8 +338,6 @@ def _plan_manifest(entries, default="psum", pairs=("layers.mlp",),
         "collective_plan": {"entries": [list(e) for e in entries],
                             "default": default},
     }
-    if tuner is not None:
-        man["collective_tuner"] = tuner
     return man
 
 
@@ -335,36 +353,6 @@ def test_mf002_shadowed_glob_fires():
                           ("layers.mlp", "psum")])
     fs = manifest_lint.lint_manifest_dict(man)
     assert _rules(fs) == {"MF002"}
-
-
-def test_mf003_unprovenanced_fused_mark_fires():
-    man = _plan_manifest([("layers.mlp", "quant-int8:128:fused")])
-    fs = manifest_lint.lint_manifest_dict(man)
-    assert _rules(fs) == {"MF003"}
-    assert "no tuner record" in fs[0].message
-
-
-def test_mf003_contradicted_eligibility_fires():
-    tuner = [{"path": "layers.mlp", "kind": "pair", "tp": 2,
-              "status": "tuned", "chosen": "quant-int8:128:fused",
-              "fused": True, "overlap": False,
-              "eligibility": {"fusable": False,
-                              "reason": "K=24 is not a multiple of 256"}}]
-    man = _plan_manifest([("layers.mlp", "quant-int8:128:fused")],
-                         tuner=tuner)
-    fs = manifest_lint.lint_manifest_dict(man)
-    assert _rules(fs) == {"MF003"}
-    assert "not a multiple" in fs[0].message
-
-
-def test_mf003_recorded_eligibility_passes():
-    tuner = [{"path": "layers.mlp", "kind": "pair", "tp": 2,
-              "status": "tuned", "chosen": "quant-int8:128:fused",
-              "fused": True, "overlap": False,
-              "eligibility": {"fusable": True, "reason": ""}}]
-    man = _plan_manifest([("layers.mlp", "quant-int8:128:fused")],
-                         tuner=tuner)
-    assert manifest_lint.lint_manifest_dict(man) == []
 
 
 def test_mf006_shorthand_echo_mismatch_fires():
@@ -455,49 +443,6 @@ def test_mf004_missing_and_stray_rank_files_fire(artifact_dir, tmp_path):
     msgs = [f.message for f in fs if f.rule == "MF004"]
     assert any("missing rank shard" in m for m in msgs)
     assert any("stray rank shard" in m for m in msgs)
-
-
-def test_mf003_on_disk_rederivation_fires(tmp_path):
-    """A ':fused' mark whose rank-0 shard cannot take the wire epilogue
-    — forged provenance says fusable, but ``wire_support`` re-derived
-    from the pair on disk (a naive-actorder layout, which has no
-    wire-epilogue kernel) refuses."""
-    import jax
-    import jax.numpy as jnp
-
-    from repro.core import reorder
-    from repro.train import checkpoint
-
-    rng = jax.random.PRNGKey(0)
-    k1, n1, n2 = 16, 32, 16
-    w_up = jax.random.normal(rng, (k1, n1), jnp.float32) * 0.02
-    w_down = jax.random.normal(rng, (n1, n2), jnp.float32) * 0.02
-    pp = reorder.plan_pair(w_up, w_down, scheme="naive-actorder",
-                           group_size_up=8, group_size_down=8, rng=rng)
-    art = tmp_path / "plan"
-    art.mkdir()
-    tree = {"layers": {"mlp": pp}}
-    for r in (0, 1):
-        checkpoint.save(str(art / f"rank_{r:02d}"), tree)
-    forged = "quant-int4:12:fused"
-    man = {
-        "format_version": 1, "tp": 2, "arch_id": "qwen3-4b",
-        "policy": {"collective": f"per-layer:layers.mlp={forged},*=psum"},
-        "pairs": [{"path": "layers.mlp", "stacked": []}],
-        "leaf_shards": {k: None
-                        for k in checkpoint.flatten_keys(tree)},
-        "collective_plan": {"entries": [["layers.mlp", forged]],
-                            "default": "psum"},
-        "collective_tuner": [
-            {"path": "layers.mlp", "kind": "pair", "tp": 2,
-             "status": "tuned", "chosen": forged, "fused": True,
-             "overlap": False,
-             "eligibility": {"fusable": True, "reason": ""}}],
-    }
-    (art / "manifest.json").write_text(json.dumps(man))
-    fs = manifest_lint.lint_artifact(str(art))
-    assert any(f.rule == "MF003" and "on disk" in f.message
-               for f in fs), [str(f) for f in fs]
 
 
 def test_serve_verify_subcommand(artifact_dir, tmp_path):

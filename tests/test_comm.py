@@ -15,6 +15,7 @@ Multi-device cases run in a subprocess with
 host device count at first init).
 """
 
+import json
 import os
 import subprocess
 import sys
@@ -28,13 +29,14 @@ from repro.comm import CollectiveSpec, dispatch
 _ROOT = os.path.join(os.path.dirname(__file__), "..")
 
 
-def _run(script: str, devices: int = 8):
+def _run(script: str, *args: str, devices: int = 8):
     env = dict(os.environ)
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={devices}")
     env["PYTHONPATH"] = os.path.join(_ROOT, "src")
-    p = subprocess.run([sys.executable, "-c", textwrap.dedent(script)],
-                       capture_output=True, text=True, env=env, timeout=560)
+    p = subprocess.run(
+        [sys.executable, "-c", textwrap.dedent(script), *args],
+        capture_output=True, text=True, env=env, timeout=560)
     assert p.returncode == 0, f"STDOUT:\n{p.stdout}\nSTDERR:\n{p.stderr}"
     return p.stdout
 
@@ -288,6 +290,90 @@ def test_quant_int8_non_tiling_padded_ring_and_pair_forward():
     assert out.count("OK") == 6
 
 
+def _epilogue_tolerance(short: str, tp: int) -> float:
+    """Relative error allowed a gated tp-aware pair closed by ``short``:
+    ``test_collectives_vs_lax_primitives_under_shard_map``'s bounds at
+    this TP degree — one wire rounding per rank (cast), or one
+    quantization per rank plus the re-quantized reduction (quant-int8/4).
+    The full-precision strategies differ from the single-device
+    reference only in f32 accumulation order."""
+    spec = CollectiveSpec.parse(short)
+    if spec.name == "cast":
+        return tp * float(jnp.finfo(spec.wire_dtype).eps)
+    if spec.name == "quant-int8":
+        return (tp + 1) * 2.0 ** (1 - spec.bits)
+    if spec.name == "quant-int4":
+        return (tp + 1) * 2.0 / 15.0
+    return 1e-5
+
+
+EPILOGUE_STRATEGIES = ("psum", "psum_scatter", "cast", "quant-int8",
+                       "quant-int4")
+
+
+EPILOGUE_SCRIPT = r"""
+import json, re, sys
+import jax, numpy as np
+from repro.core import reorder, schemes
+from repro.core.policy import ExecutionPolicy
+from repro.launch.mesh import make_mesh
+
+r = jax.random.split(jax.random.PRNGKey(0), 4)
+k1, n1, n2, m = 128, 256, 128, 16
+pp = reorder.plan_pair(
+    jax.random.normal(r[0], (k1, n1)), jax.random.normal(r[2], (n1, n2)),
+    w_gate=jax.random.normal(r[1], (k1, n1)), scheme="tp-aware",
+    group_size_up=32, group_size_down=32, rng=r[0])
+x = jax.random.normal(r[3], (m, k1))
+ref = np.asarray(schemes.pair_forward_reference(x, pp, activation="silu"))
+collective = re.compile(r"\s(all-reduce|all-gather|all-to-all"
+                        r"|reduce-scatter|collective-permute)"
+                        r"(-start|-done)?\(")
+op_name = re.compile(r'op_name="([^"]*)"')
+report = {}
+for tp in (2, 4):
+    mesh = make_mesh((tp,), ("model",))
+    for short in json.loads(sys.argv[1]):
+        pol = ExecutionPolicy(collective=short)
+        fn = jax.jit(lambda xx, p, pol=pol, mesh=mesh:
+                     schemes.pair_forward_tp(xx, p, mesh, pol,
+                                             activation="silu",
+                                             pair_path="layers.mlp"))
+        hlo = fn.lower(x, pp).compile().as_text()
+        scopes = [hit[1] if (hit := op_name.search(line)) else ""
+                  for line in hlo.splitlines() if collective.search(line)]
+        y = np.asarray(fn(x, pp), np.float32)
+        report[f"{short}@{tp}"] = {
+            "collectives": len(scopes),
+            "outside": [s for s in scopes if "/epilogue/" not in s],
+            "err": float(np.abs(y - ref).max() / np.abs(ref).max())}
+print("REPORT " + json.dumps(report))
+"""
+
+
+@pytest.fixture(scope="module")
+def epilogue_report():
+    """Per (strategy, tp): the compiled ``pair_forward_tp`` program's
+    collectives and their ``op_name`` scopes, and the output's error
+    against ``pair_forward_reference`` — one 8-device subprocess."""
+    out = _run(EPILOGUE_SCRIPT, json.dumps(EPILOGUE_STRATEGIES))
+    line, = [x for x in out.splitlines() if x.startswith("REPORT ")]
+    return json.loads(line[len("REPORT "):])
+
+
+@pytest.mark.parametrize("tp", [2, 4])
+@pytest.mark.parametrize("short", EPILOGUE_STRATEGIES)
+def test_every_strategy_closes_the_pair_inside_the_epilogue_scope(
+        epilogue_report, short, tp):
+    """Every collective of a TP pair program is its epilogue's — the
+    invariant ``epilogue_ms_per_step`` reads — and the pair still closes
+    to the single-device result within the strategy's wire tolerance."""
+    case = epilogue_report[f"{short}@{tp}"]
+    assert case["collectives"] > 0
+    assert case["outside"] == []
+    assert case["err"] < _epilogue_tolerance(short, tp), case["err"]
+
+
 def test_quant_int4_packs_like_the_weights():
     """The int4 collective's wire payload reuses the weight quantizer's
     nibble packing (``pack_int4``): pack->unpack along the last dim is the
@@ -469,6 +555,26 @@ def test_collective_plan_rejects_malformed_shorthand():
         CollectivePlan.parse("per-layer:justaname")
     with pytest.raises(ValueError, match="registered strategies"):
         CollectivePlan.parse("per-layer:*.mlp=warp-speed,*=psum")
+
+
+@pytest.mark.parametrize("value,token", [
+    ("quant-int8:128:fused", "fused"),
+    ("quant-int4:32:fused", "fused"),
+    ("quant-int8:128:overlap", "overlap"),
+    ("quant-int4:32:overlap", "overlap"),
+    ("quant-int8:fused", "fused"),
+    ("quant-int4:overlap", "overlap"),
+    ("quant-int4:32:fused:overlap", "fused"),
+    ("per-layer:*.mlp=quant-int8:128:fused,*=psum", "fused"),
+])
+def test_retired_and_malformed_quant_suffixes_are_refused(value, token):
+    """A quant shorthand takes one optional integer block and nothing
+    else: configs, CLI strings and manifests naming a token the program
+    does not know fail loudly instead of serving something else."""
+    from repro.comm import parse_collective
+
+    with pytest.raises(ValueError, match=f"'{token}'"):
+        parse_collective(value)
 
 
 def test_policy_accepts_plan_and_spec():

@@ -437,6 +437,31 @@ def test_artifact_roundtrip_heterogeneous_collective_plan(tmp_path):
             collective="per-layer:*.mlp=quant-int8:128,*=psum"))
 
 
+@pytest.mark.parametrize("mark", ["fused", "overlap"])
+def test_artifact_with_a_retired_epilogue_mark_is_refused(tmp_path, mark):
+    """A manifest whose collective plan carries a ':fused' or ':overlap'
+    mark (epilogue modes the program no longer has) is refused where the
+    artifact is served, naming the mark, instead of serving a plain
+    epilogue under the old name."""
+    from repro.launch import serve
+
+    cfg = _smoke_cfg().with_quant(
+        collective="per-layer:*.mlp=quant-int8:128,*=psum")
+    art_dir = tmp_path / "marked"
+    _prepare(cfg, tp=1).save(str(art_dir))
+    manifest = art_dir / "manifest.json"
+    text = manifest.read_text()
+    assert text.count("quant-int8:128") >= 2    # the policy and the echo
+    manifest.write_text(text.replace("quant-int8:128",
+                                     f"quant-int8:128:{mark}"))
+
+    with pytest.raises(ValueError, match=f"'{mark}'"):
+        DeploymentArtifact.load(str(art_dir)).policy()
+    with pytest.raises(ValueError, match=f"'{mark}'"):
+        serve.main(["--artifact", str(art_dir), "--requests", "1",
+                    "--max-new", "1"])
+
+
 def test_autotune_compiles_collective_plan(tmp_path):
     """``prepare(autotune=True)`` scores every full-output strategy per
     pair site and freezes a per-layer ``CollectivePlan`` into the
