@@ -59,26 +59,48 @@ class QuantizedLinear:
       * ``"ordered"`` — rows permuted by ``P = argsort(g_idx)`` (Algorithm 1);
         groups are contiguous: row ``i`` belongs to group ``i // group_size``;
         ``g_idx`` is None.  The caller must feed ``X[:, P]``.
+
+    ``layer`` (traced int32 scalar, or None): set, the arrays keep the
+    leading ``L`` dim of a layer stack and the linear is their layer
+    ``layer``, which the layer scan (``models/common.scan_layers``) hands
+    over in place of a slice.  A kernel that can address the stack reads
+    it there; every other reader takes ``sliced()``.
     """
 
-    qweight: jax.Array                  # (K // 8, N) uint32 packed int4
-    scales: jax.Array                   # (G, N)
-    zeros: jax.Array                    # (G, N)  (float zero-points)
-    g_idx: Optional[jax.Array]          # (K,) int32 — only for kind="naive"
+    qweight: jax.Array                  # ([L,] K // 8, N) uint32 packed int4
+    scales: jax.Array                   # ([L,] G, N)
+    zeros: jax.Array                    # ([L,] G, N)  (float zero-points)
+    g_idx: Optional[jax.Array]          # ([L,] K) int32 — only kind="naive"
     group_size: int = dataclasses.field(metadata=dict(static=True))
     kind: str = dataclasses.field(metadata=dict(static=True))
+    layer: Optional[jax.Array] = None   # () int32: the layer of the stack
 
     @property
     def k(self) -> int:
-        return self.qweight.shape[0] * PACK
+        return self.qweight.shape[-2] * PACK
 
     @property
     def n(self) -> int:
-        return self.qweight.shape[1]
+        return self.qweight.shape[-1]
 
     @property
     def num_groups(self) -> int:
-        return self.scales.shape[0]
+        return self.scales.shape[-2]
+
+    def sliced(self) -> "QuantizedLinear":
+        """The 2-D linear: itself, or layer ``layer`` of its stack read
+        with ``lax.dynamic_index_in_dim`` (XLA fuses that read into a
+        consumer it compiles; a custom call gets a copy)."""
+        if self.layer is None:
+            return self
+
+        def take(a):
+            return (None if a is None else jax.lax.dynamic_index_in_dim(
+                a, self.layer, 0, keepdims=False))
+
+        return dataclasses.replace(
+            self, qweight=take(self.qweight), scales=take(self.scales),
+            zeros=take(self.zeros), g_idx=take(self.g_idx), layer=None)
 
 
 # ---------------------------------------------------------------------------
@@ -279,6 +301,7 @@ def quantize(
 
 def dequantize(ql: QuantizedLinear, dtype=jnp.float32) -> jax.Array:
     """Materialize the fp weight ``(K, N)`` in the linear's own row layout."""
+    ql = ql.sliced()
     q = unpack_int4(ql.qweight).astype(jnp.float32)
     if ql.kind == "ordered":
         g_idx = jnp.arange(ql.k, dtype=jnp.int32) // ql.group_size

@@ -22,6 +22,7 @@ happens here.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Callable, Optional
 
@@ -110,26 +111,29 @@ def pair_forward_reference(
 # ---------------------------------------------------------------------------
 
 def pair_pspecs(pp: PlannedPair, axis: str, x_batch_axes=()) -> PlannedPair:
-    """PartitionSpec pytree matching ``pp`` for the model-TP axis ``axis``."""
-    col = P(None, axis)
+    """PartitionSpec pytree matching ``pp`` for the model-TP axis ``axis``.
+    A linear read in place (``layer`` set) keeps its stack dim whole and
+    its layer index replicated."""
+
+    def specs(ql: QuantizedLinear, **arrays) -> QuantizedLinear:
+        lead = () if ql.layer is None else (None,)
+        return dataclasses.replace(
+            ql, layer=None if ql.layer is None else P(),
+            **{f: None if s is None else P(*lead, *s)
+               for f, s in arrays.items()})
 
     def col_specs(ql: QuantizedLinear) -> QuantizedLinear:
-        import dataclasses
-        return dataclasses.replace(
-            ql, qweight=col, scales=col, zeros=col,
-            g_idx=(P(None) if ql.g_idx is not None else None))
+        col = (None, axis)
+        return specs(ql, qweight=col, scales=col, zeros=col,
+                     g_idx=(None,) if ql.g_idx is not None else None)
 
     def row_specs(ql: QuantizedLinear) -> QuantizedLinear:
-        import dataclasses
         if ql.kind == "naive":
-            return dataclasses.replace(
-                ql, qweight=P(axis, None), scales=P(None, None),
-                zeros=P(None, None), g_idx=P(axis))
-        return dataclasses.replace(
-            ql, qweight=P(axis, None), scales=P(axis, None),
-            zeros=P(axis, None), g_idx=None)
+            return specs(ql, qweight=(axis, None), scales=(None, None),
+                         zeros=(None, None), g_idx=(axis,))
+        return specs(ql, qweight=(axis, None), scales=(axis, None),
+                     zeros=(axis, None), g_idx=None)
 
-    import dataclasses
     return dataclasses.replace(
         pp,
         up=col_specs(pp.up),

@@ -21,6 +21,15 @@ Packing: 8 int4 nibbles per uint32 along K (``quantization.pack_int4``); a
 unpacked with VPU shifts/masks and fed to the MXU in the compute dtype with
 f32 accumulation.
 
+A model's layers hold their packed weights, scales and zeros stacked as
+``(L, K/8, N)`` and ``(L, G, N)``.  ``dequant_matmul_ordered`` reads
+layer ``l`` of those stacks in place: ``l`` is a scalar-prefetch operand,
+and the weight-side blocks have a squeezed leading dim whose index map
+returns it.  Handed a slice instead, XLA would copy it first: a custom
+call's operand cannot be a view into a larger buffer (a dot's slice XLA
+fuses into the dot), so each layer's 26-44 MB of packed weights would be
+written out once and read again by the kernel.
+
 Every block obeys the TPU tiling rule (last two block dims divisible by
 the dtype's sublane count and 128, or equal to the array's), which
 ``check_tiling`` states once for the entry points and the dispatcher.
@@ -136,10 +145,13 @@ def _expand_groups(meta_ref, group_size: int):
 # ordered-groups kernel
 # ---------------------------------------------------------------------------
 
-def _dequant_matmul_ordered_kernel(x_ref, qw_ref, s_ref, z_ref, o_ref,
-                                   acc_ref, *, group_size: int, bk: int,
-                                   compute_dtype):
-    """Grid (M/bm, N/bn, K/bk); K innermost so acc_ref carries the sum."""
+def _dequant_matmul_ordered_kernel(layer_ref, x_ref, qw_ref, s_ref, z_ref,
+                                   o_ref, acc_ref, *, group_size: int,
+                                   bk: int, compute_dtype):
+    """Grid (M/bm, N/bn, K/bk); K innermost so acc_ref carries the sum.
+    ``layer_ref`` (scalar prefetch) only steers the weight-side index
+    maps."""
+    del layer_ref
     kk = pl.program_id(2)
 
     @pl.when(kk == 0)
@@ -169,11 +181,12 @@ def _dequant_matmul_ordered_kernel(x_ref, qw_ref, s_ref, z_ref, o_ref,
 
 def dequant_matmul_ordered(
     x: jax.Array,           # (M, K)
-    qweight: jax.Array,     # (K//8, N) uint32
-    scales: jax.Array,      # (G, N)
-    zeros: jax.Array,       # (G, N)
+    qweight: jax.Array,     # ([L,] K//8, N) uint32
+    scales: jax.Array,      # ([L,] G, N)
+    zeros: jax.Array,       # ([L,] G, N)
     *,
     group_size: int,
+    layer: jax.Array | int | None = None,
     block_m: int = 128,
     block_n: int = 128,
     block_k: int | None = None,
@@ -181,32 +194,44 @@ def dequant_matmul_ordered(
     out_dtype=None,
     interpret: bool,
 ) -> jax.Array:
+    """``x @ dequantize(weight)``.  Stacked ``(L, ...)`` weight arrays are
+    read in place at ``layer``: the index is a scalar-prefetch operand
+    that the weight-side index maps return as their squeezed leading
+    block index, so the kernel's operands are the stacks themselves."""
+    if qweight.ndim == 2:   # one matrix: a stack of one
+        qweight, scales, zeros = qweight[None], scales[None], zeros[None]
+        layer = 0
     m, k = x.shape
-    n = qweight.shape[1]
+    n = qweight.shape[-1]
     bk = block_k or pick_block_k(k, group_size)
     bm = min(block_m, m)
     bn = min(block_n, n)
     check_tiling(m, k, n, group_size, bm, bn, bk, x.dtype)
     out_dtype = out_dtype or compute_dtype
 
-    grid = (m // bm, n // bn, k // bk)
     kernel = functools.partial(
         _dequant_matmul_ordered_kernel, group_size=group_size, bk=bk,
         compute_dtype=compute_dtype)
+    gk = bk // group_size
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(m // bm, n // bn, k // bk),
+        in_specs=[
+            pl.BlockSpec((bm, bk), lambda i, j, kk, l: (i, kk)),
+            pl.BlockSpec((None, bk // PACK, bn),
+                         lambda i, j, kk, l: (l[0], kk, j)),
+            pl.BlockSpec((None, gk, bn), lambda i, j, kk, l: (l[0], kk, j)),
+            pl.BlockSpec((None, gk, bn), lambda i, j, kk, l: (l[0], kk, j)),
+        ],
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk, l: (i, j)),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+    )
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bk // PACK, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((bk // group_size, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((bk // group_size, bn), lambda i, j, kk: (kk, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-    )(x, qweight, scales, zeros)
+    )(jnp.asarray(layer, jnp.int32).reshape(1), x, qweight, scales, zeros)
 
 
 # ---------------------------------------------------------------------------

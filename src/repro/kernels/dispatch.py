@@ -8,7 +8,8 @@ the ``@register`` decorator and immediately become valid policy values —
 no stringly-typed branching at the call sites.
 
 Kernel contract: ``fn(x, ql, policy) -> y`` with ``x: (..., K)``,
-``ql: QuantizedLinear`` (whose static ``kind`` selected the entry), and
+``ql: QuantizedLinear`` (whose static ``kind`` selected the entry; with
+``ql.layer`` set, one layer of a stack), and
 ``policy: ExecutionPolicy`` supplying dtypes and tiling.  Returns
 ``(..., N)`` in ``policy.compute_dtype``.
 
@@ -40,20 +41,31 @@ from repro.core.quantization import PACK, QuantizedLinear
 KernelFn = Callable[[jax.Array, QuantizedLinear, ExecutionPolicy], jax.Array]
 
 _REGISTRY: dict[tuple[str, str], KernelFn] = {}
+#: the entries whose kernel reads a stacked linear's layer in place
+_IN_PLACE: set[tuple[str, str]] = set()
 
 KINDS = ("ordered", "naive")
 
 
-def register(kind: str, backend: str):
-    """Decorator: register ``fn(x, ql, policy)`` for a (kind, backend)."""
+def register(kind: str, backend: str, *, in_place: bool = False):
+    """Decorator: register ``fn(x, ql, policy)`` for a (kind, backend).
+    ``in_place``: the kernel reads a stacked ``ql`` (``ql.layer`` set)
+    from the stack itself; the others take ``ql.sliced()``."""
     if kind not in KINDS:
         raise ValueError(f"unknown layout kind {kind!r}, expected {KINDS}")
 
     def deco(fn: KernelFn) -> KernelFn:
         _REGISTRY[(kind, backend)] = fn
+        if in_place:
+            _IN_PLACE.add((kind, backend))
         return fn
 
     return deco
+
+
+def reads_in_place(kind: str, backend: str) -> bool:
+    """Does the (kind, backend) kernel read a stacked linear in place?"""
+    return (kind, backend) in _IN_PLACE
 
 
 def backends(kind: Optional[str] = None) -> tuple[str, ...]:
@@ -131,7 +143,7 @@ def _jnp_dequant_matmul(x, ql, policy):
     return jnp.matmul(x.astype(policy.compute_dtype), w)
 
 
-@register("ordered", "pallas")
+@register("ordered", "pallas", in_place=True)
 def _pallas_ordered(x, ql, policy):
     from repro.kernels import ops
 

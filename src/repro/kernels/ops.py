@@ -4,6 +4,8 @@ Handles the impedance between model code and kernel constraints:
 * arbitrary leading batch dims (flattened to M),
 * M/N padding to tile multiples (zero-padded, sliced off),
 * dispatch on ``QuantizedLinear.kind`` (ordered vs g_idx gather),
+* a stacked linear's ``layer``: read in place by the ordered kernel,
+  sliced out for the others,
 * interpret mode, resolved by ``core.policy.interpret_mode``: compiled
   Mosaic on a TPU, the Pallas interpreter elsewhere.
 """
@@ -62,18 +64,21 @@ def dequant_matmul(
     bm = min(block_m, max(dk.sublanes(compute_dtype), m))
     x2 = _pad_to(x2, bm, 0)
     bn = min(block_n, n)
+    if ql.kind != "ordered":
+        ql = ql.sliced()    # the g_idx kernel takes one matrix
     qweight, scales, zeros = ql.qweight, ql.scales, ql.zeros
     if n % bn:
-        qweight = _pad_to(qweight, bn, 1)
-        scales = _pad_to(scales, bn, 1)
-        zeros = _pad_to(zeros, bn, 1)
+        qweight = _pad_to(qweight, bn, -1)
+        scales = _pad_to(scales, bn, -1)
+        zeros = _pad_to(zeros, bn, -1)
 
     bk_kw = {} if block_k is None else {"block_k": block_k}
     if ql.kind == "ordered":
+        # a stacked linear is read in place at its layer
         y = dk.dequant_matmul_ordered(
             x2, qweight, scales, zeros, group_size=ql.group_size,
-            block_m=bm, block_n=bn, compute_dtype=compute_dtype,
-            interpret=interpret, **bk_kw)
+            layer=ql.layer, block_m=bm, block_n=bn,
+            compute_dtype=compute_dtype, interpret=interpret, **bk_kw)
     else:
         y = dk.dequant_matmul_gidx(
             x2, qweight, scales, zeros, ql.g_idx,
@@ -113,6 +118,7 @@ def dequantize(ql: QuantizedLinear, *, out_dtype=jnp.float32,
                interpret: bool | None = None) -> jax.Array:
     """Materialize the fp weight with the standalone dequant kernel."""
     interpret = interpret_mode(interpret)
+    ql = ql.sliced()
     if ql.kind != "ordered":
         # unordered materialization has no locality to exploit; use ref path
         from repro.kernels import ref

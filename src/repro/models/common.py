@@ -24,6 +24,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs.base import ModelConfig
 from repro.core import schemes
 from repro.core.policy import DEFAULT_POLICY, ExecutionPolicy
+from repro.core.quantization import QuantizedLinear
 from repro.core.reorder import PlannedPair
 
 
@@ -739,19 +740,61 @@ def lm_head(cfg, p, x, ctx: ParallelContext):
 # layer scan helper
 # ---------------------------------------------------------------------------
 
+def held_in_place(leaf) -> bool:
+    """A quantized linear stacked on one leading layer dim (of at least
+    one layer).  The layer scan hands each step the whole stack with the
+    step's index (``QuantizedLinear.layer``) instead of a slice: a custom
+    call's operand cannot be a view into a larger buffer, so XLA would
+    copy a slice of the stack before every Pallas dequant-GEMM read it."""
+    return (isinstance(leaf, QuantizedLinear) and leaf.qweight.ndim == 3
+            and leaf.qweight.shape[0] > 0)
+
+
+def _layer_reader(stacked):
+    """``(n, scanned, layer)``: the stack's depth, the leaves the scan
+    slices (None where a linear is held in place), and ``layer(i, xs)``,
+    which rebuilds layer ``i``'s params from the sliced leaves ``xs`` and
+    the held linears, each with ``layer=i``."""
+    leaves, treedef = jax.tree.flatten(stacked, is_leaf=held_in_place)
+    held = [held_in_place(a) for a in leaves]
+    scanned = [None if h else a for a, h in zip(leaves, held)]
+    first = leaves[0]
+    n = (first.qweight if held[0] else first).shape[0]
+
+    def layer(i, xs):
+        return treedef.unflatten([
+            dataclasses.replace(a, layer=i) if h else x
+            for a, h, x in zip(leaves, held, xs)])
+
+    return n, scanned, layer
+
+
+def weights_in_place(params, policy: ExecutionPolicy) -> int:
+    """Quantized weight matrices per layer that the layer scan holds in
+    place and whose kernel (``policy.backend``) reads them there: 3 for a
+    gated int4 MLP on the Pallas backend, 0 where the backend slices."""
+    from repro.kernels import dispatch
+
+    return sum(
+        held_in_place(a) and dispatch.reads_in_place(a.kind, policy.backend)
+        for a in jax.tree.leaves(params, is_leaf=held_in_place))
+
+
 def scan_layers(body, x, stacked_params, ctx: ParallelContext, extra=None):
     """Scan ``body(x, layer_params, extra) -> x`` over stacked layers."""
     fn = body
     if ctx.remat:
         fn = jax.checkpoint(body)
+    n, scanned, layer = _layer_reader(stacked_params)
 
-    def step(carry, lp):
+    def step(carry, xs):
+        i, lp = xs
         # params may be f32 while activations are bf16; keep the carry dtype
         # stable so lax.scan typechecks (mixed-precision policy: activations
         # stay in the model compute dtype between layers).
-        return fn(carry, lp, extra).astype(carry.dtype), None
+        return fn(carry, layer(i, lp), extra).astype(carry.dtype), None
 
-    y, _ = jax.lax.scan(step, x, stacked_params)
+    y, _ = jax.lax.scan(step, x, (jnp.arange(n), scanned))
     return y
 
 
@@ -761,13 +804,15 @@ def scan_layers_cache(body, x, stacked_params, stacked_cache, ctx, extra=None):
     fn = body
     if ctx.remat:
         fn = jax.checkpoint(body)
+    n, scanned, layer = _layer_reader(stacked_params)
 
     def step(carry, xs):
-        lp, lc = xs
-        y, nc = fn(carry, lp, lc, extra)
+        i, lp, lc = xs
+        y, nc = fn(carry, layer(i, lp), lc, extra)
         return y.astype(carry.dtype), nc
 
-    y, new_cache = jax.lax.scan(step, x, (stacked_params, stacked_cache))
+    y, new_cache = jax.lax.scan(step, x,
+                                (jnp.arange(n), scanned, stacked_cache))
     return y, new_cache
 
 

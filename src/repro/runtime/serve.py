@@ -22,7 +22,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.policy import ExecutionPolicy
-from repro.models.common import ParallelContext, REPLICATED
+from repro.models.common import ParallelContext, REPLICATED, weights_in_place
 from repro.models.registry import Model, build_model
 from repro.runtime import sampling
 
@@ -86,10 +86,12 @@ class Engine:
                 f"policy={self.policy} but ctx.policy={self.ctx.policy}; "
                 "pass one (the ctx policy is what model code executes)")
         aux = self.aux
-        # once, here: what the served weights hold, in each dtype, and the
-        # model-axis size they are split over
+        # once, here: what the served weights hold, in each dtype, the
+        # model-axis size they are split over, and the quantized matrices
+        # per layer the layer scan's kernels read in place
         self.param_bytes = param_bytes(self.params)
         self.tp = self.ctx.axis_size(self.ctx.model_axis)
+        self.weights_in_place = weights_in_place(self.params, self.policy)
 
         def prefill_logits(params, batch):
             return mod.forward(params, batch, self.ctx, window=self.window,

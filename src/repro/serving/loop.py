@@ -340,6 +340,7 @@ class EngineLoop:
                 "lanes_drawn": sched.lanes_drawn,
                 "param_bytes": sched.engine.param_bytes,
                 "tp": sched.engine.tp,
+                "weights_in_place": sched.engine.weights_in_place,
             },
             "requests": {
                 "admitted": self.admitted,

@@ -38,6 +38,58 @@ def test_ordered_kernel_sweep(m, k, n, gs, bk):
                                rtol=1e-5, atol=1e-4)
 
 
+def _stack(seed, k, n, gs, layers=3, kind="ordered"):
+    """``layers`` quantized (k, n) linears stacked along a leading dim."""
+    qls = [getattr(_mk(seed + i, k, n, gs), kind) for i in range(layers)]
+    return jax.tree.map(lambda *a: jnp.stack(a), *qls)
+
+
+@pytest.mark.parametrize("m", [8, 64], ids=["decode", "prefill"])
+@pytest.mark.parametrize("k,n,gs", [(256, 256, 128), (800, 128, 100)],
+                         ids=["gs128", "gs100-whole-k"])
+def test_ordered_kernel_reads_a_stacked_layer_in_place(m, k, n, gs):
+    """Addressed at layer ``l`` of a stack, the kernel gives bitwise what
+    it gives on ``stack[l]``: the index steers the weight-side blocks and
+    nothing else."""
+    st = _stack(m + k, k, n, gs)
+    x = jax.random.normal(jax.random.PRNGKey(13), (m, k))
+    at = jax.jit(lambda layer: dk.dequant_matmul_ordered(
+        x, st.qweight, st.scales, st.zeros, group_size=gs, layer=layer,
+        interpret=True))
+    for layer in range(3):
+        want = dk.dequant_matmul_ordered(
+            x, st.qweight[layer], st.scales[layer], st.zeros[layer],
+            group_size=gs, interpret=True)
+        np.testing.assert_array_equal(np.asarray(at(jnp.int32(layer))),
+                                      np.asarray(want))
+
+
+@pytest.mark.parametrize("kind", ["ordered", "naive"])
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+def test_stacked_linear_at_a_layer_equals_its_slice(backend, kind):
+    """A ``QuantizedLinear`` with ``layer`` set computes bitwise what its
+    slice computes, on every backend; only the ordered Pallas kernel reads
+    the stack in place, the others slice it."""
+    import dataclasses
+
+    from repro.core.policy import ExecutionPolicy
+    from repro.kernels import dispatch
+
+    st = _stack(7, 256, 128, 64, kind=kind)
+    x = jax.random.normal(jax.random.PRNGKey(14), (2, 4, 256))
+    policy = ExecutionPolicy(backend=backend)
+    at = jax.jit(lambda layer: dispatch.qmatmul(
+        x, dataclasses.replace(st, layer=layer), policy))
+    for layer in range(3):
+        sliced = jax.tree.map(lambda a: a[layer], st)
+        assert dataclasses.replace(st, layer=layer).k == sliced.k == 256
+        np.testing.assert_array_equal(
+            np.asarray(at(jnp.int32(layer))),
+            np.asarray(dispatch.qmatmul(x, sliced, policy)))
+    assert dispatch.reads_in_place(kind, backend) == (
+        (kind, backend) == ("ordered", "pallas"))
+
+
 @pytest.mark.parametrize("m,k,n,gs", [
     (8, 128, 128, 32),
     (16, 256, 384, 64),

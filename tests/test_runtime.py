@@ -500,3 +500,43 @@ def test_engine_loop_reports_param_bytes_per_dtype():
     assert got["bfloat16"] == want
     assert sum(got.values()) == sum(
         a.nbytes for a in jax.tree_util.tree_leaves(eng.params))
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+def test_layer_scan_reads_quantized_weights_in_place_as_it_sliced_them(
+        backend, monkeypatch):
+    """The layer scan hands each layer its quantized MLP linears as the
+    whole stacks with the layer's index; decode and prefill give bitwise
+    the logits and cache of a scan that slices every leaf."""
+    import dataclasses
+
+    from repro.core.policy import ExecutionPolicy
+    from repro.models import common as cm
+    from repro.serving import EngineLoop
+
+    cfg = get_smoke_config("granite-3-8b")
+    policy = dataclasses.replace(ExecutionPolicy.from_config(cfg),
+                                 backend=backend)
+    eng = make_engine(cfg, jax.random.PRNGKey(0), max_seq=16, policy=policy)
+    assert eng.weights_in_place == (3 if backend == "pallas" else 0)
+    loop = EngineLoop(Scheduler(eng, max_batch=2, prompt_budget=8))
+    assert loop.stats()["engine"]["weights_in_place"] == eng.weights_in_place
+    model, ctx = eng.model, eng.ctx
+    cache = model.init_cache(2, 16)
+    tokens = jnp.asarray([3, 5], jnp.int32)
+    pos = jnp.asarray([0, 4], jnp.int32)
+    batch = {"tokens": jnp.arange(16, dtype=jnp.int32).reshape(2, 8)}
+
+    def run():
+        # a fresh trace each time: jit caches by function, not by the
+        # module state the scan reads
+        decode = jax.jit(lambda p, c: model.decode_step(p, c, tokens, pos,
+                                                        ctx))
+        prefill = jax.jit(lambda p: model.forward(p, batch, ctx))
+        return decode(eng.params, cache), prefill(eng.params)
+
+    in_place = run()
+    monkeypatch.setattr(cm, "held_in_place", lambda leaf: False)
+    sliced = run()
+    for a, b in zip(jax.tree.leaves(in_place), jax.tree.leaves(sliced)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -94,6 +94,34 @@ out["cache_heads"] = [s.is_equivalent_to(heads, 5) for s in shardings]
 out["decode_compiles"] = tp4_compiles
 out["decode_compiles_after"] = compiles.count("jit(decode)") - tp4_compiles - 1
 
+# the MLP weights read in place from their per-chip stacks, against a scan
+# that slices every leaf: bitwise the same logits and cache, on the
+# engine's backend (jnp on the CPU) and on the interpreted Pallas kernel
+import dataclasses
+
+import repro.models.common as cm
+
+pallas = dataclasses.replace(eng.ctx, policy=dataclasses.replace(
+    eng.policy, backend="pallas"))
+out["weights_in_place"] = [
+    EngineLoop(sched4).stats()["engine"]["weights_in_place"],
+    cm.weights_in_place(eng.params, pallas.policy)]
+
+
+def step_once(ctx):
+    fn = jax.jit(lambda p, c: eng.model.decode_step(p, c, lanes + 1, lanes,
+                                                    ctx))
+    return jax.tree.leaves(fn(eng.params, cache))
+
+
+in_place = [step_once(eng.ctx), step_once(pallas)]
+held, cm.held_in_place = cm.held_in_place, lambda leaf: False
+sliced = [step_once(eng.ctx), step_once(pallas)]
+cm.held_in_place = held
+out["in_place_equal"] = [
+    all(np.array_equal(a, b) for a, b in zip(x, y))
+    for x, y in zip(in_place, sliced)]
+
 # the scheduler's sampler on the mesh's logits: tokens and keys come out
 # replicated, from the program the served steps compiled
 n = compiles.count("jit(sample_step)")
@@ -177,6 +205,13 @@ def test_tp4_cache_keeps_its_kv_head_sharding_across_decode_calls(report):
     # call returns: one decode program
     assert report["decode_compiles"] == 1
     assert report["decode_compiles_after"] == 0
+
+
+def test_tp4_decode_reads_mlp_weights_in_place_as_it_sliced_them(report):
+    # gate, up and down of each layer, read from their per-chip stacks by
+    # the Pallas kernel; the jnp backend the CPU resolves to slices them
+    assert report["weights_in_place"] == [0, 3]
+    assert report["in_place_equal"] == [True, True]
 
 
 def test_tp4_cache_of_a_length_the_axis_does_not_divide_replicates_it(

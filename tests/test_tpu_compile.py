@@ -74,6 +74,24 @@ def test_ordered_gemm_compiles_at_qwen3_4b_widths(one_chip, m, k, n, gs):
     assert "tpu_custom_call" in hlo
 
 
+def _assert_weights_read_in_place(text: str, num_layers: int) -> None:
+    """Every Pallas dequant-GEMM of a compiled decode step takes its packed
+    weights as the whole ``(L, K/8, N)`` stack, and no instruction yields
+    a 2-D ``u32`` value: no layer's packed weights are sliced out (and so
+    copied) for a kernel."""
+    import re
+
+    packed = [re.findall(r"u32\[([\d,]+)\]", c) for c in re.findall(
+        r'custom_call_target="tpu_custom_call", '
+        r"operand_layout_constraints=\{(.*?\})\}", text)]
+    assert len(packed) >= 3
+    for shapes in packed:
+        dims, = shapes
+        assert dims.split(",")[0] == str(num_layers) \
+            and dims.count(",") == 2, shapes
+    assert not re.findall(r"= u32\[\d+,\d+\]", text)
+
+
 def test_qwen3_4b_decode_step_compiles_for_one_chip(one_chip):
     """The full-width decode step the server runs (batch 8, max_seq 1024,
     int4 tp-aware MLPs on the Pallas backend, compiled) fits one chip."""
@@ -100,8 +118,10 @@ def test_qwen3_4b_decode_step_compiles_for_one_chip(one_chip):
 
     compiled = jax.jit(decode, donate_argnums=1).lower(
         params, cache, tokens, pos).compile()
+    text = compiled.as_text()
     # gate, up and down of the scanned layer body
-    assert compiled.as_text().count("tpu_custom_call") >= 3
+    assert text.count("tpu_custom_call") >= 3
+    _assert_weights_read_in_place(text, cfg.num_layers)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         < V5E_HBM_BYTES
@@ -156,6 +176,7 @@ def test_mistral_large_stage_decode_step_compiles_for_four_chips(topo):
                        r"|collective-permute)(?:-start)?\(", comp)
     assert found == ["all-reduce", "all-reduce"], found
     assert comp.count("tpu_custom_call") >= 3
+    _assert_weights_read_in_place(text, cfg.num_layers)
 
 
 # the served vocabularies: granite-3-8b's on one chip, Mistral-Large's
