@@ -8,7 +8,11 @@ One run of one cell:
 metrics.  Everything that belongs to one of them is a file of its own,
 found by name:
 
-* ``configs/<config>.json``  model sizes as run, with source and cuts;
+* ``configs/<config>.json``  model sizes as run, with source and cuts,
+  and the ``family`` that builds, references and counts the model;
+* ``families/<family>.py``   for one family of models: the program's
+  config, the seeded weights and parameter tree, the plain reference
+  layer and the counts of its work (``families/dense.py`` lists them);
 * ``traffic/<mix>.json``     parameters of the one traffic generator;
 * ``cells/<cell>.json``      batch slots, cache length and the limits of
   the correctness comparison;

@@ -1,8 +1,9 @@
 """The yardstick's arithmetic: peaks, operations and bytes from shapes,
 and statistics over all samples of a window.
 
-Nothing here imports the program: a change to the program cannot change
-how its work is counted.
+Nothing here imports the program, nor do the families' counts this takes
+the model's shape from: a change to the program cannot change how its
+work is counted.
 """
 
 from __future__ import annotations
@@ -10,6 +11,8 @@ from __future__ import annotations
 import json
 import math
 import os
+
+from perfbench import spec
 
 PEAKS_FILE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                           "peaks.json")
@@ -77,13 +80,10 @@ def roofline_seconds(flops: float, nbytes: float, peak: dict) -> float:
 
 
 def mlp_gemm_shapes(conf: dict, tp: int = 1) -> list[tuple[int, int, int]]:
-    """``(k, n, group_size)`` of the gate, up and down GEMMs one layer
-    runs on one chip of a ``tp``-way model axis."""
-    d, ff = conf["hidden_size"], conf["intermediate_size"]
-    q = conf["quantization"]
-    return [(d, ff // tp, q["group_size_up"]),
-            (d, ff // tp, q["group_size_up"]),
-            (ff // tp, d, q["group_size_down"])]
+    """``(k, n, group_size)`` of the dense-MLP GEMMs one layer runs on one
+    chip of a ``tp``-way model axis, as the configuration's family counts
+    them."""
+    return spec.family(conf).mlp_gemm_shapes(conf, tp)
 
 
 # ---------------------------------------------------------------------------
@@ -91,30 +91,34 @@ def mlp_gemm_shapes(conf: dict, tp: int = 1) -> list[tuple[int, int, int]]:
 # ---------------------------------------------------------------------------
 
 def matmul_params(conf: dict) -> int:
-    """Weights one token multiplies through: attention projections, the
-    gated MLP and the LM head, over every layer."""
-    d, ff = conf["hidden_size"], conf["intermediate_size"]
-    hd = conf["head_dim"]
-    q = conf["num_attention_heads"] * hd
-    kv = conf["num_key_value_heads"] * hd
-    per_layer = d * q + 2 * d * kv + q * d + 3 * d * ff
-    return conf["num_hidden_layers"] * per_layer + d * conf["vocab_size"]
+    """Weights one token multiplies through, over every layer and the LM
+    head (with experts, the active ones), as the configuration's family
+    counts them."""
+    return spec.family(conf).matmul_params(conf)
+
+
+def _keys(first: int, count: int, window) -> int:
+    """Keys attended by ``count`` consecutive tokens from 0-based position
+    ``first``: ``p + 1`` at position ``p``, at most ``window``."""
+    lo, hi = first + 1, first + count
+    cap = hi if window is None else min(hi, window)
+    ramp = (cap * (cap + 1) - (lo - 1) * lo) // 2 if cap >= lo else 0
+    flat = 0 if window is None else window * max(0, hi - max(lo - 1, window))
+    return ramp + flat
 
 
 def token_flops(conf: dict, position: int) -> int:
     """Model FLOPs of one token at 0-based ``position``: every weight
-    once (multiply and add) and attention over the ``position + 1`` keys
-    before it (scores and the weighted sum)."""
-    attn = (4 * conf["num_hidden_layers"] * conf["num_attention_heads"]
-            * conf["head_dim"] * (position + 1))
-    return 2 * matmul_params(conf) + attn
+    once (multiply and add) and attention over the keys before it
+    (scores and the weighted sum)."""
+    return span_flops(conf, position, 1)
 
 
 def span_flops(conf: dict, first: int, count: int) -> int:
     """FLOPs of ``count`` consecutive tokens from position ``first``."""
     if count <= 0:
         return 0
-    pos_sum = count * first + count * (count - 1) // 2
-    attn = (4 * conf["num_hidden_layers"] * conf["num_attention_heads"]
-            * conf["head_dim"] * (pos_sum + count))
+    attn = sum(4 * layers * heads * head_dim * _keys(first, count, window)
+               for layers, heads, head_dim, window
+               in spec.family(conf).attention_layers(conf))
     return 2 * matmul_params(conf) * count + attn

@@ -1,12 +1,14 @@
 """The system under test, built for one cell.
 
-Weights come from ``weights`` on the device, one layer per call of one
-jitted program: the raw layer is made from the seed and goes through the
-program's own plan compiler (``plan.compiler.compile_params``: int4
-round-to-nearest in act-order groups, then the tp-aware layout), and is
-written in place into the stacked buffers.  On a mesh the buffers carry
-the shardings of the model's own ``param_specs``.  Nothing is read from
-disk and no ``serve prepare`` runs.
+The configuration's family (``families/<family>.py``) names the program's
+config and lays out its parameter tree; the weights are built on the
+device, one layer per call of one jitted program (``fill``): the raw
+layer is made from the seed and goes through the program's own plan
+compiler (``plan.compiler.compile_params``: int4 round-to-nearest in
+act-order groups, then the tp-aware layout), and is written in place
+into its stacked buffers.  On a mesh the buffers carry the shardings of
+the model's own ``param_specs``.  Nothing is read from disk and no
+``serve prepare`` runs.
 """
 
 from __future__ import annotations
@@ -14,79 +16,92 @@ from __future__ import annotations
 import functools
 
 import jax
+import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from perfbench import weights
+from perfbench import spec
 
 
 def model_config(conf: dict):
     """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import ModelConfig, QuantConfig
-
-    q = conf["quantization"]
-    return ModelConfig(
-        arch_id=conf["name"], family="dense", source=conf["source"],
-        num_layers=conf["num_hidden_layers"], d_model=conf["hidden_size"],
-        n_heads=conf["num_attention_heads"],
-        n_kv_heads=conf["num_key_value_heads"], head_dim=conf["head_dim"],
-        d_ff=conf["intermediate_size"], vocab_size=conf["vocab_size"],
-        qk_norm=conf["qk_norm"], rope_theta=float(conf["rope_theta"]),
-        norm_eps=conf["rms_norm_eps"], activation=conf["hidden_act"],
-        mlp_gated=True, dtype=q["activation_dtype"],
-        quant=QuantConfig(mode="mlp", scheme=q["scheme"],
-                          group_size=q["group_size"],
-                          act_order=q["act_order"], tp_groups=q["tp_groups"],
-                          compute_dtype=q["compute_dtype"],
-                          collective=q["collective"]))
+    return spec.family(conf).model_config(conf)
 
 
-def _planned_layer(cfg, conf, key, index):
-    """Layer ``index`` as the program serves it (leading stack dim 1)."""
+def abstract_params(cfg, conf: dict, key) -> dict:
+    """Shapes and dtypes of the planned parameter tree."""
+    return spec.family(conf).abstract_params(cfg, conf, key)
+
+
+def build_params(cfg, conf: dict, key, ctx):
+    """The full planned parameter tree on the device(s)."""
+    return spec.family(conf).fill(cfg, conf, key, ctx)
+
+
+def plan_layer(cfg, raw: dict, key, index):
+    """A raw layer as the program serves it (leading stack dim 1)."""
     from repro.plan import compiler
 
-    raw = jax.tree.map(lambda a: a[None], weights.layer(conf, key, index))
+    raw = jax.tree.map(lambda a: a[None], raw)
     plan_rng = jax.random.fold_in(jax.random.fold_in(key, 2), index)
     return compiler.compile_params(cfg, {"layers": raw},
                                    rng=plan_rng)["layers"]
 
 
-def build_params(cfg, conf: dict, key, ctx):
-    """The full planned parameter tree on the device(s)."""
+def stacked(one, n: int):
+    """The shapes of ``n`` layers shaped as ``one`` (leading dim 1)."""
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct((n,) + a.shape[1:], a.dtype), one)
+
+
+def fill(cfg, conf: dict, key, ctx, abstract: dict, stacks: dict) -> dict:
+    """``abstract`` built on the device(s): the family's ``top`` in one
+    jitted call, and each stack ``path -> (planned(key, index), indices)``
+    as zeros into which one jitted call per layer writes layer
+    ``indices[r]`` at row ``r``.  Every key of ``abstract`` but the stacks
+    is ``top``'s."""
     from repro.models.registry import build_model
 
-    n = conf["num_hidden_layers"]
-    one = jax.eval_shape(functools.partial(_planned_layer, cfg, conf), key, 0)
-    stacked = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct((n,) + a.shape[1:], a.dtype), one)
-    top_shape = jax.eval_shape(functools.partial(weights.top, conf), key)
-    abstract = dict(top_shape, layers=stacked)
-    if ctx.mesh is None:
-        shard = None
-        top_shard = layer_shard = None
-    else:
+    shard = None
+    if ctx.mesh is not None:
         specs = build_model(cfg).param_specs(abstract, ctx)
         shard = jax.tree.map(lambda s: NamedSharding(ctx.mesh, s), specs,
                              is_leaf=lambda x: isinstance(x, P))
-        layer_shard = shard["layers"]
-        top_shard = {k: v for k, v in shard.items() if k != "layers"}
 
-    top = jax.jit(functools.partial(weights.top, conf),
-                  out_shardings=top_shard)(key)
+    def part(keys):
+        return None if shard is None else {k: shard[k] for k in keys}
+
+    top = jax.jit(functools.partial(spec.family(conf).top, conf),
+                  out_shardings=part(k for k in abstract if k not in stacks)
+                  )(key)
+    out = dict(top)
+    for path, (planned, indices) in stacks.items():
+        out[path] = _fill_stack(planned, abstract[path], list(indices), key,
+                                None if shard is None else shard[path])
+    return out
+
+
+def _fill_stack(planned, shapes, indices: list, key, shard):
     zeros = jax.jit(lambda: jax.tree.map(
-        lambda a: jax.numpy.zeros(a.shape, a.dtype), stacked),
-        out_shardings=layer_shard)
+        lambda a: jax.numpy.zeros(a.shape, a.dtype), shapes),
+        out_shardings=shard)
     layers = zeros()
+    # the row of each layer index, where a stack does not hold 0, 1, ...
+    rows = None
+    if indices != list(range(len(indices))):
+        rows = np.zeros(max(indices) + 1, np.int32)
+        rows[indices] = np.arange(len(indices))
 
-    @functools.partial(jax.jit, donate_argnums=0, out_shardings=layer_shard)
+    @functools.partial(jax.jit, donate_argnums=0, out_shardings=shard)
     def fill(buf, key, index):
-        part = _planned_layer(cfg, conf, key, index)
+        part = planned(key, index)
+        row = index if rows is None else jax.numpy.asarray(rows)[index]
         return jax.tree.map(
-            lambda b, p: jax.lax.dynamic_update_slice_in_dim(b, p, index, 0),
+            lambda b, p: jax.lax.dynamic_update_slice_in_dim(b, p, row, 0),
             buf, part)
 
-    for i in range(n):
+    for i in indices:
         layers = fill(layers, key, i)
-    return dict(top, layers=layers)
+    return layers
 
 
 def make_loop(cell, params, cfg, ctx, *, seed: int):

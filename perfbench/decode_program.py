@@ -18,7 +18,6 @@ nothing.
 
 from __future__ import annotations
 
-import functools
 import json
 import re
 from bisect import bisect_right
@@ -124,16 +123,9 @@ def compile_hlo(cell):
     from repro.models.registry import build_model
     from repro.runtime.serve import Engine
 
-    conf = cell.conf
-    cfg = build.model_config(conf)
+    cfg = build.model_config(cell.conf)
     model = build_model(cfg)
-    key = weights.jax_key(0)
-    one = jax.eval_shape(functools.partial(build._planned_layer, cfg, conf),
-                         key, 0)
-    layers = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-        (conf["num_hidden_layers"],) + a.shape[1:], a.dtype), one)
-    top = jax.eval_shape(functools.partial(weights.top, conf), key)
-    params = dict(top, layers=layers)
+    params = build.abstract_params(cfg, cell.conf, weights.jax_key(0))
     ctx = ParallelContext()
     if cell.chips > 1:
         mesh = make_mesh((1, cell.chips), ("data", "model"),
@@ -172,6 +164,21 @@ def decode_hlo(cell):
     if key not in _HLO:
         _HLO[key] = compile_hlo(cell)
     return _HLO[key]
+
+
+def scoped_ms_per_step(run, scope: str):
+    """Device time on the first chip of the ops in ``scope`` inside the
+    decode programs wholly in the traced window, per program; None where
+    the program has no op in the scope or is not the one that ran."""
+    found = ops_of_the_program(run)
+    if found is None:
+        return None
+    ran, programs, hlo = found
+    names = scoped(hlo, scope)
+    if not names:
+        return None
+    ns = sum(e.dur_ns for e in ran if trace.op_name(e.name) in names)
+    return ns / 1e6 / programs
 
 
 def ops_of_the_program(run):

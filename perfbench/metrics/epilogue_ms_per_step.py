@@ -7,18 +7,10 @@ of the cell's decode program, compiled again for its mesh
 (``perfbench.decode_program``); a program without the scope reads
 nothing."""
 
-from perfbench import decode_program, trace
+from perfbench import decode_program
 
 SCOPE = "epilogue"
 
 
 def read(run):
-    found = decode_program.ops_of_the_program(run)
-    if found is None:
-        return None
-    ran, programs, hlo = found
-    names = decode_program.scoped(hlo, SCOPE)
-    if not names:
-        return None
-    ns = sum(e.dur_ns for e in ran if trace.op_name(e.name) in names)
-    return ns / 1e6 / programs
+    return decode_program.scoped_ms_per_step(run, SCOPE)

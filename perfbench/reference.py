@@ -1,12 +1,13 @@
-"""Plain float32 forward of the dense decoder, and its lower-precision
+"""Plain float32 forward of a configuration, and its lower-precision
 control.
 
-Straight ``jax.numpy`` in float32 on the host CPU: pre-norm GQA attention
-with RoPE (and Qwen3's per-head q/k norm) and a SwiGLU MLP, over whole
-sequences with a causal mask, one layer at a time so a full-width model
-fits beside its activations.  No kernel, no cache, no slots, and nothing
-of the program: the weights come from ``weights`` and the MLP weights are
-the int4 grid values themselves.
+Straight ``jax.numpy`` in float32 on the host CPU, over whole sequences
+with a causal mask, one layer at a time so a full-width model fits beside
+its activations: each layer is its family's ``reference_layer`` (the
+dense family's: pre-norm GQA attention with RoPE, Qwen3's per-head q/k
+norm, a SwiGLU MLP), built from the helpers here.  No kernel, no cache,
+no slots, and nothing of the program: the weights come from the family's
+generators and the MLP weights are the int4 grid values themselves.
 
 The control computes the same forward with both operands of every matmul
 rounded to float8 e4m3's precision: the step below the configurations'
@@ -21,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from perfbench import weights
+from perfbench import spec
 
 HIGHEST = jax.lax.Precision.HIGHEST
 FP8_MANTISSA = 3
@@ -64,33 +65,6 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _layer(conf, low, x, w):
-    b, s, _ = x.shape
-    eps, hd = conf["rms_norm_eps"], conf["head_dim"]
-    nh, nkv = conf["num_attention_heads"], conf["num_key_value_heads"]
-    a = w["attn"]
-    h = _rms(x, w["ln1"]["scale"], eps)
-    q = _mm("bsd,dn->bsn", h, a["wq"], low).reshape(b, s, nh, hd)
-    k = _mm("bsd,dn->bsn", h, a["wk"], low).reshape(b, s, nkv, hd)
-    v = _mm("bsd,dn->bsn", h, a["wv"], low).reshape(b, s, nkv, hd)
-    if conf["qk_norm"]:
-        q = _rms(q, a["q_norm"], eps)
-        k = _rms(k, a["k_norm"], eps)
-    q, k = _rope(q, conf["rope_theta"]), _rope(k, conf["rope_theta"])
-    k = jnp.repeat(k, nh // nkv, axis=2)
-    v = jnp.repeat(v, nh // nkv, axis=2)
-    scores = _mm("bshd,bthd->bhst", q, k, low) / hd ** 0.5
-    causal = jnp.tril(jnp.ones((s, s), bool))
-    p = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
-    o = _mm("bhst,bthd->bshd", p, v, low).reshape(b, s, nh * hd)
-    x = x + _mm("bsn,nd->bsd", o, a["wo"], low)
-    m = w["mlp"]
-    h = _rms(x, w["ln2"]["scale"], eps)
-    g = _mm("bsd,df->bsf", h, m["w_gate"], low)
-    u = _mm("bsd,df->bsf", h, m["w_up"], low)
-    return x + _mm("bsf,fd->bsd", jax.nn.silu(g) * u, m["w_down"], low)
-
-
 class Reference:
     """The plain forward for one configuration and weight seed.
 
@@ -103,10 +77,16 @@ class Reference:
         self.conf = conf
         self.key = key
         self.host = jax.devices("cpu")[0]
-        self._layer_w = jax.jit(functools.partial(weights.layer, conf))
-        self._top = jax.jit(functools.partial(weights.top, conf))
-        self._layer = {low: jax.jit(functools.partial(_layer, conf, low))
-                       for low in (False, True)}
+        fam = spec.family(conf)
+        self.kinds = fam.layer_kinds(conf)
+        # one program per kind of layer, made or computed
+        self._layer_w = {k: jax.jit(functools.partial(fam.layer, conf,
+                                                      kind=k))
+                         for k in set(self.kinds)}
+        self._top = jax.jit(functools.partial(fam.top, conf))
+        self._layer = {(k, low): jax.jit(functools.partial(
+            fam.reference_layer, conf, k, low))
+            for k in set(self.kinds) for low in (False, True)}
         self._head = {c: jax.jit(functools.partial(self._head_rows, conf, c))
                       for c in (False, True)}
 
@@ -144,14 +124,15 @@ class Reference:
         x = jnp.take(top["embed"]["embedding"],
                      jax.device_put(tokens, self.host), axis=0)
         xs = {False: x, True: x} if control else {False: x}
-        for li in range(self.conf["num_hidden_layers"]):
+        for li, kind in enumerate(self.kinds):
             # the CPU runs a layer asynchronously: fetch the next layer's
             # weights meanwhile, but wait for the layer before dispatching
             # another, or every layer's weights (0.8 GB each at 8B width)
             # would pile up on the host ahead of the arithmetic
-            w = self._on_host(self._layer_w(self.key, li))
+            w = self._on_host(self._layer_w[kind](self.key, li))
             jax.block_until_ready(xs)
-            xs = {low: self._layer[low](h, w) for low, h in xs.items()}
+            xs = {low: self._layer[kind, low](h, w)
+                  for low, h in xs.items()}
             del w
         # rows predicting each served token: positions plen-1 .. len-2
         idx, served, owner = [], [], []

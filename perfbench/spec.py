@@ -3,12 +3,16 @@
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import os
+import re
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
+#: what a name in ``BENCHMARK.json`` and a file under it may be called
+NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}")
 
 
 @dataclasses.dataclass
@@ -55,11 +59,36 @@ def load(workload: str, root: str = ROOT) -> Cell:
                 end_to_end=e2e, per_layer=per)
 
 
+def _module(package: str, name: str, path: str):
+    mod_spec = importlib.util.spec_from_file_location(
+        f"perfbench.{package}." + name.replace(".", "_"), path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return mod
+
+
 def reader(metric: str, root: str = ROOT):
     """``read(run)`` of ``metrics/<metric>.py``."""
     path = os.path.join(root, "perfbench", "metrics", metric + ".py")
-    mod_spec = importlib.util.spec_from_file_location(
-        "perfbench.metrics." + metric.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod.read
+    return _module("metrics", metric, path).read
+
+
+def family(conf: dict, root: str = ROOT):
+    """The module ``families/<family>.py`` of the family a configuration
+    states: its program config, layer kinds, seeded weights, parameter
+    tree, plain reference layer and counts (``families/dense.py`` holds
+    the list).  A family is loaded once per file."""
+    here = os.path.join(root, "perfbench", "families")
+    name = conf.get("family")
+    path = os.path.join(here, f"{name}.py")
+    if not (isinstance(name, str) and NAME.fullmatch(name)
+            and os.path.isfile(path)):
+        known = sorted(f[:-3] for f in os.listdir(here) if f.endswith(".py"))
+        raise KeyError(f"configuration {conf.get('name')!r} states family "
+                       f"{name!r}; known: {known}")
+    return _family(path, name)
+
+
+@functools.lru_cache(maxsize=None)
+def _family(path: str, name: str):
+    return _module("families", name, path)

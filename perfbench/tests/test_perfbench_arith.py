@@ -13,7 +13,7 @@ HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 #: Qwen3-4B's widths (Qwen/Qwen3-4B config.json), a second shape check
-QWEN3_4B = {"hidden_size": 2560, "intermediate_size": 9728,
+QWEN3_4B = {"family": "dense", "hidden_size": 2560, "intermediate_size": 9728,
             "num_hidden_layers": 36, "num_attention_heads": 32,
             "num_key_value_heads": 8, "head_dim": 128, "vocab_size": 151936}
 
@@ -94,6 +94,16 @@ def test_model_flops_per_token(name, params):
     assert arith.span_flops(conf, 7, 50) == sum(
         arith.token_flops(conf, p) for p in range(7, 57))
     assert arith.span_flops(conf, 7, 0) == 0
+
+
+@pytest.mark.parametrize("window", [None, 1, 7, 128])
+def test_keys_attended_over_a_span_are_those_of_each_position(window):
+    # a token at 0-based position p attends p + 1 keys, at most a window's
+    for first in range(0, 140, 3):
+        for count in (0, 1, 2, 9, 130):
+            assert arith._keys(first, count, window) == sum(
+                p + 1 if window is None else min(p + 1, window)
+                for p in range(first, first + count))
 
 
 @pytest.mark.parametrize("q", [0, 5, 50, 95, 99, 100])

@@ -63,10 +63,11 @@ def test_served_mlp_weights_dequantize_to_the_generators_values(name):
         w[rows[:, None], cols[None, :]] = np.asarray(dequantize(got))
         np.testing.assert_allclose(w, np.asarray(want), rtol=1e-6,
                                    atol=1e-9)
-    # attention and norms go through untouched
+    # the plan compiler stores the attention projections in bfloat16
     np.testing.assert_array_equal(
         np.asarray(params["layers"]["attn"]["wq"][1]),
-        np.asarray(weights.layer(conf, key, 1)["attn"]["wq"]))
+        np.asarray(weights.layer(conf, key, 1)["attn"]["wq"].astype(
+            jnp.bfloat16)))
 
 
 def test_int4_grid_has_sixteen_levels_and_both_ends_in_every_group():

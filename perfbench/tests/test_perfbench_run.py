@@ -79,8 +79,10 @@ def test_open_loop_answers_every_request_due_in_the_window():
 def test_the_control_fails_the_limit_the_program_passes():
     # twelve layers and answers of 48-96 tokens: the control's float8
     # error, like the program's bfloat16 error, grows with depth, and its
-    # widest gap with the positions compared
-    cell = smoke_cell("granite-3-8b.decode")
+    # widest gap with the positions compared.  An open loop waits for
+    # every answer due in its window, so however slowly the CPU serves,
+    # every run compares the same greedy answers
+    cell = smoke_cell("granite-3-8b.decode", open_loop=True)
     cell = dataclasses.replace(
         cell, conf=dict(cell.conf, num_hidden_layers=12), max_seq=160,
         traffic=dict(cell.traffic, output_len={"dist": "uniform",
@@ -88,9 +90,12 @@ def test_the_control_fails_the_limit_the_program_passes():
     result, run = bench_run.execute(cell, SEED, 4.0, False,
                                     t_start=time.monotonic(), allow_cpu=True,
                                     control=True)
+    greedy = [r for r in run.records if r.req.greedy]
+    assert len(greedy) == 3 and all(r.complete for r in greedy)
     assert not result["correct"]
     gap = result["checks"]["max_logit_gap"]
-    assert gap["tokens"] > 0 and gap["value"] > gap["limit"]
+    assert gap["tokens"] == sum(r.req.max_new for r in greedy)
+    assert gap["value"] > gap["limit"]
     assert gap["value"] == run.reading["control_gap"]
     program = bench_run.checks(run.cell, run.reading)
     assert bench_run.passed(program), program

@@ -12,6 +12,7 @@ import jax
 import numpy as np
 import pytest
 
+from perfbench import decode_program
 from perfbench import run as bench_run
 from perfbench import spec, trace
 from perfbench.metrics import attention_ms_per_step as attention
@@ -142,16 +143,17 @@ def test_the_attention_reader_compiles_the_program_that_ran(traced_loop):
     lanes = jax.numpy.zeros((sched.max_batch,), jax.numpy.int32)
     ran = sched.engine._decode.lower(sched.engine.params, sched._cache,
                                      lanes, lanes).compile().as_text()
-    hlo = attention.decode_hlo(smoke_cell("granite-3-8b.decode"))
+    hlo = decode_program.compile_hlo(smoke_cell("granite-3-8b.decode"))
 
     def instructions(text):
         # source locations differ with the caller; the program may not
         return [re.sub(r", metadata=\{.*\}$", "", m[0])
-                for m in attention.INSTRUCTION.finditer(text)]
+                for m in decode_program.INSTRUCTION.finditer(text)]
 
     assert instructions(hlo) == instructions(ran)
-    assert attention.scoped(hlo) == attention.scoped(ran)
-    assert attention.scoped(hlo)
+    assert decode_program.scoped(hlo, attention.SCOPE) == \
+        decode_program.scoped(ran, attention.SCOPE)
+    assert decode_program.scoped(hlo, attention.SCOPE)
 
 
 def test_a_traced_run_keeps_counters_and_the_scoped_decode_program(
@@ -170,7 +172,8 @@ def test_a_traced_run_keeps_counters_and_the_scoped_decode_program(
     _, run = bench_run.execute(cell, SEED, 4.0, True,
                                t_start=time.monotonic(), allow_cpu=True)
     # the attention ops of the compiled decode program carry the scope
-    assert attention.scoped(attention.decode_hlo(cell))
+    assert decode_program.scoped(decode_program.decode_hlo(cell),
+                                 attention.SCOPE)
     first, last = reads[0], reads[-1]
     assert (first["steps"], last["steps"]) == run.steps
     fed = (last["lanes_replay"] - first["lanes_replay"]
@@ -269,12 +272,13 @@ def test_sample_idle_counts_gaps_in_the_sample_phase():
 
 def test_attention_counts_scoped_ops_inside_whole_decode_programs(
         monkeypatch):
-    monkeypatch.setattr(attention, "decode_hlo", lambda cell: HLO)
+    monkeypatch.setattr(decode_program, "decode_hlo", lambda cell: HLO)
     # 6 ms and 4 ms of attention in the two decode programs wholly in the
     # window; the while loop, the unscoped ops, the scoped op of another
     # program and the program cut by the window's end count nothing
     assert attention.read(_hand_run()) == pytest.approx((6 + 4) / 2)
-    assert attention.scoped(HLO) == {"%fusion.1", "%fusion.3", "%dot.7"}
+    assert decode_program.scoped(HLO, attention.SCOPE) == \
+        {"%fusion.1", "%fusion.3", "%dot.7"}
 
 
 @pytest.mark.parametrize("hlo", [
@@ -284,7 +288,7 @@ def test_attention_counts_scoped_ops_inside_whole_decode_programs(
 ])
 def test_attention_reads_nothing_without_the_scoped_program(monkeypatch,
                                                            hlo):
-    monkeypatch.setattr(attention, "decode_hlo", lambda cell: hlo)
+    monkeypatch.setattr(decode_program, "decode_hlo", lambda cell: hlo)
     assert attention.read(_hand_run()) is None
 
 

@@ -12,7 +12,7 @@ import types
 
 import pytest
 
-from perfbench import decode_program, spec, trace
+from perfbench import arith, decode_program, spec, trace
 from perfbench.trace import Event
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -20,6 +20,10 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 CELL = "mistral-large-123b.tp4.decode"
 NEW_METRICS = {"collective_ms_per_step", "collectives_per_step",
                "epilogue_ms_per_step"}
+#: per-layer metrics the cell shares with the one-chip cell
+SHARED_METRICS = {"decode_step_ms", "step_mfu", "dequant_gemm_roofline",
+                  "idle_share", "host_idle_ms_per_step",
+                  "sample_idle_ms_per_step", "attention_ms_per_step"}
 MS = 1e6
 
 
@@ -31,10 +35,11 @@ def test_the_cell_loads_with_four_chips_the_decode_mix_and_its_metrics():
     assert cell.conf["name"] == "mistral-large-123b"
     assert cell.conf["num_hidden_layers"] == 22
     assert (cell.max_batch, cell.max_seq) == (8, 512)
-    assert {m["name"] for m in cell.end_to_end} == {"itl_p95_ms", "setup_s"}
-    assert {m["name"] for m in cell.per_layer} == NEW_METRICS
+    assert {m["name"] for m in cell.end_to_end} == {"tokens_per_s",
+                                                    "itl_p95_ms", "setup_s"}
+    assert {m["name"] for m in cell.per_layer} == NEW_METRICS | SHARED_METRICS
     for m in cell.per_layer:
-        assert m["moves"] == "itl_p95_ms" and m["workloads"] == [CELL]
+        assert m["moves"] == "itl_p95_ms" and CELL in m["workloads"]
 
 
 def test_the_configuration_keeps_every_published_width():
@@ -161,6 +166,95 @@ def test_collective_readers_read_nothing_without_the_program(hand_hlo,
         run = _run(hlo=None)
     for name in NEW_METRICS:
         assert spec.reader(name)(run) is None, name
+
+
+def test_attention_reads_the_scope_of_the_mesh_program(hand_hlo):
+    # the all-reduce under the attention scope, 1 ms per program on chip 0
+    assert spec.reader("attention_ms_per_step")(_run()) == \
+        pytest.approx(1.0)
+
+
+# ----------------------------------------------------------------------
+# the step, kernel, device and host readers on a hand-made trace of the
+# cell's four chips
+# ----------------------------------------------------------------------
+
+def _host(name, start, dur):
+    return Event("/host:CPU", "engine-loop", name, start * MS, dur * MS)
+
+
+def _four_chip_trace():
+    """A 100 ms window with two decode programs on every chip, at 10 and
+    50 ms, each 30 ms long.  In each, two layers of gate, up and down
+    dequant-GEMMs back to back, 2 + 2 + 3 ms, but chip ``d``'s last down
+    takes 3 + d: chip ``d`` is busy 14 + d ms a program.  The host's
+    spans leave chip 0 idle in working phases 0-10 (admit 5-9, decode
+    9-10), 40-50 (sample 40-45, update 45-49, decode 49-50) and 80-85
+    (prepare); not in readback 24-40 and 64-80 nor in wait 85-100."""
+    events = [Event("/host:CPU", "python3", trace.WINDOW, 0.0, 100 * MS),
+              _host("serve.admit", 5, 4), _host("serve.decode", 9, 1),
+              _host("serve.readback", 24, 16), _host("serve.sample", 40, 5),
+              _host("serve.update", 45, 4), _host("serve.decode", 49, 1),
+              _host("serve.readback", 64, 16), _host("serve.prepare", 80, 5),
+              _host("serve.wait", 85, 15)]
+    for dev in range(4):
+        for p0 in (10, 50):
+            events.append(_program(dev, p0, 30))
+            t = p0
+            for layer in range(2):
+                for name, dur in (("%dequant_matmul.3", 2),
+                                  ("%dequant_matmul.4", 2),
+                                  ("%dequant_matmul.5",
+                                   3 + dev * layer)):
+                    events.append(_op(dev, name, t, dur))
+                    t += dur
+    return events
+
+
+def _four_chip_run():
+    cell = spec.load(CELL)
+    return types.SimpleNamespace(
+        trace=trace.summarize(_four_chip_trace()), cell=cell, chips=4,
+        device_kind="TPU v5 lite", trace_steps=(10, 14),
+        lanes=lambda lo, hi: [(None, 100, 4), (None, 7, 4)])
+
+
+def test_step_and_device_readers_on_four_chips():
+    run = _four_chip_run()
+    assert spec.reader("decode_step_ms")(run) == pytest.approx(30.0)
+    # busy 2 * (14 + d) ms on chip d: 31 ms of 100 on average
+    assert spec.reader("idle_share")(run) == pytest.approx(69.0)
+
+
+def test_the_gemm_roofline_sums_every_chips_calls_at_per_chip_shapes():
+    run = _four_chip_run()
+    peak = arith.peaks("TPU v5 lite")
+    # one chip's share of a layer at tp=4: gate and up 12288 x 7168, down
+    # 7168 x 12288, groups of 128, 8 rows
+    per_layer = sum(arith.roofline_seconds(
+        arith.dequant_gemm_flops(8, k, n),
+        arith.dequant_gemm_bytes(8, k, n, 128), peak)
+        for k, n in ((12288, 7168), (12288, 7168), (7168, 12288)))
+    # 48 calls (16 layers' worth) over all four chips, 2 * (14 + d) ms of
+    # kernel time on chip d
+    device_s = sum(2 * (14 + d) for d in range(4)) / 1e3
+    assert spec.reader("dequant_gemm_roofline")(run) == \
+        pytest.approx(100 * 16 * per_layer / device_s)
+
+
+def test_step_mfu_counts_the_stage_over_four_chips_peak():
+    run = _four_chip_run()
+    flops = arith.span_flops(run.cell.conf, 100, 4) + \
+        arith.span_flops(run.cell.conf, 7, 4)
+    assert spec.reader("step_mfu")(run) == \
+        pytest.approx(100 * flops / 0.1 / (4 * 197e12))
+
+
+def test_host_readers_read_chip_0s_idle_in_working_phases():
+    run = _four_chip_run()
+    # 4 + 1 + 5 + 4 + 1 + 5 ms over the two decode spans
+    assert spec.reader("host_idle_ms_per_step")(run) == pytest.approx(10.0)
+    assert spec.reader("sample_idle_ms_per_step")(run) == pytest.approx(2.5)
 
 
 # ----------------------------------------------------------------------

@@ -4,6 +4,7 @@ trace and on a few steps recorded from a v5e chip."""
 
 import json
 import os
+import types
 
 import pytest
 
@@ -95,3 +96,29 @@ def test_recorded_chip_trace_reduces_to_decode_steps_and_kernels():
     idle = s.window_s - s.busy_s()
     assert 0.5 * idle < sum(t for _, t in b["idle_gaps"]) <= idle + 1e-9
     assert not any(n.startswith("%while") for n, _ in b["device_ops"])
+
+
+def _fixture_hlo(events):
+    """An HLO text with a line for every op of the recorded trace; every
+    third, by name, in the ``attention`` scope."""
+    names = sorted({trace.op_name(e.name) for e in events
+                    if e.line == trace.OPS_LINE})
+    body = "\n".join(
+        f'  {n} = f32[8]{{0}} fusion(%x), metadata={{op_name="jit(decode)/'
+        f'while/body/closed_call/{"attention/" if i % 3 == 0 else ""}op"}}'
+        for i, n in enumerate(names))
+    return "HloModule jit_decode\nENTRY %main.1 () -> f32[8] {\n" + body \
+        + "\n}\n"
+
+
+def test_attention_reads_the_recorded_granite_trace_as_before(monkeypatch):
+    from perfbench import decode_program
+    from perfbench.metrics import attention_ms_per_step as attention
+
+    events = _fixture()
+    hlo = _fixture_hlo(events)
+    monkeypatch.setattr(decode_program, "decode_hlo", lambda cell: hlo)
+    run = types.SimpleNamespace(trace=trace.summarize(events), cell=None)
+    # what the reader's own one-chip compile and op filter read here before
+    # it went through decode_program's
+    assert attention.read(run) == 39.3498715

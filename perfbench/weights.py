@@ -1,8 +1,10 @@
-"""Seeded weights of a dense decoder, in the program's parameter layout.
+"""Seeded weights, in the program's parameter layout.
 
-The same function feeds the program (which quantizes and lays the MLP
-weights out itself) and the plain reference, so the reference needs
-nothing the program made.  The MLP weights lie on an int4 grid: 16
+A configuration's family (``families/<family>.py``) makes each layer
+from these generators, and the same function feeds the program (which
+quantizes and lays the MLP weights out itself) and the plain reference,
+so the reference needs nothing the program made.  The MLP weights lie on
+an int4 grid: 16
 evenly spaced levels with one step per column, zero at level 8 and a
 quarter of the codes at each end.  Then any grouping of at least 76 rows
 holds both ends of every column but for a chance of 2 * 0.75**76 (about
@@ -24,6 +26,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from perfbench import spec
 
 LEVELS = 16
 ZERO = 8
@@ -64,35 +68,15 @@ def int4_grid(key, shape) -> jax.Array:
 
 
 def top(conf: dict, key) -> dict:
-    """Embedding, LM head and final norm."""
-    kt = jax.random.fold_in(key, 0)
-    ke, kh, kn = jax.random.split(kt, 3)
-    d, v = conf["hidden_size"], conf["vocab_size"]
-    emb = _normal(ke, (v, d), d ** -0.5)
-    head = emb.T if conf["tie_word_embeddings"] else _normal(kh, (d, v),
-                                                            d ** -0.5)
-    return {"embed": {"embedding": emb, "lm_head": head},
-            "final_norm": {"scale": _norm_scale(kn, (d,))}}
+    """Embedding, LM head and final norm, as the configuration's family
+    makes them."""
+    return spec.family(conf).top(conf, key)
 
 
 def layer(conf: dict, key, index) -> dict:
-    """Layer ``index`` (may be traced): norms, attention, raw MLP."""
-    k = jax.random.split(jax.random.fold_in(jax.random.fold_in(key, 1),
-                                            index), 10)
-    d, ff, hd = conf["hidden_size"], conf["intermediate_size"], \
-        conf["head_dim"]
-    q = conf["num_attention_heads"] * hd
-    kv = conf["num_key_value_heads"] * hd
-    attn = {"wq": _normal(k[0], (d, q), d ** -0.5),
-            "wk": _normal(k[1], (d, kv), d ** -0.5),
-            "wv": _normal(k[2], (d, kv), d ** -0.5),
-            "wo": _normal(k[3], (q, d), q ** -0.5)}
-    if conf["qk_norm"]:
-        attn["q_norm"] = _norm_scale(k[4], (hd,))
-        attn["k_norm"] = _norm_scale(k[5], (hd,))
-    return {"ln1": {"scale": _norm_scale(k[6], (d,))},
-            "attn": attn,
-            "ln2": {"scale": _norm_scale(k[7], (d,))},
-            "mlp": {"w_up": int4_grid(k[8], (d, ff)),
-                    "w_gate": int4_grid(jax.random.fold_in(k[8], 1), (d, ff)),
-                    "w_down": int4_grid(k[9], (ff, d))}}
+    """Raw layer ``index``, as the configuration's family makes it; may be
+    traced where every layer is of one kind."""
+    fam = spec.family(conf)
+    kinds = fam.layer_kinds(conf)
+    kind = kinds[0] if len(set(kinds)) == 1 else kinds[index]
+    return fam.layer(conf, key, index, kind)
